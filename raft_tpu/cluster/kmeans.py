@@ -65,14 +65,26 @@ class KMeansOutput:
 
 
 def kmeans_plus_plus(key, X: jax.Array, k: int, sample_weights=None) -> jax.Array:
-    """k-means++ seeding (``cluster/detail/kmeans.cuh:91`` kmeansPlusPlus):
-    first center uniform, then each next center sampled with probability
-    proportional to (weighted) squared distance to the nearest chosen
-    center."""
-    n, d = X.shape
+    """Greedy k-means++ seeding (``cluster/detail/kmeans.cuh:91``
+    kmeansPlusPlus): the first center sampled by weight; each next one the
+    best of ``2 + log(k)`` candidates sampled with probability proportional
+    to (weighted) squared distance to the nearest chosen center — the
+    candidate that lowers the total potential most. One sample per step
+    (plain k-means++) leaves a centroid between two blobs for some seeds
+    of the random stream; the local trials make the seeding robust to
+    the stream, as in the reference."""
+    n = X.shape[0]
     w = jnp.ones((n,), jnp.float32) if sample_weights is None else jnp.asarray(sample_weights, jnp.float32)
+    return _kmeans_plus_plus(key, jnp.asarray(X, jnp.float32), w, k=k)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _kmeans_plus_plus(key, X, w, *, k: int):
+    n, d = X.shape
+    n_trials = 2 + int(np.log(max(k, 1)))
+    x_sq = jnp.sum(X * X, axis=1)
     k0, kloop = jax.random.split(key)
-    first = jax.random.randint(k0, (), 0, n)
+    first = jax.random.categorical(k0, jnp.log(jnp.maximum(w, 1e-30)))
     centers = jnp.zeros((k, d), jnp.float32).at[0].set(X[first])
     min_d2 = jnp.sum((X - X[first]) ** 2, axis=1)
 
@@ -81,11 +93,14 @@ def kmeans_plus_plus(key, X: jax.Array, k: int, sample_weights=None) -> jax.Arra
         kk, ksel = jax.random.split(kk)
         # Sample proportional to w * min_d2 (log-categorical; zero-safe).
         logits = jnp.log(jnp.maximum(w * min_d2, 1e-30))
-        idx = jax.random.categorical(ksel, logits)
-        c = X[idx]
-        centers = centers.at[i].set(c)
-        min_d2 = jnp.minimum(min_d2, jnp.sum((X - c) ** 2, axis=1))
-        return centers, min_d2, kk
+        idx = jax.random.categorical(ksel, logits, shape=(n_trials,))
+        cand = X[idx]  # [t, d]
+        dots = jnp.dot(cand, X.T, precision=lax.Precision.HIGHEST)
+        d2 = jnp.maximum(x_sq[idx][:, None] + x_sq[None, :] - 2.0 * dots, 0.0)
+        trial = jnp.minimum(min_d2[None, :], d2)  # [t, n]
+        best = jnp.argmin(jnp.sum(w[None, :] * trial, axis=1))
+        centers = centers.at[i].set(cand[best])
+        return centers, trial[best], kk
 
     centers, _, _ = lax.fori_loop(1, k, body, (centers, min_d2, kloop))
     return centers

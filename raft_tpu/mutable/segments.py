@@ -370,14 +370,16 @@ class Snapshot:
             )
             d = p = None
             if route == "fused":
-                from raft_tpu.robust.fallback import FALLBACK_ERRORS
+                from raft_tpu.robust.fallback import FALLBACK_ERRORS, record_fallback
 
                 try:
                     d, p = _delta_fused_search(
                         self.metric, self.delta_bf, self.delta_live, queries, k_delta
                     )
-                except FALLBACK_ERRORS:
-                    route = "exact"  # kernel failure degrades to the XLA scan
+                except FALLBACK_ERRORS as e:
+                    # kernel failure degrades to the XLA scan, counted
+                    record_fallback("mutable_delta", e)
+                    route = "exact"
             if d is None:
                 d, p = brute_force.search(
                     self.delta_bf, queries, k_delta,

@@ -402,7 +402,8 @@ def build(
         knn = jnp.take_along_axis(nbrs, pos, axis=1).astype(jnp.int32)
         knn_graph = jnp.where(jnp.take_along_axis(mask, pos, axis=1), knn, -1)
 
-    graph = optimize(knn_graph, kout)
+    with obs.span("cagra.build.optimize", n=n) as sp:
+        graph = sp.sync(optimize(knn_graph, kout))
     data_f32 = dataset.astype(jnp.float32)
     sqnorms = jnp.sum(data_f32 * data_f32, axis=1)
     return CagraIndex(dataset=dataset, sqnorms=sqnorms, graph=graph, metric=metric, size=n)
@@ -730,28 +731,54 @@ def derive_search_config(params: "CagraSearchParams", k: int, size: int):
     return itopk, width, iters, min(itopk, size)
 
 
-def fused_eligible(
+def fused_ineligible_reason(
     index: CagraIndex,
     params: "CagraSearchParams",
     prefilter: Optional[Bitset] = None,
-) -> bool:
-    """Whether the Pallas fused beam kernel
-    (:mod:`raft_tpu.ops.pallas.cagra_search`) can serve this search:
-    raw (uncompressed) dataset, shared strided seeding, ``"post"``
-    dedup semantics (the kernel's merge implements exactly those), no
-    prefilter, ids within the packed base-256 encoding, and id rows
-    that fit the vector lanes (``graph_degree <= dim``)."""
-    from raft_tpu.ops.pallas.cagra_search import MAX_TABLE_IDS
+) -> str:
+    """Why the Pallas fused beam kernel
+    (:mod:`raft_tpu.ops.pallas.cagra_search`) cannot serve this search,
+    or ``""`` when it can. It needs a raw (uncompressed) dataset, shared
+    strided seeding, ``"post"`` dedup semantics (the kernel's merge
+    implements exactly those), no prefilter, ids within the packed
+    base-256 encoding, id rows that fit the vector lanes
+    (``graph_degree <= dim``), and room in HBM for the packed neighbor
+    table it reads (``deg + 3`` rows per node) — an index whose table
+    cannot fit plans the XLA path up front instead of failing on the
+    allocation."""
+    from raft_tpu.ops.pallas import cagra_search, hbm_model
 
-    return (
+    if not (
         index.dataset is not None
         and prefilter is None
         and params.init_sample > 0
         and params.dedup == "post"
         and index.metric in _SUPPORTED
         and index.graph_degree <= index.dim
-        and index.size <= MAX_TABLE_IDS
-    )
+        and index.size <= cagra_search.MAX_TABLE_IDS
+    ):
+        return ("needs raw dataset, init_sample>0, dedup='post', no prefilter, "
+                "graph_degree<=dim")
+    dtype = jnp.dtype(params.fused_table_dtype)
+    cached = getattr(index, "_fused_table_cache", None)
+    if cached is not None and cached[0] == dtype:
+        return ""
+    need = cagra_search.table_bytes(index.size, index.graph_degree, index.dim, dtype)
+    free = hbm_model.device_free_bytes()
+    if need > free * hbm_model.HBM_HEADROOM:
+        return (f"needs {need} B of HBM for the packed neighbor table, "
+                f"{free} B free (x{hbm_model.HBM_HEADROOM} headroom)")
+    return ""
+
+
+def fused_eligible(
+    index: CagraIndex,
+    params: "CagraSearchParams",
+    prefilter: Optional[Bitset] = None,
+) -> bool:
+    """Whether the fused beam kernel can serve this search (see
+    :func:`fused_ineligible_reason`)."""
+    return not fused_ineligible_reason(index, params, prefilter)
 
 
 def _fused_table(index: CagraIndex, dtype) -> jax.Array:
@@ -771,7 +798,7 @@ def _fused_table(index: CagraIndex, dtype) -> jax.Array:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "itopk", "width", "iters", "metric", "qt", "interpret"),
+    static_argnames=("k", "deg", "itopk", "width", "iters", "metric", "qt", "interpret"),
 )
 def _cagra_fused_impl(
     table,
@@ -781,6 +808,7 @@ def _cagra_fused_impl(
     init_ids,
     *,
     k: int,
+    deg: int,
     itopk: int,
     width: int,
     iters: int,
@@ -814,7 +842,7 @@ def _cagra_fused_impl(
     kidf0 = jnp.where(i0 < 0, -1, i0 * 2)
     bv, bidf = cagra_fused_search(
         table, qf, kv0, kidf0,
-        itopk=itopk, width=width, iters=iters, qt=qt,
+        deg=deg, itopk=itopk, width=width, iters=iters, qt=qt,
         ip=not select_min, interpret=interpret,
     )
     buf_i = bidf >> 1
@@ -899,6 +927,8 @@ def _search_dispatch(
     filter_bits = prefilter.bits if prefilter is not None else None
 
     requested_mode = mode
+    if mode in ("auto", "fused"):
+        why_not_fused = fused_ineligible_reason(index, params, prefilter)
     if mode == "auto":
         from raft_tpu import plan as _plan
 
@@ -906,21 +936,13 @@ def _search_dispatch(
         if _plan.is_enabled():
             mode = _plan.plan_cagra_mode(
                 queries.shape[0], on_tpu=on_tpu,
-                fused_ok=fused_eligible(index, params, prefilter),
+                fused_ok=not why_not_fused, why_not_fused=why_not_fused,
             ).choice
         else:
-            mode = (
-                "fused"
-                if on_tpu and fused_eligible(index, params, prefilter)
-                else "xla"
-            )
+            mode = "fused" if on_tpu and not why_not_fused else "xla"
     expects(mode in ("xla", "fused"), "mode must be auto|xla|fused, got %r", mode)
     if mode == "fused":
-        expects(
-            fused_eligible(index, params, prefilter),
-            "fused mode needs a raw dataset, init_sample > 0, dedup='post', "
-            "no prefilter, and graph_degree <= dim (use mode='xla')",
-        )
+        expects(not why_not_fused, "fused mode %s (use mode='xla')", why_not_fused)
     if obs.is_enabled():
         obs.inc("cagra.search.calls", mode=mode)
         obs.inc("cagra.search.queries", float(queries.shape[0]))
@@ -960,6 +982,7 @@ def _search_dispatch(
                             qc,
                             init_ids,
                             k=k,
+                            deg=index.graph_degree,
                             itopk=itopk,
                             width=width,
                             iters=iters,

@@ -25,6 +25,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from raft_tpu.ops.distance import DistanceType
@@ -80,49 +81,54 @@ def topk_labels(ds_f32: jax.Array, centers: jax.Array, k: int = 4, block: int = 
     return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("n_lists", "max_list"))
 def assign_slots(cand_labels, *, n_lists: int, max_list: int) -> jax.Array:
     """Flat destination slot per row in the padded layout (list-major).
 
     ``cand_labels [n, c]`` ranks each row's candidate lists nearest-first.
     One pass per candidate column (nearest list while it has room, then the
     next candidate, ...), then a final pass dropping stragglers into any
-    free slot. All static-shape sorts/scatters. Returns ``slot [n] int32``
-    with every row placed (requires ``n <= n_lists * max_list``); the final
-    list of a row is ``slot // max_list``.
+    free slot. Returns ``slot [n] int32`` with every row placed (requires
+    ``n <= n_lists * max_list``); the final list of a row is
+    ``slot // max_list``.
+
+    Runs in numpy on the host (one ``[n, c]`` fetch). The same passes as
+    one XLA program took 83 s to compile for a v5e at 1M rows and 1086 s
+    at 4M (the sorts and scans compile in time that grows with ``n``),
+    past the four-chip smoke's whole time limit (PERF.md, PR 21); here
+    they take under half a second per million rows.
     """
-    n, n_cand = cand_labels.shape
-    big = jnp.int32(n_lists)
+    cand = np.asarray(cand_labels)
+    n, n_cand = cand.shape
     total = n_lists * max_list
 
     def rank_within(labels, active):
         """Stable rank of each active row within its label group."""
-        key = jnp.where(active, labels, big)
-        order = jnp.argsort(key)
-        sl = key[order]
-        first = jnp.concatenate([jnp.ones((1,), bool), sl[1:] != sl[:-1]])
-        group_start = jnp.where(first, jnp.arange(n), 0)
-        group_start = lax.associative_scan(jnp.maximum, group_start)
-        rank_sorted = jnp.arange(n) - group_start
-        return jnp.zeros((n,), jnp.int32).at[order].set(rank_sorted.astype(jnp.int32))
+        key = np.where(active, labels, n_lists)
+        order = np.argsort(key, kind="stable")
+        counts = np.bincount(key, minlength=n_lists + 1)
+        start = np.cumsum(counts) - counts
+        rank = np.empty(n, np.int64)
+        rank[order] = np.arange(n) - start[key[order]]
+        return rank
 
-    slot = jnp.full((n,), total, jnp.int32)
-    placed = jnp.zeros((n,), bool)
-    used = jnp.zeros((n_lists,), jnp.int32)
+    slot = np.full(n, total, np.int64)
+    placed = np.zeros(n, bool)
+    used = np.zeros(n_lists, np.int64)
     for c in range(n_cand):
-        lc = cand_labels[:, c]
+        lc = cand[:, c]
         rank = rank_within(lc, ~placed)
-        fits = (~placed) & (used[lc] + rank < max_list)
-        slot = jnp.where(fits, lc * max_list + used[lc] + rank, slot)
-        used = used.at[jnp.where(fits, lc, n_lists)].add(1, mode="drop")
-        placed = placed | fits
+        fits = ~placed & (used[lc] + rank < max_list)
+        slot = np.where(fits, lc * max_list + used[lc] + rank, slot)
+        used += np.bincount(lc[fits], minlength=n_lists)
+        placed |= fits
 
-    # final pass: leftovers into any free slot (argsort puts free first)
-    filled = (jnp.zeros((total + 1,), jnp.int32).at[slot].set(1, mode="drop"))[:total]
-    free_slots = jnp.argsort(filled).astype(jnp.int32)
-    rank3 = rank_within(jnp.zeros((n,), jnp.int32), ~placed)
-    slot = jnp.where(~placed, free_slots[jnp.clip(rank3, 0, total - 1)], slot)
-    return slot
+    # final pass: leftovers, in row order, into the free slots in order
+    filled = np.zeros(total, np.int8)
+    filled[slot[placed]] = 1
+    free_slots = np.flatnonzero(filled == 0)
+    left = np.flatnonzero(~placed)
+    slot[left] = free_slots[:left.size]
+    return jnp.asarray(slot.astype(np.int32))
 
 
 def choose_max_list(l1, n: int, n_lists: int, cap_factor: float) -> int:

@@ -689,6 +689,8 @@ def search(
     expects(
         mode in ("scan", "probe", "fused"), "mode must be auto|scan|probe|fused, got %r", mode
     )
+    if obs.is_enabled():
+        obs.inc("ivf_flat.search.calls", mode=mode)
     if mode == "fused":
         from raft_tpu.ops.pallas.ivf_scan import (
             ivf_flat_fused_search,

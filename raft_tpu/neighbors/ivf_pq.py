@@ -307,6 +307,17 @@ def _make_rotation(key, rot_dim: int, dim: int, force: bool) -> jax.Array:
     return q[:rot_dim, :dim]
 
 
+def _nearest_sub(x, c):
+    """Nearest sub-center ``c [..., k, l]`` (the leading axes broadcast)
+    of each sub-vector ``x [..., l]``: ``[...]`` indices, by exact
+    elementwise f32 distances. The norm expansion's dot contracts only
+    ``l`` (2-16) elements, and on a v5e neither of its precisions is
+    exact: at the default one it encoded 1.4% of a 1M-row set off a
+    float64 encode, at ``HIGHEST`` 87% (PERF.md, PR 21)."""
+    diff = x[..., None, :] - c
+    return jnp.argmin(jnp.sum(diff * diff, axis=-1), axis=-1)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "n_iters"))
 def _batched_lloyd(X, mask, init, *, k: int, n_iters: int):
     """Vmapped masked Lloyd: ``X [B, n, d]``, 0/1 ``mask [B, n]``,
@@ -315,17 +326,12 @@ def _batched_lloyd(X, mask, init, *, k: int, n_iters: int):
     The batched replacement for the reference's per-subspace /
     per-cluster sequential codebook loops (``train_per_subset``,
     ``train_per_cluster``, ``ivf_pq_build.cuh``): every subspace trains in
-    the same stack of MXU ops.
+    the same stack of ops.
     """
 
     def one(Xb, mb, cb):
         def body(_, centers):
-            d2 = (
-                jnp.sum(Xb * Xb, axis=1)[:, None]
-                - 2.0 * Xb @ centers.T
-                + jnp.sum(centers * centers, axis=1)[None, :]
-            )
-            labels = jnp.argmin(d2, axis=1)
+            labels = _nearest_sub(Xb, centers)
             w = mb
             sums = jax.ops.segment_sum(Xb * w[:, None], labels, num_segments=k)
             counts = jax.ops.segment_sum(w, labels, num_segments=k)
@@ -340,24 +346,17 @@ def _batched_lloyd(X, mask, init, *, k: int, n_iters: int):
 @functools.partial(jax.jit, static_argnames=("per_cluster",))
 def _encode_chunk(resid_rot, labels, pq_centers, *, per_cluster: bool):
     """Encode rotated residuals ``[c, pq_dim, pq_len]`` to uint8 codes
-    (``process_and_fill_codes`` analog): nearest sub-center per subspace via
-    one batched matmul."""
-    if per_cluster:
-        pqc = pq_centers[labels]  # [c, ksub, pq_len]
-        dots = jnp.einsum("npl,nkl->npk", resid_rot, pqc, preferred_element_type=jnp.float32)
-        cn = jnp.sum(pqc * pqc, axis=-1)[:, None, :]  # [c, 1, ksub]
-    else:
-        dots = jnp.einsum("npl,pkl->npk", resid_rot, pq_centers, preferred_element_type=jnp.float32)
-        cn = jnp.sum(pq_centers * pq_centers, axis=-1)[None, :, :]  # [1, pq_dim, ksub]
-    # ||r - c||^2 = ||r||^2 - 2 r.c + ||c||^2 ; ||r||^2 constant in argmin
-    d2 = cn - 2.0 * dots
-    return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
+    (``process_and_fill_codes`` analog): nearest sub-center per subspace,
+    by exact distances (:func:`_nearest_sub`)."""
+    # per-cluster books [c, 1, ksub, pq_len]; per-subspace [pq_dim, ksub, pq_len]
+    pqc = pq_centers[labels][:, None] if per_cluster else pq_centers
+    return _nearest_sub(resid_rot, pqc).astype(jnp.uint8)
 
 
 def _rotated_residuals(X, labels, centers, rotation, pq_dim: int):
     """R @ (x - c[label]) reshaped to [n, pq_dim, pq_len]."""
     resid = X - centers[labels]
-    rr = resid @ rotation.T  # [n, rot_dim]
+    rr = jnp.dot(resid, rotation.T, precision=lax.Precision.HIGHEST)  # [n, rot_dim]
     return rr.reshape(X.shape[0], pq_dim, -1)
 
 
@@ -419,6 +418,20 @@ def nibble_books(pq_centers) -> jax.Array:
     return jnp.concatenate([a, b], axis=1)  # hi-half then lo-half
 
 
+def _seed_books(key, Xs, k: int):
+    """Initial ``[b, k, l]`` codebooks for the ``b`` stacked subspaces of
+    ``Xs [b, nt, l]``: greedy k-means++ per subspace (a random pick of
+    seeds left the books at the mercy of the random stream); a trainset
+    smaller than ``k`` tiles its rows."""
+    nt = Xs.shape[1]
+    if nt < k:
+        return jnp.tile(Xs, (1, -(-k // nt), 1))[:, :k, :]
+    from raft_tpu.cluster.kmeans import kmeans_plus_plus
+
+    return jax.vmap(lambda kk, x: kmeans_plus_plus(kk, x, k))(
+        jax.random.split(key, Xs.shape[0]), Xs)
+
+
 def _train_nibble_books(t_resid, key, n_iters: int):
     """Additive nibble codebooks: A = 16-center Lloyd on the residuals,
     B = 16-center Lloyd on the second-level residuals, then alternating
@@ -433,27 +446,14 @@ def _train_nibble_books(t_resid, key, n_iters: int):
     Xs = jnp.transpose(t_resid, (1, 0, 2))  # [pq_dim, nt, pq_len]
     mask = jnp.ones((pq_dim, nt), jnp.float32)
     ka, kb = jax.random.split(key)
-
-    def seed_init(k, X):
-        idx = jax.random.permutation(k, nt)[: min(16, nt)]
-        init = X[:, idx, :]
-        if init.shape[1] < 16:
-            reps = -(-16 // init.shape[1])
-            init = jnp.tile(init, (1, reps, 1))[:, :16, :]
-        return init
-
-    A = _batched_lloyd(Xs, mask, seed_init(ka, Xs), k=16, n_iters=n_iters)
+    A = _batched_lloyd(Xs, mask, _seed_books(ka, Xs, 16), k=16, n_iters=n_iters)
 
     def assign(X, books):  # [pq_dim, nt, pq_len] x [pq_dim, 16, pq_len]
-        d2 = (
-            jnp.sum(books * books, axis=-1)[:, None, :]
-            - 2.0 * jnp.einsum("pnl,pkl->pnk", X, books, preferred_element_type=jnp.float32)
-        )
-        return jnp.argmin(d2, axis=-1)  # [pq_dim, nt]
+        return _nearest_sub(X, books[:, None])  # [pq_dim, nt]
 
     hi = assign(Xs, A)
     R2 = Xs - jnp.take_along_axis(A, hi[:, :, None], axis=1)
-    B = _batched_lloyd(R2, mask, seed_init(kb, R2), k=16, n_iters=n_iters)
+    B = _batched_lloyd(R2, mask, _seed_books(kb, R2, 16), k=16, n_iters=n_iters)
 
     def refit(X, labels, k):
         def one(Xb, lb):
@@ -530,7 +530,7 @@ def _sqnorms_for(codes, centers_rot, pq_centers, per_cluster: bool):
     )
 
 
-def _encode_all(ds_f32, labels, centers, rotation, pq_centers, pq_dim, per_cluster, chunk=65536):
+def _encode_all(ds_f32, labels, centers, rotation, pq_centers, pq_dim, per_cluster, chunk=16384):
     """Encode every row against its (final) list's center — fully on
     device, chunked so the [chunk, pq_dim, ksub] temporaries stay bounded."""
     outs = []
@@ -717,7 +717,7 @@ def build(
     # quantizer needs the residual direction uniformly distributed on the
     # sphere), so rabitq always forces one.
     rotation = _make_rotation(k_rot, rot_dim, d, params.force_random_rotation or rabitq)
-    centers_rot = centers @ rotation.T
+    centers_rot = jnp.dot(centers, rotation.T, precision=lax.Precision.HIGHEST)
 
     per_cluster = params.codebook_kind == PER_CLUSTER
     if rabitq:
@@ -770,11 +770,7 @@ def build(
         # [pq_dim, nt, pq_len] stacks; one vmapped Lloyd trains all subspaces.
         Xs = jnp.transpose(t_resid, (1, 0, 2))
         mask = jnp.ones((pq_dim, nt), jnp.float32)
-        init_idx = jax.random.permutation(k_cb, nt)[: min(ksub, nt)]
-        init = Xs[:, init_idx, :]
-        if init.shape[1] < ksub:  # degenerate tiny trainset: tile seeds
-            reps = -(-ksub // init.shape[1])
-            init = jnp.tile(init, (1, reps, 1))[:, :ksub, :]
+        init = _seed_books(k_cb, Xs, ksub)
         pq_centers = _batched_lloyd(Xs, mask, init, k=ksub, n_iters=params.kmeans_n_iters)
     else:
         # Pool each cluster's residual subvectors (all subspaces), pad to a

@@ -140,11 +140,16 @@ def _acc_dtype(dtype) -> jnp.dtype:
 
 def _dot(x: jax.Array, y: jax.Array) -> jax.Array:
     """``x @ y.T`` with accumulation in f32/i32 (MXU-friendly: bf16 and int8
-    inputs keep their narrow storage type through the matmul)."""
+    inputs keep their narrow storage type through the matmul). Two f32
+    operands multiply at full f32 precision: the TPU's default rounds
+    them to bf16, which reorders near neighbors in an "exact" search."""
+    f32 = jnp.dtype(jnp.float32)
     out = lax.dot_general(
         x,
         y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=(lax.Precision.HIGHEST
+                   if jnp.dtype(x.dtype) == f32 and jnp.dtype(y.dtype) == f32 else None),
         preferred_element_type=_acc_dtype(x.dtype),
     )
     return out.astype(jnp.float32)

@@ -75,44 +75,79 @@ MAX_TABLE_IDS = (1 << 24) - 2
 #: back to the XLA path's ``worst_value`` outside the kernel.
 WORST = 3.0e38
 
+#: Scoped-VMEM limit the beam kernel compiles under (see its call).
+VMEM_LIMIT_BYTES = 48 * 1024 * 1024
+
 #: Column chunk of the pairwise rank / one-hot placement passes — bounds
 #: the [qt, m, chunk] body intermediates to ~1 MiB at the bench shape.
 _RANK_CHUNK = 64
 
 
+def table_rows(deg: int) -> int:
+    """Rows per node of the packed table: ``deg`` vectors, the
+    ``ID_ROWS`` id rows, then zero rows up to a whole 8-row tile — a DMA
+    may only move whole tiles, and HBM pads the rows to them anyway."""
+    return -(-(deg + ID_ROWS) // 8) * 8
+
+
+def table_bytes(n: int, deg: int, d: int, dtype=jnp.bfloat16) -> int:
+    """HBM bytes of the packed ``[n, table_rows(deg), d]`` neighbor table."""
+    return n * table_rows(deg) * d * jnp.dtype(dtype).itemsize
+
+
 def build_neighbor_table(dataset, graph, *, dtype=jnp.bfloat16, row_chunk: int = 65536):
-    """Pack ``[n, deg + ID_ROWS, d]`` neighbor rows: node ``v``'s rows are
-    its ``deg`` neighbors' vectors followed by 3 id-digit rows (base-256
-    of ``id + 1`` in lanes ``0..deg-1``; lane 0-fill decodes to -1)."""
+    """Pack ``[n, table_rows(deg), d]`` neighbor rows: node ``v``'s rows
+    are its ``deg`` neighbors' vectors followed by 3 id-digit rows
+    (base-256 of ``id + 1`` in lanes ``0..deg-1``; lane 0-fill decodes
+    to -1) and zero rows to the tile boundary.
+
+    The table is filled in place one ``row_chunk`` of nodes at a time, so
+    the build holds one table plus one chunk: at SIFT-1M the table alone
+    is ~9 GB of a v5e's 16 GB, and chunks concatenated at the end would
+    need it twice."""
     n, d = dataset.shape
     deg = graph.shape[1]
     expects(deg <= d, "packed id rows need graph_degree (%d) <= dim (%d)", deg, d)
     expects(n <= MAX_TABLE_IDS, "packed ids support <= %d rows, got %d", MAX_TABLE_IDS, n)
-    parts = []
-    for s in range(0, n, row_chunk):
-        g = jnp.asarray(graph[s : s + row_chunk], jnp.int32)
-        c = g.shape[0]
-        vecs = jnp.asarray(dataset)[jnp.clip(g, 0, None)].astype(dtype)  # [c, deg, d]
+    return _pack_table(jnp.asarray(dataset), jnp.asarray(graph, jnp.int32),
+                       dtype=jnp.dtype(dtype), row_chunk=min(row_chunk, n))
+
+
+@functools.partial(jax.jit, static_argnames=("dtype", "row_chunk"))
+def _pack_table(dataset, graph, *, dtype, row_chunk: int):
+    n, d = dataset.shape
+    deg = graph.shape[1]
+
+    def fill(c, table):
+        # the last chunk is clamped to end at row n: it rewrites a few
+        # rows of its predecessor with the same values
+        start = jnp.minimum(c * row_chunk, n - row_chunk)
+        g = lax.dynamic_slice_in_dim(graph, start, row_chunk, 0)
+        vecs = dataset[jnp.clip(g, 0, None)].astype(dtype)  # [c, deg, d]
         gp1 = g + 1  # -1 pad -> 0
         digits = jnp.stack([gp1 & 255, (gp1 >> 8) & 255, (gp1 >> 16) & 255], axis=1)
-        id_rows = jnp.zeros((c, ID_ROWS, d), dtype).at[:, :, :deg].set(digits.astype(dtype))
-        parts.append(jnp.concatenate([vecs, id_rows], axis=1))
-    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+        id_rows = jnp.zeros((row_chunk, table_rows(deg) - deg, d), dtype).at[
+            :, :ID_ROWS, :deg].set(digits.astype(dtype))
+        block = jnp.concatenate([vecs, id_rows], axis=1)
+        return lax.dynamic_update_slice_in_dim(table, block, start, 0)
+
+    table = jnp.zeros((n, table_rows(deg), d), dtype)
+    return lax.fori_loop(0, cdiv(n, row_chunk), fill, table)
 
 
-def _pick_positions(vals, width: int):
+def _pick_columns(vals, width: int):
     """``width`` rounds of min-extract over ``[qt, itopk]`` (the
-    ``pickup_next_parents`` analog, shared logic with the XLA path)."""
+    ``pickup_next_parents`` analog): per round, the ``[qt, 1]`` position
+    and validity columns, which the kernel consumes one at a time."""
     cols = lax.broadcasted_iota(jnp.int32, vals.shape, 1)
     big = jnp.int32(2**30)
-    poss, valids = [], []
+    out = []
     for _ in range(width):
         mv = jnp.min(vals, axis=1, keepdims=True)
         sel = jnp.min(jnp.where(vals == mv, cols, big), axis=1, keepdims=True)
-        poss.append(sel)
-        valids.append(mv < WORST)
+        out.append((sel, mv < WORST))
         vals = jnp.where(cols == sel, WORST, vals)
-    return jnp.concatenate(poss, axis=1), jnp.concatenate(valids, axis=1)
+    return out
 
 
 def _rank_merge(uv, uidf, itopk: int):
@@ -127,7 +162,7 @@ def _rank_merge(uv, uidf, itopk: int):
     parts = []
     for i0 in range(0, m, _RANK_CHUNK):
         i1 = min(i0 + _RANK_CHUNK, m)
-        vi = uv[:, None, i0:i1]
+        vi = jnp.expand_dims(uv[:, i0:i1], 1)  # a None index here lowers to a gather
         ii = lax.broadcasted_iota(jnp.int32, (1, 1, i1 - i0), 2) + i0
         less = (uv[:, :, None] < vi).astype(jnp.int32)
         tie = ((uv[:, :, None] == vi) & (jj < ii)).astype(jnp.int32)
@@ -160,20 +195,22 @@ def _beam_kernel(
         beam_idf = oi_ref[...]
         # -- pick parents: best `width` unvisited, valid slots ------------
         masked = jnp.where(((beam_idf & 1) == 1) | (beam_idf < 0), WORST, beam_v)
-        ppos, pvalid = _pick_positions(masked, width)  # [qt, width]
-        oh = ppos[:, :, None] == cols[:, None, :]  # [qt, width, itopk]
-        ohv = oh & pvalid[:, :, None]
-        pidf = jnp.sum(jnp.where(ohv, beam_idf[:, None, :], 0), axis=2)
-        pv[...] = jnp.where(pvalid, pidf >> 1, -1)  # parent ids, -1 invalid
+        # one parent column at a time: 2-D [qt, itopk] masks only (Mosaic
+        # cannot lay out the [qt, width, 1] casts of a 3-D one-hot, nor
+        # concatenate mask columns)
+        picked = jnp.zeros((qt, itopk), jnp.bool_)
+        for w, (ppos, pvalid) in enumerate(_pick_columns(masked, width)):
+            ohv = (cols == ppos) & pvalid  # [qt, itopk]
+            pidf = jnp.sum(jnp.where(ohv, beam_idf, 0), axis=1, keepdims=True)
+            pv[:, w:w + 1] = jnp.where(pvalid, pidf >> 1, -1)  # parent ids, -1 invalid
+            picked = picked | ohv
         # mark the picked slots visited before the merge sees them
-        oi_ref[...] = jnp.where(jnp.any(ohv, axis=1), beam_idf | 1, beam_idf)
+        oi_ref[...] = jnp.where(picked, beam_idf | 1, beam_idf)
 
         # -- stage parent ids to SMEM, then issue every DMA up front ------
         stage = pltpu.make_async_copy(pv, ps, semp)
         stage.start()
         stage.wait()
-
-        rows = deg + ID_ROWS
 
         def issue(j, c):
             qq, ww = j // width, j % width
@@ -182,7 +219,7 @@ def _beam_kernel(
             @pl.when(pid >= 0)
             def _():
                 pltpu.make_async_copy(
-                    table_ref.at[pid], nbr.at[qq, pl.ds(ww * rows, rows)],
+                    table_ref.at[pid], nbr.at[qq, ww],
                     semn.at[qq, ww],
                 ).start()
 
@@ -198,39 +235,44 @@ def _beam_kernel(
                 @pl.when(pid >= 0)
                 def _():
                     pltpu.make_async_copy(
-                        table_ref.at[pid], nbr.at[qq, pl.ds(ww * rows, rows)],
+                        table_ref.at[pid], nbr.at[qq, ww],
                         semn.at[qq, ww],
                     ).wait()
 
                 return c2
 
             lax.fori_loop(0, width, waitw, 0)
-            blk = nbr[qq]  # [width * rows, d]: per parent, deg vec + 3 id rows
-            vecs = jnp.concatenate(
-                [blk[w * rows : w * rows + deg] for w in range(width)]
-            ).astype(jnp.float32)  # [width * deg, d]
-            qv = q_ref[qq]  # [d]
-            if ip:
-                dist = -jnp.sum(vecs * qv[None, :], axis=1)
-            else:
-                diff = vecs - qv[None, :]
-                dist = jnp.sum(diff * diff, axis=1)
-            # decode ids: base-256 digit rows, exact in the table dtype
-            digits = [
-                jnp.concatenate(
-                    [blk[w * rows + deg + t : w * rows + deg + t + 1, :deg]
-                     for w in range(width)]
-                ).astype(jnp.float32)  # [width, deg]
-                for t in range(ID_ROWS)
-            ]
-            cid = (digits[0] + 256.0 * digits[1] + 65536.0 * digits[2]).astype(
-                jnp.int32
-            ) - 1
-            # a skipped (invalid-parent) DMA leaves stale lanes: mask them
-            pm = jnp.broadcast_to((pv[qq] >= 0)[:, None], (width, deg))
-            cid = jnp.where(pm, cid, -1).reshape(width * deg)
-            cv[qq, :] = jnp.where(cid >= 0, dist, WORST)
-            ci[qq, :] = cid
+            qv = q_ref[pl.ds(qq, 1), :]  # [1, d]
+            ones = jnp.ones((1, d), jnp.float32)
+            # one parent at a time, all 2-D: distances come out as a lane
+            # row [1, deg] (a lane reduction would give a column), the
+            # sum over d taken by the MXU at full f32 precision
+            vrow, irow = [], []
+            for w in range(width):
+                blk = nbr[qq, w]  # [table_rows, d]: deg vectors + 3 id rows
+                vecs = blk[:deg].astype(jnp.float32)  # [deg, d]
+                if ip:
+                    terms = vecs * qv
+                else:
+                    diff = vecs - qv
+                    terms = diff * diff
+                dist = lax.dot_general(
+                    ones, terms, (((1,), (1,)), ((), ())),
+                    precision=lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32,
+                )  # [1, deg]
+                if ip:
+                    dist = -dist
+                # decode ids: base-256 digit rows, exact in the table dtype
+                dg = blk[deg:deg + ID_ROWS].astype(jnp.float32)  # [3, d]
+                cid = (dg[0:1] + 256.0 * dg[1:2] + 65536.0 * dg[2:3])[:, :deg]
+                cid = cid.astype(jnp.int32) - 1
+                # a skipped (invalid-parent) DMA leaves stale lanes: mask them
+                cid = jnp.where(ps[qq, w] >= 0, cid, -1)
+                vrow.append(jnp.where(cid >= 0, dist, WORST))
+                irow.append(cid)
+            cv[pl.ds(qq, 1), :] = jnp.concatenate(vrow, axis=1)
+            ci[pl.ds(qq, 1), :] = jnp.concatenate(irow, axis=1)
             return c
 
         lax.fori_loop(0, qt, score_q, 0)
@@ -258,7 +300,8 @@ def kernel_scratch_shapes(qt: int, width: int, deg: int, d: int, table_dtype=jnp
     literal shapes (the SMEM staging buffer and DMA semaphores are not
     VMEM and are appended separately at the call site)."""
     return [
-        pltpu.VMEM((qt, width * (deg + 3), d), table_dtype),  # nbr rows
+        # one [table_rows, d] slot per parent: a DMA may not cut a tile
+        pltpu.VMEM((qt, width, table_rows(deg), d), table_dtype),  # nbr rows
         pltpu.VMEM((qt, width), jnp.int32),  # parent ids
         pltpu.VMEM((qt, width * deg), jnp.float32),  # candidate dists
         pltpu.VMEM((qt, width * deg), jnp.int32),  # candidate ids
@@ -267,7 +310,7 @@ def kernel_scratch_shapes(qt: int, width: int, deg: int, d: int, table_dtype=jnp
 
 @functools.partial(
     jax.jit,
-    static_argnames=("itopk", "width", "iters", "qt", "ip", "interpret"),
+    static_argnames=("deg", "itopk", "width", "iters", "qt", "ip", "interpret"),
 )
 def cagra_fused_search(
     table,
@@ -275,6 +318,7 @@ def cagra_fused_search(
     init_v,
     init_idf,
     *,
+    deg: int,
     itopk: int,
     width: int,
     iters: int,
@@ -289,8 +333,8 @@ def cagra_fused_search(
     ``(values [nq, itopk] f32, packed idf [nq, itopk] i32)``; the caller
     unpacks, runs the final unique-merge and metric epilogue."""
     nq, d = queries.shape
-    rows = table.shape[1]
-    deg = rows - ID_ROWS
+    expects(table.shape[1] == table_rows(deg), "table has %d rows per node, want %d",
+            table.shape[1], table_rows(deg))
     nqp = cdiv(nq, qt) * qt
     if nqp != nq:
         pad = nqp - nq
@@ -305,7 +349,7 @@ def cagra_fused_search(
             pl.BlockSpec((qt, d), lambda i: (i, 0)),
             pl.BlockSpec((qt, itopk), lambda i: (i, 0)),
             pl.BlockSpec((qt, itopk), lambda i: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # table stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # table stays in HBM
         ],
         out_specs=[
             pl.BlockSpec((qt, itopk), lambda i: (i, 0)),
@@ -332,6 +376,10 @@ def cagra_fused_search(
                 jax.ShapeDtypeStruct((nqp, itopk), jnp.float32),
                 jax.ShapeDtypeStruct((nqp, itopk), jnp.int32),
             ],
+            # the pairwise-rank merge temporaries of a 32-query tile need
+            # ~21 MiB: past the 16 MiB default scope, well inside v5e's
+            # 128 MiB of VMEM
+            compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES),
             interpret=interpret,
         )(queries, init_v, init_idf, table)
     return out_v[:nq], out_idf[:nq]

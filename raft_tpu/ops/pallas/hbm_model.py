@@ -246,6 +246,19 @@ def cagra_residency(
     ))
 
 
+def device_free_bytes(device=None) -> int:
+    """HBM the allocator of ``device`` (default: the first device) can
+    still hand out — ``bytes_limit - bytes_in_use`` from
+    ``memory_stats()``. A backend that reports no stats (the CPU) gets
+    :data:`HBM_DEFAULT_BUDGET_BYTES`."""
+    import jax
+
+    stats = (device or jax.devices()[0]).memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return HBM_DEFAULT_BUDGET_BYTES
+    return int(stats["bytes_limit"]) - int(stats.get("bytes_in_use", 0))
+
+
 def delta_bank_residency(
     index_id: str,
     *,

@@ -67,8 +67,10 @@ participant — same placement as the ``comms.all_gather`` seam); callers
 in ``parallel/`` catch :class:`~raft_tpu.core.errors.KernelFailure` /
 runtime errors through ``_guard.kernel_guard`` and fall back to the
 gather merge (warn-once, ``fallbacks{algo="ring_topk"}``). Per-ring obs:
-``comms.ring.hops`` and ``comms.ring.bytes{direction}`` counters and a
-traced ``ring_topk`` span.
+``comms.ring.hops``, ``comms.ring.bytes{direction}`` (the payload
+model) and ``comms.ring.wire_bytes{direction}`` (what the engine ships:
+the fused kernel's DMA slots are lane-padded) counters and a traced
+``ring_topk`` span.
 
 VMEM residency of the fused kernel is modeled in
 :func:`raft_tpu.ops.pallas.vmem_model.ring_topk_residency` and checked
@@ -88,7 +90,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from raft_tpu import obs
 from raft_tpu.core.errors import expects
-from raft_tpu.parallel._compat import axis_size
+from jax.lax import axis_size
 from raft_tpu.robust import faults
 
 #: Finite in-kernel "worst" value (see ``cagra_search.WORST``): the
@@ -109,6 +111,16 @@ _FOLD_ROWS = 32
 #: (``cagra_search._RANK_CHUNK``).
 _RANK_CHUNK = 64
 
+#: Lane tile of a VMEM buffer: a remote DMA must move whole lane tiles,
+#: so the send/recv slots are ``lanes(w)`` wide and carry the merge
+#: width ``w`` in their leading columns.
+_LANES = 128
+
+
+def lanes(w: int) -> int:
+    """Width of a DMA slot that carries ``w`` columns."""
+    return -(-w // _LANES) * _LANES
+
 # The per-query merge wire model moved to the consolidated
 # raft_tpu.parallel.wire_model (the planner prices ring-vs-gather from
 # it); re-exported at this original home, where the engines' byte
@@ -116,6 +128,7 @@ _RANK_CHUNK = 64
 from raft_tpu.parallel.wire_model import (  # noqa: F401  (re-export)
     AG_ENTRY_BYTES,
     RS_ENTRY_BYTES,
+    ring_wire_bytes,
     wire_bytes_per_query,
 )
 
@@ -259,16 +272,17 @@ def kernel_scratch_shapes(n: int, B: int, w: int):
         pltpu.VMEM((n, B, w), jnp.int32),     # state_pos
         pltpu.VMEM((n, B, w), jnp.float32),   # state_val
         pltpu.VMEM((n, B, w), jnp.int32),     # state_id
-        pltpu.VMEM((2, B, w), jnp.float32),   # send_key
-        pltpu.VMEM((2, B, w), jnp.int32),     # send_pos
-        pltpu.VMEM((2, B, w), jnp.float32),   # send_val
-        pltpu.VMEM((2, B, w), jnp.int32),     # send_id
-        pltpu.VMEM((2, B, w), jnp.float32),   # recv_key
-        pltpu.VMEM((2, B, w), jnp.int32),     # recv_pos
-        pltpu.VMEM((2, B, w), jnp.float32),   # recv_val
-        pltpu.VMEM((2, B, w), jnp.int32),     # recv_id
+        pltpu.VMEM((2, B, lanes(w)), jnp.float32),   # send_key
+        pltpu.VMEM((2, B, lanes(w)), jnp.int32),     # send_pos
+        pltpu.VMEM((2, B, lanes(w)), jnp.float32),   # send_val
+        pltpu.VMEM((2, B, lanes(w)), jnp.int32),     # send_id
+        pltpu.VMEM((2, B, lanes(w)), jnp.float32),   # recv_key
+        pltpu.VMEM((2, B, lanes(w)), jnp.int32),     # recv_pos
+        pltpu.VMEM((2, B, lanes(w)), jnp.float32),   # recv_val
+        pltpu.VMEM((2, B, lanes(w)), jnp.int32),     # recv_id
         pltpu.SemaphoreType.DMA((2, 4)),      # send sems [slot, lane]
         pltpu.SemaphoreType.DMA((2, 4)),      # recv sems [slot, lane]
+        pltpu.SemaphoreType.REGULAR((2,)),    # credits: my right's recv slot is free
     ]
 
 
@@ -293,8 +307,8 @@ def _rank_merge_pos(uk, up, uv, ui, w: int):
     parts = []
     for i0 in range(0, m, _RANK_CHUNK):
         i1 = min(i0 + _RANK_CHUNK, m)
-        ki = uk[:, None, i0:i1]
-        pi = up[:, None, i0:i1]
+        ki = jnp.expand_dims(uk[:, i0:i1], 1)  # a None index here lowers to a gather
+        pi = jnp.expand_dims(up[:, i0:i1], 1)
         less = (uk[:, :, None] < ki).astype(jnp.int32)
         tie = ((uk[:, :, None] == ki) & (up[:, :, None] < pi)).astype(jnp.int32)
         parts.append(jnp.sum(less + tie, axis=1))
@@ -347,7 +361,7 @@ def hop_merge(a, b, qt: int = _FOLD_ROWS, interpret: bool = True):
 
 
 def _ring_body(n: int, B: int, w: int, axis: str, ov, oi, state, send, recv,
-               send_sem, recv_sem):
+               send_sem, recv_sem, credit):
     """The shared ring schedule: reduce-scatter then all-gather, one
     ``make_async_remote_copy`` bundle per hop into the right neighbor's
     recv slot, fold on the VPU while the outgoing DMA drains (its
@@ -356,10 +370,18 @@ def _ring_body(n: int, B: int, w: int, axis: str, ov, oi, state, send, recv,
     all-gather). ``state`` must already hold the staged ``[n, B, w]``
     per-block partials; :func:`_ring_kernel` stages a straight copy of
     the inputs, :func:`_scan_ring_kernel` stages the scan fold's
-    winners."""
+    winners.
+
+    Hops are numbered ``t = 0 .. 2(n-1)-1`` across both phases and use
+    slot ``t % 2``. A recv slot is written again two hops later, so the
+    receiver hands a credit back to its left neighbor once it has read
+    the slot, and the sender waits for that credit before the DMA — the
+    right neighbor may still be folding the slot's previous contents
+    (Pallas' interpret-mode race detector finds that race without it)."""
     me = lax.axis_index(axis)
     right = lax.rem(me + 1, n)
     left = lax.rem(me + n - 1, n)
+    n_hops = 2 * (n - 1)
 
     # neighbor rendezvous: nobody DMAs into a peer still setting up
     # (staging touches only local state, never a recv slot, so running
@@ -369,37 +391,46 @@ def _ring_body(n: int, B: int, w: int, axis: str, ov, oi, state, send, recv,
     pltpu.semaphore_signal(barrier, inc=1, device_id=(right,))
     pltpu.semaphore_wait(barrier, 2)
 
-    def start_hop(slot, src_block, lanes):
-        """Stage ``state[src_block]`` into the send slot and launch one
-        remote copy per lane into the right neighbor's recv slot."""
-        for ln in lanes:
-            send[ln][slot] = pl.load(
-                state[ln], (pl.ds(src_block, 1), slice(None), slice(None))
-            )[0]
-            pltpu.make_async_remote_copy(
-                src_ref=send[ln].at[slot],
-                dst_ref=recv[ln].at[slot],
-                send_sem=send_sem.at[slot, ln],
-                recv_sem=recv_sem.at[slot, ln],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL,
-            ).start()
+    def copy(slot, ln):
+        """The lane-``ln`` remote copy of send slot ``slot`` into the
+        right neighbor's recv slot. DMA semaphores count bytes, so every
+        wait goes through the descriptor, which knows the byte count."""
+        return pltpu.make_async_remote_copy(
+            src_ref=send[ln].at[slot],
+            dst_ref=recv[ln].at[slot],
+            send_sem=send_sem.at[slot, ln],
+            recv_sem=recv_sem.at[slot, ln],
+            device_id=(right,),
+        )
+
+    def free_send(t, lanes):
+        """Before staging hop ``t``: my send of hop ``t-2`` (same slot)
+        has drained, and my right neighbor has read that hop's data."""
+        if t >= 2:
+            for ln in lanes:
+                copy(t % 2, ln).wait_send()
+            pltpu.semaphore_wait(credit.at[t % 2], 1)
+
+    def release(t):
+        """My recv slot of hop ``t`` has been read: credit the sender."""
+        pltpu.semaphore_signal(credit.at[t % 2], 1, device_id=(left,))
 
     lanes_rs = (0, 1, 2, 3)
     lanes_ag = (2, 3)  # finished blocks travel as (val, id) only
 
     # -- reduce-scatter hops ------------------------------------------------
     for s in range(n - 1):
-        slot = s % 2
-        if s >= 2:  # the slot's previous send must have drained
-            for ln in lanes_rs:
-                pltpu.semaphore_wait(send_sem[slot, ln], 1)
-        start_hop(slot, lax.rem(me + n - s, n) if s else me, lanes_rs)
+        t, slot = s, s % 2
+        free_send(t, lanes_rs)
+        src = lax.rem(me + n - s, n) if s else me
         for ln in lanes_rs:
-            pltpu.semaphore_wait(recv_sem[slot, ln], 1)
+            send[ln][slot, :, 0:w] = state[ln][pl.ds(src, 1)][0]
+            copy(slot, ln).start()
+        for ln in lanes_rs:
+            copy(slot, ln).wait_recv()
         dst = lax.rem(me + n - s - 1 + n, n)
-        cur = tuple(pl.load(st, (pl.ds(dst, 1), slice(None), slice(None)))[0] for st in state)
-        got = tuple(rcv[slot] for rcv in recv)
+        cur = tuple(st[pl.ds(dst, 1)][0] for st in state)
+        got = tuple(rcv[slot, :, 0:w] for rcv in recv)
         for q0 in range(0, B, _FOLD_ROWS):
             q1 = min(q0 + _FOLD_ROWS, B)
             uk = jnp.concatenate([cur[0][q0:q1], got[0][q0:q1]], axis=1)
@@ -408,46 +439,43 @@ def _ring_body(n: int, B: int, w: int, axis: str, ov, oi, state, send, recv,
             ui = jnp.concatenate([cur[3][q0:q1], got[3][q0:q1]], axis=1)
             fk, fp, fv, fi = _rank_merge_pos(uk, up, uv, ui, w)
             for st, f in zip(state, (fk, fp, fv, fi)):
-                pl.store(st, (pl.ds(dst, 1), pl.ds(q0, q1 - q0), slice(None)), f[None])
-    for s in range(max(0, n - 3), n - 1):  # drain outstanding sends
-        for ln in lanes_rs:
-            pltpu.semaphore_wait(send_sem[s % 2, ln], 1)
+                st[pl.ds(dst, 1), pl.ds(q0, q1 - q0)] = f[None]
+        release(t)
 
     # rank r owns finished block (r+1)%n; write it to the output
     own = lax.rem(me + 1, n)
     for dst_ref, ln in ((ov, 2), (oi, 3)):
-        blk = pl.load(state[ln], (pl.ds(own, 1), slice(None), slice(None)))[0]
-        pl.store(dst_ref, (pl.ds(own * B, B), slice(None)), blk)
+        dst_ref[pl.ds(own * B, B)] = state[ln][pl.ds(own, 1)][0]
 
     # -- all-gather hops: forward the newest finished block rightward -------
     for s in range(n - 1):
-        slot = s % 2
-        if s >= 2:
-            for ln in lanes_ag:
-                pltpu.semaphore_wait(send_sem[slot, ln], 1)
+        t = n - 1 + s
+        slot = t % 2
+        # the lanes the reduce-scatter sent but this phase does not must
+        # drain too before the slot is reused (hop t-2 may be an RS hop)
+        free_send(t, lanes_rs if t - 2 < n - 1 else lanes_ag)
         # the block being forwarded is already in the output; stage from
-        # state (hop 0: own block) or from the previous hop's recv slot
-        if s == 0:
-            start_hop(slot, own, lanes_ag)
-        else:
-            for ln in lanes_ag:
+        # state (first hop: own block) or from the previous hop's recv
+        # slot, which is then read and goes back to the sender
+        for ln in lanes_ag:
+            if s == 0:
+                send[ln][slot, :, 0:w] = state[ln][pl.ds(own, 1)][0]
+            else:
                 send[ln][slot] = recv[ln][1 - slot]
-                pltpu.make_async_remote_copy(
-                    src_ref=send[ln].at[slot],
-                    dst_ref=recv[ln].at[slot],
-                    send_sem=send_sem.at[slot, ln],
-                    recv_sem=recv_sem.at[slot, ln],
-                    device_id=(right,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL,
-                ).start()
+            copy(slot, ln).start()
+        if s:
+            release(t - 1)
         for ln in lanes_ag:
-            pltpu.semaphore_wait(recv_sem[slot, ln], 1)
+            copy(slot, ln).wait_recv()
         dst = lax.rem(me + n - s, n)
-        pl.store(ov, (pl.ds(dst * B, B), slice(None)), recv[2][slot])
-        pl.store(oi, (pl.ds(dst * B, B), slice(None)), recv[3][slot])
-    for s in range(max(0, n - 3), n - 1):
-        for ln in lanes_ag:
-            pltpu.semaphore_wait(send_sem[s % 2, ln], 1)
+        ov[pl.ds(dst * B, B)] = recv[2][slot, :, 0:w]
+        oi[pl.ds(dst * B, B)] = recv[3][slot, :, 0:w]
+    release(n_hops - 1)
+    # drain: the last two sends, and the right neighbor's last two credits
+    for t in range(max(0, n_hops - 2), n_hops):
+        for ln in (lanes_rs if t < n - 1 else lanes_ag):
+            copy(t % 2, ln).wait_send()
+        pltpu.semaphore_wait(credit.at[t % 2], 1)
 
 
 def _ring_kernel(
@@ -456,7 +484,7 @@ def _ring_kernel(
     sk, sp, sv, si,          # state [n, B, w]
     tk, tp, tv, ti,          # send slots [2, B, w]
     rk, rp, rv, ri,          # recv slots [2, B, w]
-    send_sem, recv_sem,
+    send_sem, recv_sem, credit,
 ):
     """Width-``w`` inputs: stage a straight copy of each query block
     into the state buffers, then run the shared :func:`_ring_body`."""
@@ -464,7 +492,7 @@ def _ring_kernel(
         sk[b], sp[b] = ink[b * B:(b + 1) * B], inp[b * B:(b + 1) * B]
         sv[b], si[b] = inv[b * B:(b + 1) * B], ini[b * B:(b + 1) * B]
     _ring_body(n, B, w, axis, ov, oi, (sk, sp, sv, si), (tk, tp, tv, ti),
-               (rk, rp, rv, ri), send_sem, recv_sem)
+               (rk, rp, rv, ri), send_sem, recv_sem, credit)
 
 
 def _scan_ring_kernel(
@@ -473,7 +501,7 @@ def _scan_ring_kernel(
     sk, sp, sv, si,          # state [n, B, w]
     tk, tp, tv, ti,          # send slots [2, B, w]
     rk, rp, rv, ri,          # recv slots [2, B, w]
-    send_sem, recv_sem,
+    send_sem, recv_sem, credit,
 ):
     """Scan-fused staging: the inputs are the scan's FULL ``[n*B, kc]``
     candidate tile (``kc`` a multiple of ``w``; e.g. ``k·refine_ratio``
@@ -497,9 +525,9 @@ def _scan_ring_kernel(
                 ui = jnp.concatenate([acc[3], sl[3]], axis=1)
                 acc = _rank_merge_pos(uk, up, uv, ui, w)
             for st, f in zip(state, acc):
-                pl.store(st, (pl.ds(b, 1), pl.ds(q0, q1 - q0), slice(None)), f[None])
+                st[pl.ds(b, 1), pl.ds(q0, q1 - q0)] = f[None]
     _ring_body(n, B, w, axis, ov, oi, state, (tk, tp, tv, ti),
-               (rk, rp, rv, ri), send_sem, recv_sem)
+               (rk, rp, rv, ri), send_sem, recv_sem, credit)
 
 
 def fused_ring_topk(v, i, k: int, select_min: bool, axis: str, collective_id: int = 7):
@@ -518,7 +546,7 @@ def fused_ring_topk(v, i, k: int, select_min: bool, axis: str, collective_id: in
         functools.partial(_ring_kernel, n, B, w, axis),
         out_shape=tuple(jax.ShapeDtypeStruct((n * B, w), d) for d in dts),
         scratch_shapes=kernel_scratch_shapes(n, B, w),
-        compiler_params=pltpu.TPUCompilerParams(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
     )(key, pos, vals, ii)
     # restore the inf sentinels the XLA/gather paths report
     worst = jnp.float32(WORST if select_min else -WORST)
@@ -565,7 +593,7 @@ def fused_scan_ring_topk(v, i, k: int, select_min: bool, axis: str,
         functools.partial(_scan_ring_kernel, n, B, w, kcp, axis),
         out_shape=tuple(jax.ShapeDtypeStruct((n * B, w), d) for d in dts),
         scratch_shapes=scan_kernel_scratch_shapes(n, B, w, kcp),
-        compiler_params=pltpu.TPUCompilerParams(collective_id=collective_id),
+        compiler_params=pltpu.CompilerParams(collective_id=collective_id),
     )(key, pos, vals, ids)
     worst = jnp.float32(WORST if select_min else -WORST)
     inf = jnp.float32(jnp.inf if select_min else -jnp.inf)
@@ -576,6 +604,21 @@ def fused_scan_ring_topk(v, i, k: int, select_min: bool, axis: str,
 # ---------------------------------------------------------------------------
 # dispatch
 # ---------------------------------------------------------------------------
+
+
+def _count_ring(n: int, nq: int, k: int, axis: str, fused: bool) -> None:
+    """Trace-time ring counters. ``comms.ring.bytes`` is the payload
+    model (:func:`wire_bytes_per_query`: the ``k`` entries that must
+    cross); ``comms.ring.wire_bytes`` is what the engine puts on the
+    wire (:func:`ring_wire_bytes`: the sort-key lane too, and the fused
+    kernel's ``lanes(k)``-wide DMA slots)."""
+    B = -(-nq // n)
+    payload = max(0, n - 1) * B * k * (RS_ENTRY_BYTES + AG_ENTRY_BYTES)
+    wire = ring_wire_bytes(n, B, lanes(k) if fused else k)
+    obs.inc("comms.ring.hops", 2 * max(0, n - 1), axis=str(axis))
+    for direction in ("send", "recv"):
+        obs.inc("comms.ring.bytes", float(payload), axis=str(axis), direction=direction)
+        obs.inc("comms.ring.wire_bytes", wire, axis=str(axis), direction=direction)
 
 
 def ring_topk(
@@ -599,13 +642,7 @@ def ring_topk(
     if use_fused is None:
         use_fused = jax.default_backend() == "tpu"
     if obs.is_enabled():
-        hops = 2 * max(0, n - 1)
-        B = -(-v.shape[0] // n)
-        rs = (n - 1) * B * k * RS_ENTRY_BYTES
-        ag = (n - 1) * B * k * AG_ENTRY_BYTES
-        obs.inc("comms.ring.hops", hops, axis=str(axis))
-        obs.inc("comms.ring.bytes", float(rs + ag), axis=str(axis), direction="send")
-        obs.inc("comms.ring.bytes", float(rs + ag), axis=str(axis), direction="recv")
+        _count_ring(n, v.shape[0], k, axis, use_fused)
         with obs.span(
             "ring_topk", axis=str(axis), n_shards=int(n), k=int(k),
             engine="fused" if use_fused else "xla", traced=True,
@@ -631,7 +668,7 @@ def scan_ring_topk(
     Same (value, position) total order as the gather path's stable merge
     over the shard-major width-``k_candidates`` concatenation — the
     global top-k is a subset of the per-shard top-k, so folding locally
-    first is bit-exact. Wire bytes are identical to ``ring_topk``; obs
+    first is bit-exact. Wire bytes equal ``ring_topk``'s; obs
     counters land under the same ``comms.ring.*`` names and the shared
     ``ring_topk`` span (``engine="scan_fused"/"scan_xla"``). Failures
     escape to the caller's ``kernel_guard`` → gather fallback
@@ -642,6 +679,9 @@ def scan_ring_topk(
     faults.fire("comms.ring_topk", axis=str(axis), n_shards=int(n), kind="scan")
     if use_fused is None:
         use_fused = jax.default_backend() == "tpu"
+    # a tile no wider than k has nothing to fold: fused_scan_ring_topk
+    # hands it to the XLA ring
+    fused = use_fused and n > 1 and v.shape[1] > k
 
     def run():
         if use_fused:
@@ -649,16 +689,10 @@ def scan_ring_topk(
         return _ring_topk_xla(v, i, k, select_min, axis, scan_fold=True)
 
     if obs.is_enabled():
-        hops = 2 * max(0, n - 1)
-        B = -(-v.shape[0] // n)
-        rs = (n - 1) * B * k * RS_ENTRY_BYTES
-        ag = (n - 1) * B * k * AG_ENTRY_BYTES
-        obs.inc("comms.ring.hops", hops, axis=str(axis))
-        obs.inc("comms.ring.bytes", float(rs + ag), axis=str(axis), direction="send")
-        obs.inc("comms.ring.bytes", float(rs + ag), axis=str(axis), direction="recv")
+        _count_ring(n, v.shape[0], k, axis, fused)
         with obs.span(
             "ring_topk", axis=str(axis), n_shards=int(n), k=int(k),
-            engine="scan_fused" if use_fused else "scan_xla", traced=True,
+            engine="scan_fused" if fused else "scan_xla", traced=True,
         ):
             return run()
     return run()

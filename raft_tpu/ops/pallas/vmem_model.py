@@ -333,7 +333,8 @@ def cagra_search_residency(
         Resident("out_idf", (qt, itopk), 4, buffers=2),
         # scratch_shapes, in declaration order (table stays in HBM/ANY
         # and is streamed by explicit per-parent DMAs into nbr)
-        Resident("nbr", (qt, width * (deg + 3), d), table_itemsize, kind="scratch"),
+        Resident("nbr", (qt, width, -(-(deg + 3) // 8) * 8, d), table_itemsize,
+                 kind="scratch"),
         Resident("parents", (qt, width), 4, kind="scratch"),
         Resident("cand_v", (qt, width * deg), 4, kind="scratch"),
         Resident("cand_id", (qt, width * deg), 4, kind="scratch"),
@@ -358,7 +359,8 @@ def ring_topk_residency(
     grid — the whole prepped candidate set ([n*B, w] per lane) sits in
     VMEM — so every in/out ref is single-buffered; the ring state
     ([n, B, w] per lane) plus the double-buffered send/recv DMA slots
-    ([2, B, w] per lane) are scratch, asserted against
+    ([2, B, w] per lane, padded to whole 128-lane tiles for the DMA) are
+    scratch, asserted against
     ``ring_topk.kernel_scratch_shapes`` (DMA semaphores are not VMEM and
     are excluded); the body peak is one (key, pos) pairwise-rank chunk
     of the fold (two i32 ``[fold_rows, 2w, rank_chunk]`` temps live).
@@ -379,14 +381,14 @@ def ring_topk_residency(
         Resident("state_pos", (n, B, w), 4, kind="scratch"),
         Resident("state_val", (n, B, w), 4, kind="scratch"),
         Resident("state_id", (n, B, w), 4, kind="scratch"),
-        Resident("send_key", (2, B, w), 4, kind="scratch"),
-        Resident("send_pos", (2, B, w), 4, kind="scratch"),
-        Resident("send_val", (2, B, w), 4, kind="scratch"),
-        Resident("send_id", (2, B, w), 4, kind="scratch"),
-        Resident("recv_key", (2, B, w), 4, kind="scratch"),
-        Resident("recv_pos", (2, B, w), 4, kind="scratch"),
-        Resident("recv_val", (2, B, w), 4, kind="scratch"),
-        Resident("recv_id", (2, B, w), 4, kind="scratch"),
+        Resident("send_key", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("send_pos", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("send_val", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("send_id", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_key", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_pos", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_val", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_id", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
         # peak body intermediate: less + tie of one rank chunk
         Resident("rank_chunk", (fold_rows, 2 * w, rank_chunk), 4, buffers=2,
                  kind="body"),
@@ -429,14 +431,14 @@ def scan_ring_topk_residency(
         Resident("state_pos", (n, B, w), 4, kind="scratch"),
         Resident("state_val", (n, B, w), 4, kind="scratch"),
         Resident("state_id", (n, B, w), 4, kind="scratch"),
-        Resident("send_key", (2, B, w), 4, kind="scratch"),
-        Resident("send_pos", (2, B, w), 4, kind="scratch"),
-        Resident("send_val", (2, B, w), 4, kind="scratch"),
-        Resident("send_id", (2, B, w), 4, kind="scratch"),
-        Resident("recv_key", (2, B, w), 4, kind="scratch"),
-        Resident("recv_pos", (2, B, w), 4, kind="scratch"),
-        Resident("recv_val", (2, B, w), 4, kind="scratch"),
-        Resident("recv_id", (2, B, w), 4, kind="scratch"),
+        Resident("send_key", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("send_pos", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("send_val", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("send_id", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_key", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_pos", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_val", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
+        Resident("recv_id", (2, B, -(-w // 128) * 128), 4, kind="scratch"),
         # peak body intermediate: less + tie of one rank chunk (shared
         # by the staging fold and the per-hop fold)
         Resident("rank_chunk", (fold_rows, 2 * w, rank_chunk), 4, buffers=2,

@@ -20,19 +20,12 @@ from raft_tpu.parallel.comms import (
     replicated,
     row_sharded,
 )
-try:
-    from raft_tpu.parallel.sharded_ann import (
-        sharded_cagra_search,
-        sharded_ivf_flat_search,
-        sharded_ivf_pq_search,
-    )
-    from raft_tpu.parallel.sharded_knn import sharded_knn
-except ImportError:  # graft-lint: ignore[silent-except] — availability probe
-    # sharded_* need jax.shard_map (jax >= 0.5). Keep the comms verb set
-    # importable on older jax; the sharded names stay UNDEFINED so
-    # `from raft_tpu.parallel import sharded_knn` still raises ImportError
-    # (not a silent None) exactly as it would with a hard import.
-    pass
+from raft_tpu.parallel.sharded_ann import (
+    sharded_cagra_search,
+    sharded_ivf_flat_search,
+    sharded_ivf_pq_search,
+)
+from raft_tpu.parallel.sharded_knn import sharded_knn
 
 __all__ = [
     "sharded_cagra_search",

@@ -136,7 +136,7 @@ def run_comms_self_test(mesh=None, axis: str = comms_mod.DEFAULT_AXIS) -> bool:
     barrier over the mesh; returns True when every verb round-trips."""
     from jax.sharding import PartitionSpec as P
 
-    from raft_tpu.parallel._compat import shard_map
+    from jax import shard_map
 
     if mesh is None:
         mesh = global_mesh()

@@ -37,7 +37,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from raft_tpu import obs
 from raft_tpu.core.errors import expects
 from raft_tpu.core.resources import Resources, ensure_resources
-from raft_tpu.parallel._compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from raft_tpu.robust import faults
 
 DEFAULT_AXIS = "data"

@@ -43,7 +43,7 @@ from raft_tpu.neighbors import cagra as cagra_mod, ivf_flat as ivf_flat_mod, ivf
 from raft_tpu.ops.distance import DistanceType
 from raft_tpu.ops.pallas._guard import kernel_guard
 from raft_tpu.ops.select_k import merge_parts, worst_value
-from raft_tpu.parallel._compat import shard_map
+from jax import shard_map
 from raft_tpu.random.rng import as_key
 from raft_tpu.robust.fallback import FALLBACK_ERRORS, record_fallback
 
@@ -464,7 +464,7 @@ def _note_build_comms(phase: str, payload_bytes: float, axis: str,
     build programs retrace per call, so per-iteration launches inside the
     Python training loop each fire once."""
     from raft_tpu import obs
-    from raft_tpu.parallel._compat import axis_size
+    from jax.lax import axis_size
     from raft_tpu.parallel.comms import wire_bytes
 
     if not obs.is_enabled():
@@ -566,6 +566,44 @@ def dist_lloyd_step(centers, x_local, n_lists, axis, cache=None, fuse_comms=True
     return jnp.where(cnts[:, None] > 0, new, centers), lab
 
 
+#: Residual rows per step of the codebook update's local pass: bounds its
+#: ``[rows, pq_dim, ksub]`` distance and one-hot temporaries (at 1M rows
+#: a shard, pq_dim 64 and 256 sub-centers one such f32 array is 65 GB).
+_CODEBOOK_ROWS = 8192
+
+
+def _codebook_partials(books, resid, ksub, prev_code=None):
+    """The local pass of a codebook update, ``_CODEBOOK_ROWS`` rows at a
+    time: each residual sub-vector's nearest sub-center ``code [nl,
+    pq_dim]``, the per-sub-center sums ``[pq_dim, ksub, pq_len]`` and
+    counts ``[pq_dim, ksub]``, and, given ``prev_code``, how many rows
+    entered or left each sub-center ``[pq_dim, ksub]``."""
+    nl, pq_dim, pq_len = resid.shape
+    rows = min(_CODEBOOK_ROWS, nl)
+    n_steps = -(-nl // rows)
+    pad = n_steps * rows - nl
+    valid = (jnp.arange(n_steps * rows) < nl).astype(jnp.float32).reshape(n_steps, rows)
+    r = jnp.pad(resid, ((0, pad), (0, 0), (0, 0))).reshape(n_steps, rows, pq_dim, pq_len)
+    prev = (jnp.zeros((n_steps, rows, pq_dim), jnp.int32) if prev_code is None else
+            jnp.pad(prev_code, ((0, pad), (0, 0))).reshape(n_steps, rows, pq_dim))
+
+    def step(acc, xs):
+        rc, w, pc = xs
+        code = ivf_pq_mod._nearest_sub(rc, books)  # [rows, pq_dim]
+        oh = jax.nn.one_hot(code, ksub, dtype=jnp.float32) * w[:, None, None]
+        sums = acc[0] + jnp.einsum("npk,npl->pkl", oh, rc, precision=lax.Precision.HIGHEST)
+        cnts = acc[1] + jnp.sum(oh, axis=0)
+        moved = (code != pc).astype(jnp.float32) * w[:, None]  # [rows, pq_dim]
+        prev_oh = jax.nn.one_hot(pc, ksub, dtype=jnp.float32)
+        changed = acc[2] + jnp.sum(moved[..., None] * (oh + prev_oh), axis=0)
+        return (sums, cnts, changed), code
+
+    zero = jnp.zeros((pq_dim, ksub), jnp.float32)
+    init = (jnp.zeros((pq_dim, ksub, pq_len), jnp.float32), zero, zero)
+    (sums, cnts, changed), codes = lax.scan(step, init, (r, valid, prev))
+    return codes.reshape(n_steps * rows, pq_dim)[:nl], sums, cnts, changed
+
+
 def dist_codebook_step(books, resid, ksub, axis, fuse_comms=True,
                        comm_mode="full", carry=None, ca_cap=None):
     """One distributed per-subspace codebook update (runs inside
@@ -580,12 +618,8 @@ def dist_codebook_step(books, resid, ksub, axis, fuse_comms=True,
     with ``carry=(codes, packed_rows)``."""
     from raft_tpu.parallel.comms import allreduce
 
-    dots = jnp.einsum("npl,pkl->npk", resid, books, preferred_element_type=jnp.float32)
-    cn = jnp.sum(books * books, axis=-1)[None, :, :]
-    code = jnp.argmin(cn - 2.0 * dots, axis=-1)  # [nl, pq_dim]
-    oh = jax.nn.one_hot(code, ksub, dtype=jnp.float32)  # [nl, pq_dim, ksub]
-    sums = jnp.einsum("npk,npl->pkl", oh, resid)
-    cnts = jnp.sum(oh, axis=0)  # [pq_dim, ksub]
+    prev_code = carry[0] if comm_mode == "ca" and carry is not None else None
+    code, sums, cnts, changed = _codebook_partials(books, resid, ksub, prev_code)
     if comm_mode == "ca":
         pq_dim, _, pq_len = sums.shape
         local_rows = jnp.concatenate([sums, cnts[..., None]], axis=-1)
@@ -594,13 +628,8 @@ def dist_codebook_step(books, resid, ksub, axis, fuse_comms=True,
             packed = allreduce(local_rows, "sum", axis)
             _note_build_comms("pq_codebook_full", local_rows.size * 4, axis)
         else:
-            prev_code, grows = carry
-            moved = (code != prev_code).astype(jnp.float32)  # [nl, pq_dim]
-            prev_oh = jax.nn.one_hot(prev_code, ksub, dtype=jnp.float32)
-            changed = (
-                jnp.einsum("np,npk->pk", moved, oh)
-                + jnp.einsum("np,npk->pk", moved, prev_oh)
-            ).reshape(pq_dim * ksub)
+            grows = carry[1]
+            changed = changed.reshape(pq_dim * ksub)
             cap = _ca_cap(pq_dim * ksub, ca_cap)
             packed = _ca_exchange(local_rows, changed, grows, cap, axis, "pq_codebook_ca")
         rows = packed.reshape(pq_dim, ksub, pq_len + 1)
@@ -628,6 +657,93 @@ from raft_tpu.parallel.wire_model import (  # noqa: E402,F401  (re-export)
     codebook_wire_bytes_per_iter,
     lloyd_wire_bytes_per_iter,
 )
+
+
+def _dist_pq_train_fn(mesh, axis, rotation, *, n_lists, pq_dim, ksub, n_iters, mode,
+                      fuse_comms, ca_cap, ca_warmup):
+    """The training program of :func:`sharded_ivf_pq_build`: coarse
+    centers by psum-Lloyd, then per-subspace codebooks, over the rows
+    sharded on ``axis``. Takes ``(dataset, init_centers)`` and returns
+    replicated ``(centers, books)``; exposed so its memory can be read
+    from an ahead-of-time compile (``tests/test_tpu_compile.py``)."""
+    n_shards = mesh.shape[axis]
+
+    def train(x_local, centers0):
+        from raft_tpu.cluster.kmeans import flash_min_cluster_and_distance, flash_norm_cache
+        from raft_tpu.parallel.comms import allgather
+
+        # sample-side norms are iteration-invariant: hoist them out of
+        # the Lloyd loop (the Flash-KMeans cache discipline)
+        cache = flash_norm_cache(x_local, DistanceType.L2Expanded)
+        centers = centers0
+        if mode == "ca":
+            carry = None
+            for it in range(n_iters):
+                if it < ca_warmup - 1:
+                    # warm-up: full-width while churn is still heavy
+                    # (the first CA call re-seeds full-width anyway, so
+                    # ca_warmup counts TOTAL full exchanges)
+                    centers, _ = dist_lloyd_step(
+                        centers, x_local, n_lists, axis, cache=cache,
+                        fuse_comms=True,
+                    )
+                    continue
+                centers, lab, carry = dist_lloyd_step(
+                    centers, x_local, n_lists, axis, cache=cache,
+                    comm_mode="ca", carry=carry, ca_cap=ca_cap,
+                )
+            # final labeling against the converged centers is comm-free
+            lab, _ = flash_min_cluster_and_distance(
+                x_local, centers, metric=DistanceType.L2Expanded, cache=cache
+            )
+        else:
+            for _ in range(n_iters):
+                centers, _ = dist_lloyd_step(
+                    centers, x_local, n_lists, axis, cache=cache, fuse_comms=fuse_comms
+                )
+            _, lab = dist_lloyd_step(
+                centers, x_local, n_lists, axis, cache=cache, fuse_comms=fuse_comms
+            )
+        # per-subspace codebooks on local residuals, psum'd updates;
+        # seeded from a stride-spread sample of EVERY shard's residuals
+        # (real-data init — random gaussians collapse to few used
+        # centers; a single shard's rows skew or under-fill the pool)
+        resid = jnp.dot(x_local - centers[lab], rotation.T, precision=lax.Precision.HIGHEST
+                        ).reshape(x_local.shape[0], pq_dim, -1)
+        nl_local = resid.shape[0]
+        per = -(-ksub // n_shards)
+        stride = max(1, nl_local // per)
+        idx = jnp.minimum(jnp.arange(per) * stride, nl_local - 1)
+        pool = allgather(resid[idx], axis)  # [n_shards, per, pq_dim, pq_len]
+        _note_build_comms("seed", pool[0].size * 4, axis, verb="allgather")
+        seed = jnp.swapaxes(pool, 0, 1).reshape(n_shards * per, pq_dim, -1)
+        n_seed = min(ksub, n_shards * nl_local)
+        books = jnp.transpose(seed[:n_seed], (1, 0, 2))
+        if n_seed < ksub:
+            reps = -(-ksub // n_seed)
+            books = jnp.tile(books, (1, reps, 1))[:, :ksub, :]
+
+        if mode == "ca":
+            bcarry = None
+            for _ in range(max(4, n_iters)):
+                books, bcarry = dist_codebook_step(
+                    books, resid, ksub, axis,
+                    comm_mode="ca", carry=bcarry, ca_cap=ca_cap,
+                )
+        else:
+            for _ in range(max(4, n_iters)):
+                books = dist_codebook_step(books, resid, ksub, axis, fuse_comms=fuse_comms)
+        return centers, books
+
+    return jax.jit(
+        shard_map(
+            train,
+            mesh=mesh,
+            in_specs=(P(axis), P()),
+            out_specs=(P(), P()),
+            check_vma=False,
+        )
+    )
 
 
 def sharded_ivf_pq_build(
@@ -685,98 +801,41 @@ def sharded_ivf_pq_build(
     init_centers = dataset[jax.random.permutation(k_init, n)[:n_lists]]
     rotation = ivf_pq_mod._make_rotation(k_rot, rot_dim, d, params.force_random_rotation)
 
-    def train(x_local, centers0):
-        from raft_tpu.cluster.kmeans import flash_min_cluster_and_distance, flash_norm_cache
-        from raft_tpu.parallel.comms import allgather
-
-        # sample-side norms are iteration-invariant: hoist them out of
-        # the Lloyd loop (the Flash-KMeans cache discipline)
-        cache = flash_norm_cache(x_local, DistanceType.L2Expanded)
-        centers = centers0
-        if mode == "ca":
-            carry = None
-            for it in range(params.kmeans_n_iters):
-                if it < ca_warmup - 1:
-                    # warm-up: full-width while churn is still heavy
-                    # (the first CA call re-seeds full-width anyway, so
-                    # ca_warmup counts TOTAL full exchanges)
-                    centers, _ = dist_lloyd_step(
-                        centers, x_local, n_lists, axis, cache=cache,
-                        fuse_comms=True,
-                    )
-                    continue
-                centers, lab, carry = dist_lloyd_step(
-                    centers, x_local, n_lists, axis, cache=cache,
-                    comm_mode="ca", carry=carry, ca_cap=ca_cap,
-                )
-            # final labeling against the converged centers is comm-free
-            lab, _ = flash_min_cluster_and_distance(
-                x_local, centers, metric=DistanceType.L2Expanded, cache=cache
-            )
-        else:
-            for _ in range(params.kmeans_n_iters):
-                centers, _ = dist_lloyd_step(
-                    centers, x_local, n_lists, axis, cache=cache, fuse_comms=fuse_comms
-                )
-            _, lab = dist_lloyd_step(
-                centers, x_local, n_lists, axis, cache=cache, fuse_comms=fuse_comms
-            )
-        # per-subspace codebooks on local residuals, psum'd updates;
-        # seeded from a stride-spread sample of EVERY shard's residuals
-        # (real-data init — random gaussians collapse to few used
-        # centers; a single shard's rows skew or under-fill the pool)
-        resid = ((x_local - centers[lab]) @ rotation.T).reshape(x_local.shape[0], pq_dim, -1)
-        nl_local = resid.shape[0]
-        per = -(-ksub // n_shards)
-        stride = max(1, nl_local // per)
-        idx = jnp.minimum(jnp.arange(per) * stride, nl_local - 1)
-        pool = allgather(resid[idx], axis)  # [n_shards, per, pq_dim, pq_len]
-        _note_build_comms("seed", pool[0].size * 4, axis, verb="allgather")
-        seed = jnp.swapaxes(pool, 0, 1).reshape(n_shards * per, pq_dim, -1)
-        n_seed = min(ksub, n_shards * nl_local)
-        books = jnp.transpose(seed[:n_seed], (1, 0, 2))
-        if n_seed < ksub:
-            reps = -(-ksub // n_seed)
-            books = jnp.tile(books, (1, reps, 1))[:, :ksub, :]
-
-        if mode == "ca":
-            bcarry = None
-            for _ in range(max(4, params.kmeans_n_iters)):
-                books, bcarry = dist_codebook_step(
-                    books, resid, ksub, axis,
-                    comm_mode="ca", carry=bcarry, ca_cap=ca_cap,
-                )
-        else:
-            for _ in range(max(4, params.kmeans_n_iters)):
-                books = dist_codebook_step(books, resid, ksub, axis, fuse_comms=fuse_comms)
-        return centers, books
-
-    fn = jax.jit(
-        shard_map(
-            train,
-            mesh=mesh,
-            in_specs=(P(axis), P()),
-            out_specs=(P(), P()),
-            check_vma=False,
-        )
-    )
+    fn = _dist_pq_train_fn(mesh, axis, rotation, n_lists=n_lists, pq_dim=pq_dim, ksub=ksub,
+                           n_iters=params.kmeans_n_iters, mode=mode, fuse_comms=fuse_comms,
+                           ca_cap=ca_cap, ca_warmup=ca_warmup)
     put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
     centers, books = fn(put(dataset, P(axis)), put(init_centers, P()))
 
-    # local encode + pack (replicated assembly; kept sharded at DCN scale)
+    # assignment + encode + pack. The row-local steps (candidate lists,
+    # encode) and the list-local one (decoded norms) run as one program a
+    # shard: as eager calls over the row-sharded array they were chunk
+    # loops that XLA partitioned call by call. Slot assignment and the
+    # scatter need every row and stay global.
     from raft_tpu.neighbors import ivf_common
 
-    cand = ivf_common.topk_labels(dataset, centers, k=8)
+    def per_shard(f, *specs):
+        return jax.jit(shard_map(f, mesh=mesh, in_specs=specs, out_specs=P(axis),
+                                 check_vma=False))
+
+    rows, rep = P(axis), P()
+    dataset = put(dataset, rows)
+    cand = per_shard(lambda x, c: ivf_common.topk_labels(x, c, k=8), rows, rep)(
+        dataset, centers)
     max_list = ivf_common.choose_max_list(cand[:, 0], n, n_lists, params.list_cap_factor)
     slot = ivf_common.assign_slots(cand, n_lists=n_lists, max_list=max_list)
     final_labels = (slot // max_list).astype(jnp.int32)
-    codes_rows = ivf_pq_mod._encode_all(
-        dataset, final_labels, centers, rotation, books, pq_dim, False
-    )
+    codes_rows = per_shard(
+        lambda x, lab, c, b: ivf_pq_mod._encode_all(x, lab, c, rotation, b, pq_dim, False),
+        rows, rows, rep, rep)(dataset, put(final_labels, rows), centers, books)
     codes, list_indices, list_sizes = ivf_common.scatter_rows(
         codes_rows, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists, max_list=max_list
     )
-    centers_rot = centers @ rotation.T
+    centers_rot = jnp.dot(centers, rotation.T, precision=lax.Precision.HIGHEST)
+    sqnorms = functools.partial(ivf_pq_mod._sqnorms_for, per_cluster=False)
+    if n_lists % n_shards == 0:
+        sqnorms = per_shard(sqnorms, rows, rows, rep)
+    rot_sqnorms = sqnorms(codes, centers_rot, books)
     return ivf_pq_mod.IvfPqIndex(
         centers=centers,
         centers_rot=centers_rot,
@@ -785,7 +844,7 @@ def sharded_ivf_pq_build(
         codes=codes,
         list_indices=list_indices,
         list_sizes=list_sizes,
-        rot_sqnorms=ivf_pq_mod._sqnorms_for(codes, centers_rot, books, False),
+        rot_sqnorms=rot_sqnorms,
         metric=ivf_pq_mod.resolve_metric(params.metric),
         codebook_kind=ivf_pq_mod.PER_SUBSPACE,
         pq_bits=params.pq_bits,

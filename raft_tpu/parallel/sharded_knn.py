@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from raft_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from raft_tpu.core.errors import expects
 from raft_tpu.neighbors.brute_force import _NORM_METRICS, _search_impl

@@ -57,21 +57,40 @@ def wire_bytes(verb: str, payload_bytes: float, n: int) -> float:
 # search-merge per-query model (ring_topk engines)
 # ---------------------------------------------------------------------------
 
-#: Wire cost per candidate: reduce-scatter hops carry (f32 val, i32 id,
+#: Payload per candidate: reduce-scatter hops carry (f32 val, i32 id,
 #: i32 pos); all-gather hops carry (val, id) only.
 RS_ENTRY_BYTES = 12
 AG_ENTRY_BYTES = 8
+#: What a reduce-scatter entry costs on the wire: both engines ship the
+#: sort key as a fourth lane (key, pos, val, id).
+RS_WIRE_ENTRY_BYTES = 16
+
+
+def ring_wire_bytes(n_shards: int, block_rows: int, slot_width: int) -> float:
+    """Bytes one rank sends (and receives) in one ring merge, as the
+    engines put them on the wire: ``n-1`` reduce-scatter hops of a
+    ``[block_rows, slot_width]`` slot at :data:`RS_WIRE_ENTRY_BYTES` per
+    entry, then ``n-1`` all-gather hops at :data:`AG_ENTRY_BYTES`.
+    ``slot_width`` is ``k`` for the XLA engine; the fused kernel's DMA
+    slots are lane-padded (``ring_topk.lanes(k)``, 128 at k <= 128),
+    12.8x the payload at k=10."""
+    n = int(n_shards)
+    return float(max(0, n - 1) * block_rows * slot_width
+                 * (RS_WIRE_ENTRY_BYTES + AG_ENTRY_BYTES))
 
 
 def wire_bytes_per_query(n_shards: int, k: int, mode: str = "ring") -> float:
-    """Estimated per-rank ICI bytes received per query for one merge.
+    """Estimated per-rank ICI payload bytes received per query for one
+    merge: the ``k`` candidate entries that must cross, not the padded
+    slots the fused kernel moves (:func:`ring_wire_bytes` counts those).
+    The planner prices merges from this payload model.
 
     ``mode="gather"``: each rank receives ``n-1`` foreign ``[k]`` blocks
     of (f32, i32). ``mode="ring"``: ``n-1`` reduce-scatter hops of one
     ``nq/n``-query block at :data:`RS_ENTRY_BYTES`/candidate plus
     ``n-1`` all-gather hops at :data:`AG_ENTRY_BYTES`, amortized over
-    all ``nq`` queries. ``mode="fused_ring"`` moves identical wire bytes
-    to ``"ring"`` — only ``k``-wide winners ever enter the ring; the
+    all ``nq`` queries. ``mode="fused_ring"`` moves the same payload
+    as ``"ring"`` — only ``k``-wide winners ever enter the ring; the
     fusion's saving is the per-shard ``[nq, k·refine_ratio]`` candidate
     tile never round-tripping through HBM, not the wire."""
     n = int(n_shards)

@@ -135,14 +135,16 @@ def plan_search_mode(algo: str, nq: int, *, on_tpu: bool, fused_ok: bool,
     )
 
 
-def plan_cagra_mode(nq: int, *, on_tpu: bool, fused_ok: bool) -> Plan:
-    """CAGRA beam engine pick (``xla`` | ``fused``) for ``nq`` queries."""
+def plan_cagra_mode(nq: int, *, on_tpu: bool, fused_ok: bool,
+                    why_not_fused: str = "") -> Plan:
+    """CAGRA beam engine pick (``xla`` | ``fused``) for ``nq`` queries.
+    ``why_not_fused`` is ``cagra.fused_ineligible_reason``'s verdict."""
     reasons = []
     if not on_tpu:
         reasons.append("backend is not tpu")
     if not fused_ok:
-        reasons.append("needs raw dataset, init_sample>0, dedup='post', "
-                       "no prefilter, graph_degree<=dim")
+        reasons.append(why_not_fused or "needs raw dataset, init_sample>0, "
+                       "dedup='post', no prefilter, graph_degree<=dim")
     return _decide(
         "cagra.search_mode",
         [

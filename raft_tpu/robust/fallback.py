@@ -15,31 +15,16 @@ from __future__ import annotations
 import threading
 import warnings
 
+from jax.errors import JaxRuntimeError
+
 from raft_tpu import obs
 from raft_tpu.core.errors import KernelFailure
 from raft_tpu.utils import lockcheck
 
 
-def _runtime_error_types():
-    errs = []
-    try:  # XLA runtime/compile failures surface as this on all jax versions
-        import jaxlib.xla_extension as xe
-
-        errs.append(xe.XlaRuntimeError)
-    except (ImportError, AttributeError):  # graft-lint: ignore[silent-except] — optional type probe
-        pass
-    try:
-        from jax.errors import JaxRuntimeError
-
-        errs.append(JaxRuntimeError)
-    except ImportError:  # graft-lint: ignore[silent-except] — optional type probe
-        pass
-    return tuple(errs)
-
-
 #: exception types the auto-mode dispatch treats as "kernel failed, XLA can
 #: still answer" — typed chaos plus real accelerator-runtime errors
-FALLBACK_ERRORS = (KernelFailure,) + _runtime_error_types()
+FALLBACK_ERRORS = (KernelFailure, JaxRuntimeError)
 
 
 def fallback_errors() -> tuple:

@@ -5,6 +5,8 @@ import csv
 import json
 import os
 
+import pytest
+
 from raft_tpu.bench.data_export import export_csv
 from raft_tpu.bench.plot import _frontier, plot_report
 
@@ -140,3 +142,25 @@ def test_compute_efficiency_absent_ops_keys():
     assert "fused_stream_gbps_est" not in eff
     assert "cagra_fused_frac_of_measured_copy_bw" not in eff
     assert eff["mfu_vs_measured_peak"] == 0.1
+
+
+@pytest.mark.parametrize("failed", [{}, {"ivf_pq": "RuntimeError: boom"}])
+def test_bench_runs_only_on_tpu_and_fails_loud(monkeypatch, failed):
+    """No CPU fallback: off the TPU (and without an explicit smoke) the
+    bench refuses before any phase runs; with the smoke, a failed phase
+    exits 1 after the result line, a clean run exits normally."""
+    bench = _load_bench()
+    ran = []
+    monkeypatch.setattr(bench, "_bench_main", lambda: ran.append(1) or failed)
+    monkeypatch.delenv("RAFT_TPU_BENCH_SMOKE", raising=False)
+    with pytest.raises(SystemExit) as e:
+        bench.main()
+    assert e.value.code == 2 and not ran
+    monkeypatch.setenv("RAFT_TPU_BENCH_SMOKE", "1")
+    if failed:
+        with pytest.raises(SystemExit) as e:
+            bench.main()
+        assert e.value.code == 1
+    else:
+        bench.main()
+    assert ran == [1]

@@ -1,6 +1,7 @@
 """CAGRA tests — recall-threshold vs exact kNN (reference pattern:
 ``cpp/test/neighbors/ann_cagra.cuh``) plus unit checks on the graph
 optimizer (prune + reverse merge)."""
+import dataclasses
 import io
 
 import numpy as np
@@ -367,6 +368,44 @@ class TestFusedSearch:
             cagra.search(
                 index, Q, 10, CagraSearchParams(dedup="sort"), mode="fused"
             )
+
+    @pytest.mark.parametrize("row_chunk", [128, 700, 4096])
+    def test_neighbor_table_layout(self, nn_index, row_chunk):
+        """The in-place chunked fill (last chunk clamped to the end)
+        packs every node the same way at any chunk size: ``deg``
+        neighbor vectors, base-256 id digits of ``id + 1``, zero rows to
+        the tile boundary."""
+        from raft_tpu.ops.pallas.cagra_search import ID_ROWS, build_neighbor_table, table_rows
+
+        X, index = nn_index
+        g = np.asarray(index.graph)
+        deg = g.shape[1]
+        t = np.asarray(build_neighbor_table(X, g, dtype=np.float32, row_chunk=row_chunk))
+        assert t.shape == (X.shape[0], table_rows(deg), X.shape[1])
+        np.testing.assert_array_equal(t[:, :deg], X[np.clip(g, 0, None)])
+        digits = t[:, deg:deg + ID_ROWS, :deg].astype(np.int64)
+        ids = digits[:, 0] + 256 * digits[:, 1] + 65536 * digits[:, 2] - 1
+        np.testing.assert_array_equal(ids, g)
+        assert not t[:, deg + ID_ROWS:].any() and not t[:, deg:, deg:].any()
+
+    def test_table_that_cannot_fit_plans_xla(self, rng, nn_index, monkeypatch):
+        """An index whose packed table exceeds free HBM is not fused-
+        eligible: auto plans XLA with the reason in its plan, and an
+        explicit fused request fails up front — no allocation attempted."""
+        from raft_tpu import plan
+        from raft_tpu.ops.pallas import hbm_model
+
+        X, index = nn_index
+        sp = CagraSearchParams(itopk_size=64, search_width=4, dedup="post")
+        fresh = dataclasses.replace(index)  # no table cached on the copy
+        monkeypatch.setattr(hbm_model, "device_free_bytes", lambda device=None: 1024)
+        why = cagra.fused_ineligible_reason(fresh, sp)
+        assert "packed neighbor table" in why
+        p = plan.plan_cagra_mode(64, on_tpu=True, fused_ok=False, why_not_fused=why)
+        assert p.choice == "xla" and "packed neighbor table" in p.explain()
+        with pytest.raises(Exception, match="fused mode needs"):
+            cagra.search(fresh, _data(rng, 4, 16), 5, sp, mode="fused")
+        assert getattr(fresh, "_fused_table_cache", None) is None
 
     def test_vmem_model_matches_kernel_scratch_shapes(self):
         import jax.numpy as jnp

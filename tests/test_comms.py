@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from raft_tpu.parallel._compat import shard_map
+from jax import shard_map
 
 from raft_tpu.parallel import comms
 from raft_tpu.parallel.sharded_knn import sharded_knn

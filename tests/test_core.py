@@ -232,3 +232,33 @@ class TestAsArray:
         t = torch.arange(6, dtype=torch.float32).reshape(2, 3)
         a = as_array(t, ndim=2)
         np.testing.assert_allclose(np.asarray(a), t.numpy())
+
+
+class TestCompileCache:
+    """``$JAX_COMPILATION_CACHE_DIR`` wins untouched; otherwise the cache
+    goes to one fixed path inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        was = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", was)
+
+    def test_env_var_wins(self, monkeypatch, tmp_path):
+        from raft_tpu.core.compile_cache import use_compile_cache
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", "/unchanged")
+        assert use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == "/unchanged"
+
+    def test_checkout_path_otherwise(self, monkeypatch):
+        import os
+
+        from raft_tpu.core.compile_cache import CHECKOUT_CACHE_DIR, use_compile_cache
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        assert use_compile_cache() == CHECKOUT_CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == CHECKOUT_CACHE_DIR
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert CHECKOUT_CACHE_DIR == os.path.join(root, ".jax_cache")

@@ -1,7 +1,7 @@
 """Tier-1 gate: graft-lint must be clean over the repo's own code.
 
-Runs the full checker set over ``raft_tpu/`` (plus ``bench.py`` and
-``tools/``) and fails listing every unsuppressed violation. Known-safe
+Runs the full checker set over ``raft_tpu/`` (plus ``bench.py``,
+``chip_smoke.py``, ``tools/`` and ``tests/test_tpu_compile.py``) and fails listing every unsuppressed violation. Known-safe
 patterns carry inline ``# graft-lint: ignore[rule-id]`` suppressions at
 the offending line (see docs/static_analysis.md).
 
@@ -29,7 +29,9 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TARGETS = [
     os.path.join(REPO, "raft_tpu"),
     os.path.join(REPO, "bench.py"),
+    os.path.join(REPO, "chip_smoke.py"),
     os.path.join(REPO, "tools"),
+    os.path.join(REPO, "tests", "test_tpu_compile.py"),
 ]
 
 

@@ -158,6 +158,37 @@ def test_list_sizes_balanced(index):
     assert sizes.max() < avg * 4, sizes.max()
 
 
+def _slots_one_row_at_a_time(cand, n_lists, max_list):
+    """The capacity-capped assignment, written plainly: each pass walks
+    the unplaced rows in order into their pass-``c`` list while it has
+    room; the leftovers then take the free slots in order."""
+    n, n_cand = cand.shape
+    slot, used = [None] * n, [0] * n_lists
+    for c in range(n_cand):
+        for r in range(n):
+            lc = int(cand[r, c])
+            if slot[r] is None and used[lc] < max_list:
+                slot[r] = lc * max_list + used[lc]
+                used[lc] += 1
+    free = iter(sorted(set(range(n_lists * max_list)) - set(s for s in slot if s is not None)))
+    return np.array([next(free) if s is None else s for s in slot])
+
+
+@pytest.mark.parametrize("n,n_lists,n_cand,max_list", [
+    (600, 8, 3, 80),    # crowded: spills down the candidates and into the final pass
+    (2000, 32, 8, 64),  # every row fits by its candidates
+])
+def test_assign_slots_matches_plain_reference(n, n_lists, n_cand, max_list):
+    from raft_tpu.neighbors import ivf_common
+
+    rng = np.random.default_rng(n)
+    cand = (rng.zipf(1.3, (n, n_cand)) % n_lists).astype(np.int32)
+    got = np.asarray(ivf_common.assign_slots(jnp.asarray(cand), n_lists=n_lists,
+                                             max_list=max_list))
+    assert got.dtype == np.int32
+    np.testing.assert_array_equal(got, _slots_one_row_at_a_time(cand, n_lists, max_list))
+
+
 def test_query_batching(data, index):
     _, queries = data
     d1, i1 = ivf_flat.search(index, queries, K, n_probes=8, query_batch=64)

@@ -89,8 +89,10 @@ class TestIvfPqSearch:
         _, ref_i = _exact(X, Q, k)
         _, ann_i = ivf_pq.search(index, Q, k, IvfPqSearchParams(n_probes=16))
         recall = float(neighborhood_recall(np.asarray(ann_i), np.asarray(ref_i)))
-        # observed 0.816 (per_subspace) / 0.844 (per_cluster) at this
-        # operating point; floor set one regression-width below
+        # observed 0.792 (per_subspace) / 0.816 (per_cluster) at this
+        # operating point with greedy k-means++ codebook seeds under the
+        # installed JAX's random stream; floor set one regression-width
+        # below
         assert recall >= 0.78, f"recall {recall}"
 
     def test_recall_with_refine(self, rng):

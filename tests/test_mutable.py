@@ -892,6 +892,36 @@ class TestDeltaFusedScan:
         np.testing.assert_array_equal(np.asarray(i), np.asarray(i2))
         np.testing.assert_allclose(np.asarray(d), np.asarray(d2), rtol=1e-6, atol=1e-6)
 
+    def test_kernel_failure_degrades_counted(self, rng, monkeypatch):
+        """A failing fused delta kernel degrades to the exact scan — same
+        answer — and the degrade is counted under ``fallbacks``."""
+        from raft_tpu import obs
+        from raft_tpu.core.errors import KernelFailure
+        from raft_tpu.mutable import segments
+        from raft_tpu.robust import reset_warned
+
+        def broken(*args, **kwargs):
+            raise KernelFailure("injected")
+
+        mut = MutableIndex("brute_force", DIM, delta_mode="fused")
+        mut.insert(_rows(rng, 50))
+        queries = _rows(rng, 4)
+        d_ref, i_ref = dataclasses.replace(mut.snapshot(), delta_mode="exact").search(
+            queries, 8)
+        monkeypatch.setattr(segments, "_delta_fused_search", broken)
+        obs.enable()
+        obs.registry().reset()
+        reset_warned()
+        try:
+            with pytest.warns(RuntimeWarning, match="falling back"):
+                d, i = mut.search(queries, 8)
+            counts = obs.registry().as_dict()["counters"]
+        finally:
+            obs.disable()
+            obs.registry().reset()
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(i_ref))
+        assert counts['fallbacks{algo="mutable_delta",reason="KernelFailure"}'] == 1.0
+
     def test_routing_and_eligibility(self):
         from raft_tpu.mutable.segments import _delta_route
         from raft_tpu.ops.distance import DistanceType

@@ -26,7 +26,7 @@ from raft_tpu.neighbors import ivf_flat
 from raft_tpu.ops.pallas import ring_topk as rt
 from raft_tpu.ops.select_k import merge_parts
 from raft_tpu.parallel import make_mesh, sharded_ivf_flat_search
-from raft_tpu.parallel._compat import shard_map
+from jax import shard_map
 from raft_tpu.robust import faults, reset_warned
 
 
@@ -71,7 +71,7 @@ def _run_ring(mesh, vs, ins, k, select_min):
 
     @functools.partial(
         shard_map, mesh=mesh,
-        in_specs=(P("data"), P("data")), out_specs=(P(), P()),
+        in_specs=(P("data"), P("data")), out_specs=(P(), P()), check_vma=False,
     )
     def prog(vb, ib):
         return rt.ring_topk(vb[0], ib[0], k, select_min=select_min, axis="data")
@@ -168,6 +168,9 @@ class TestRingObsAndFaults:
         recvd = snap["counters"]['comms.ring.bytes{axis="data",direction="recv"}']
         # 3 RS hops x B=4 rows x k=8 x 12B + 3 AG hops x 4 x 8 x 8B
         assert sent == recvd == 3 * 4 * 8 * (rt.RS_ENTRY_BYTES + rt.AG_ENTRY_BYTES)
+        # on the wire the XLA engine ships the sort-key lane too (16B/entry)
+        wire = snap["counters"]['comms.ring.wire_bytes{axis="data",direction="send"}']
+        assert wire == 3 * 4 * 8 * (16 + rt.AG_ENTRY_BYTES)
 
     def test_fault_point_registered_and_fires(self, eight_devices):
         assert "comms.ring_topk" in faults.FAULT_POINTS
@@ -265,6 +268,43 @@ class TestFusedFold:
             rt.hop_merge(a, b, qt=32, interpret=True)
 
 
+class TestFusedRingInterpret:
+    """The remote-DMA ring kernels themselves, run by Pallas' TPU
+    interpreter on the CPU mesh with its race detector on: ids and values
+    must equal the ``ppermute`` engine's, and no DMA may land in a slot
+    its reader has not released."""
+
+    @pytest.mark.parametrize("n", [2, 4])
+    @pytest.mark.parametrize("engine", ["ring", "scan_ring"])
+    def test_matches_xla_engine_without_races(self, rng, monkeypatch, n, engine):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+        from jax._src.pallas.mosaic.interpret import interpret_pallas_call as ipc
+
+        pallas_call = pl.pallas_call
+
+        def interpreted(*args, **kwargs):
+            kwargs["interpret"] = pltpu.InterpretParams(detect_races=True)
+            return pallas_call(*args, **kwargs)
+
+        monkeypatch.setattr(rt.pl, "pallas_call", interpreted)
+        k, nq = 10, 37
+        kc = k if engine == "ring" else 3 * k
+        v, i = _shard_candidates(rng, n, nq, kc, demote=(1,))
+        fn = rt.ring_topk if engine == "ring" else rt.scan_ring_topk
+        mesh = make_mesh(jax.devices()[:n])
+        outs = []
+        for fused in (False, True):
+            step = jax.jit(shard_map(
+                lambda a, b, fused=fused: fn(a[0], b[0], k, axis="data", use_fused=fused),
+                mesh=mesh, in_specs=(P("data"), P("data")), out_specs=(P(), P()),
+                check_vma=False))
+            outs.append(jax.device_get(step(v, i)))
+        np.testing.assert_array_equal(outs[1][1], outs[0][1])
+        np.testing.assert_array_equal(outs[1][0], outs[0][0])
+        assert not ipc.races.races_found
+
+
 class TestResidencyModel:
     def test_scratch_shapes_match_vmem_model(self):
         """Drift guard: the kernel's declared scratch must be exactly the
@@ -282,10 +322,22 @@ class TestResidencyModel:
         for spec, r in zip(vmem, modeled):
             assert tuple(spec.shape) == tuple(r.shape), r.name
             assert jnp.dtype(spec.dtype).itemsize == r.itemsize, r.name
-        # the two non-VMEM entries are the DMA semaphore pairs
-        assert len(declared) - len(vmem) == 2
+        # the non-VMEM entries are the DMA semaphore pairs and the credits
+        assert len(declared) - len(vmem) == 3
         # and the whole kernel fits the plan comfortably
         assert res.total_bytes < 12 * 2**20
+
+    def test_fused_slots_count_on_the_wire(self):
+        # the fused kernel's DMA slots are a lane tile wide: at k=10 the
+        # wire carries 128 columns, 12.8x the payload's 10 (x 16/12 on
+        # the reduce-scatter's sort-key lane)
+        assert rt.lanes(10) == 128 and rt.lanes(129) == 256
+        n, B, k = 4, 32, 10
+        payload = (n - 1) * B * k * (rt.RS_ENTRY_BYTES + rt.AG_ENTRY_BYTES)
+        fused = rt.ring_wire_bytes(n, B, rt.lanes(k))
+        assert fused == (n - 1) * B * 128 * 24
+        assert fused / payload == pytest.approx(12.8 * 24 / 20)
+        assert rt.ring_wire_bytes(1, B, rt.lanes(k)) == 0.0
 
     def test_wire_model_reduction_at_8(self):
         ring = rt.wire_bytes_per_query(8, 10, "ring")

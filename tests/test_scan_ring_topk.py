@@ -30,7 +30,7 @@ from raft_tpu.neighbors import ivf_flat
 from raft_tpu.ops.pallas import ring_topk as rt
 from raft_tpu.ops.select_k import merge_parts
 from raft_tpu.parallel import make_mesh, sharded_ivf_flat_search
-from raft_tpu.parallel._compat import shard_map
+from jax import shard_map
 from raft_tpu.robust import faults, reset_warned
 
 
@@ -74,7 +74,7 @@ def _run_scan(mesh, vs, ins, k, select_min):
 
     @functools.partial(
         shard_map, mesh=mesh,
-        in_specs=(P("data"), P("data")), out_specs=(P(), P()),
+        in_specs=(P("data"), P("data")), out_specs=(P(), P()), check_vma=False,
     )
     def prog(vb, ib):
         return rt.scan_ring_topk(vb[0], ib[0], k, select_min=select_min, axis="data")
@@ -257,7 +257,7 @@ class TestScanResidencyModel:
         for spec, r in zip(vmem, modeled):
             assert tuple(spec.shape) == tuple(r.shape), r.name
             assert jnp.dtype(spec.dtype).itemsize == r.itemsize, r.name
-        assert len(declared) - len(vmem) == 2  # the DMA semaphore pairs
+        assert len(declared) - len(vmem) == 3  # DMA semaphore pairs + credits
         # kc=256 lands exactly on the 12 MiB plan (the wide input refs
         # dominate); kc=512 breaches — the binding pins the safe shape
         assert res.total_bytes <= int(16 * 2**20 * 0.75)

@@ -165,14 +165,15 @@ class TestDistKMeansCommFusion:
         from jax.sharding import PartitionSpec as P
 
         from raft_tpu.cluster.kmeans import flash_norm_cache
-        from raft_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from raft_tpu.parallel.sharded_ann import dist_lloyd_step
 
         init = jnp.asarray(X[: self.N_LISTS])
         n_lists, iters = self.N_LISTS, self.ITERS
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(P(), P("data")), out_specs=P()
+            shard_map, mesh=mesh, in_specs=(P(), P("data")), out_specs=P(),
+            check_vma=False,
         )
         def run(c0, xl):
             cache = flash_norm_cache(xl, DistanceType.L2Expanded)
@@ -244,14 +245,15 @@ class TestDistKMeansCommAvoiding:
         from jax.sharding import PartitionSpec as P
 
         from raft_tpu.cluster.kmeans import flash_norm_cache
-        from raft_tpu.parallel._compat import shard_map
+        from jax import shard_map
         from raft_tpu.parallel.sharded_ann import dist_lloyd_step
 
         init = jnp.asarray(X[: self.N_LISTS])
         n_lists, iters = self.N_LISTS, self.ITERS
 
         @functools.partial(
-            shard_map, mesh=mesh, in_specs=(P(), P("data")), out_specs=P()
+            shard_map, mesh=mesh, in_specs=(P(), P("data")), out_specs=P(),
+            check_vma=False,
         )
         def run(c0, xl):
             cache = flash_norm_cache(xl, DistanceType.L2Expanded)

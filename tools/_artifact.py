@@ -1,11 +1,9 @@
 """Timestamped sweep artifacts under ``artifacts/tpu/`` (a TRACKED
-directory, unlike ``bench_artifacts/`` which bench.py overwrites): a
-wedged chip at round end must not erase mid-round measurements — commit
-these as they land.
+directory, unlike ``bench_artifacts/`` which bench.py overwrites).
 
 Use :class:`Recorder` and call ``add(row)`` after EVERY measured config:
-the JSON file is rewritten incrementally, so a sweep killed halfway (the
-known TPU stall mode) still leaves every completed row on disk."""
+the JSON file is rewritten incrementally, so a sweep killed halfway
+still leaves every completed row on disk."""
 import json
 import os
 import time

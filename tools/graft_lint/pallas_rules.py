@@ -63,12 +63,16 @@ KERNEL_SHAPE_BINDINGS: Dict[str, Dict[str, object]] = {
     "ivf_scan": dict(qt=128, k=10, d=128, m=1152, w=1024),
     # the fused CAGRA beam kernel at the 1M-row bench shape
     # (vmem_model.cagra_search_residency defaults)
-    "cagra_search": dict(qt=32, itopk=160, width=8, deg=16, d=128),
+    "cagra_search": dict(qt=32, itopk=160, width=8, deg=16, d=128,
+                         # cagra_search.table_rows: deg + 3 id rows, tiled
+                         table_rows=lambda deg: -(-(deg + 3) // 8) * 8),
     # the ICI ring top-k exchange at the 8-chip serving shape
     # (vmem_model.ring_topk_residency: n devices, B block rows, w = k;
     # kc = the scan-fused variant's candidate-tile width — 2k is the
     # widest that fits the 75% VMEM plan, see scan_ring_topk_residency)
-    "ring_topk": dict(n=8, B=128, w=128, qt=32, kc=256),
+    "ring_topk": dict(n=8, B=128, w=128, qt=32, kc=256,
+                      # ring_topk.lanes: DMA slots hold whole lane tiles
+                      lanes=lambda w: -(-w // 128) * 128),
     # tools/micro_layout.py — the layout microbench kernel
     "micro_layout": dict(QT=128, D=128, M=8704, block=(1, 8704, 128)),
 }

@@ -7,6 +7,7 @@ implicit in-kernel transpose of the RHS, not DMA or merge work.
 """
 import functools
 import os
+import sys
 import time
 
 import jax
@@ -16,7 +17,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-jax.config.update("jax_compilation_cache_dir", os.path.expanduser("~/.cache/jax_comp"))
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+from raft_tpu.core.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
 
 QT, D = 128, 128
 M = 8704  # rows per block (128-multiple)

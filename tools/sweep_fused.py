@@ -20,8 +20,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-jax.config.update("jax_compilation_cache_dir", os.path.expanduser("~/.cache/jax_comp"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 2)
+from raft_tpu.core.compile_cache import use_compile_cache  # noqa: E402
+
+use_compile_cache()
 
 from raft_tpu.neighbors import brute_force, ivf_flat  # noqa: E402
 from raft_tpu.ops.distance import DistanceType  # noqa: E402
