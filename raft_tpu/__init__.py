@@ -4,8 +4,8 @@ algorithms with the capabilities of RAPIDS RAFT (reference: shrshi/raft
 
 Layer map (bottom-up; see SURVEY.md):
 
-* ``raft_tpu.core``      — resources, errors, logging, tracing, serialize,
-                           bitsets, interruptible (L1).
+* ``raft_tpu.core``      — resources, errors, logging, serialize, bitsets,
+                           interruptible (L1).
 * ``raft_tpu.utils``     — tiling/alignment math (L2 concepts).
 * ``raft_tpu.ops``       — primitives: pairwise distance, select_k, fused
                            1-NN, linalg, matrix ops (L4).

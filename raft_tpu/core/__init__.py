@@ -1,4 +1,4 @@
-"""Core runtime layer (L1 analog): resources, errors, logging, tracing,
+"""Core runtime layer (L1 analog): resources, errors, logging,
 serialization, bitsets, interruptible cancellation, array ingestion.
 
 See ``SURVEY.md`` §2.1 for the reference component map
@@ -8,7 +8,7 @@ from raft_tpu.core.array import as_array, check_dtype_one_of, check_matching_dim
 from raft_tpu.core.bitset import Bitmap, Bitset, popcount32
 from raft_tpu.core.errors import LogicError, RaftError, expects, fail
 from raft_tpu.core.resources import Resources, default_resources, ensure_resources
-from raft_tpu.core import interruptible, logging, serialize, tracing
+from raft_tpu.core import interruptible, logging, serialize
 
 __all__ = [
     "as_array",
@@ -27,5 +27,4 @@ __all__ = [
     "interruptible",
     "logging",
     "serialize",
-    "tracing",
 ]

@@ -264,7 +264,7 @@ def search(
                 refine(dataset, queries, cand, k, metric=index.metric,
                        metric_arg=index.metric_arg)
             )
-    if not obs.is_enabled():
+    if not obs.active():
         return _search_dispatch(
             index, queries, k, prefilter, query_batch, dataset_tile, mode, recall_target, res
         )

@@ -884,8 +884,9 @@ def search(
     With observability on (:mod:`raft_tpu.obs`, ``RAFT_TPU_OBS=1``) the
     call records a sync-aware ``cagra.search`` span with per-batch
     children, the mode chosen (fused vs xla), iterations executed, and
-    beam occupancy; disabled (the default) it costs one flag check."""
-    if not obs.is_enabled():
+    beam occupancy; under a profiler session the spans are annotations
+    that never block; with neither (the default) it costs one check."""
+    if not obs.active():
         return _search_dispatch(
             index, queries, k, params, prefilter, query_batch, res, mode, **kwargs
         )

@@ -142,7 +142,7 @@ def search(
     With :mod:`raft_tpu.obs` enabled the call is wrapped in an
     ``hnsw.search`` span (the nested ``cagra.search`` span shows the
     delegated traversal) with call/query counters."""
-    if not obs.is_enabled():
+    if not obs.active():
         v, i = cagra_search(
             index.to_cagra(), queries, k, CagraSearchParams(itopk_size=max(ef, k))
         )

@@ -639,11 +639,44 @@ def search(
     All draw from the same probed candidate set; fused/scan select with an
     approximate top-k (lane-group PartialReduce), so results can differ
     slightly from the deterministic probe path. Fused accepts
-    ``params.fused_*`` tuning knobs and runs in interpret mode off-TPU."""
+    ``params.fused_*`` tuning knobs and runs in interpret mode off-TPU.
+
+    With observability on (:mod:`raft_tpu.obs`, ``RAFT_TPU_OBS=1``) the
+    call records a sync-aware ``ivf_flat.search`` span with ``put`` and
+    ``refine`` children; under a profiler session the same spans are
+    annotations that never block; with neither (the default) it costs
+    one check."""
+    if not obs.active():
+        return _search_dispatch(
+            index, queries, k, params, prefilter, query_batch, mode, res, dataset, **kwargs
+        )
+    with obs.span("ivf_flat.search", k=k, nq=int(np.shape(queries)[0])) as sp:
+        return sp.sync(
+            _search_dispatch(
+                index, queries, k, params, prefilter, query_batch, mode, res, dataset, **kwargs
+            )
+        )
+
+
+def _search_dispatch(
+    index: IvfFlatIndex,
+    queries,
+    k: int,
+    params: Optional[IvfFlatSearchParams],
+    prefilter: Optional[Bitset],
+    query_batch: int,
+    mode: str,
+    res: Optional[Resources],
+    dataset,
+    **kwargs,
+) -> Tuple[jax.Array, jax.Array]:
+    """Mode routing + query batching behind :func:`search` (split out so
+    the span-off path costs a single check)."""
     ensure_resources(res)
     if params is None:
         params = IvfFlatSearchParams(**kwargs)
-    queries = jnp.asarray(queries)
+    with obs.span("ivf_flat.search.put"):
+        queries = jnp.asarray(queries)
     expects(queries.ndim == 2 and queries.shape[1] == index.dim, "bad query shape")
     expects(k >= 1, "k must be >= 1")
     if dataset is not None and params.refine_ratio > 1:

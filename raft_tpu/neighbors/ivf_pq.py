@@ -1581,11 +1581,12 @@ def search(
 
     With observability on (:mod:`raft_tpu.obs`, ``RAFT_TPU_OBS=1``) the
     call records a sync-aware ``ivf_pq.search`` span with per-phase
-    children (``coarse_probe`` / ``pq_scan`` / ``probe_scan`` /
+    children (``put`` / ``coarse_probe`` / ``pq_scan`` / ``probe_scan`` /
     ``fused`` / ``refine``) plus counters for mode, n_probes, LUT dtype
-    and refine candidates; disabled (the default) it costs one flag
-    check."""
-    if not obs.is_enabled():
+    and refine candidates; under a profiler session the same spans are
+    annotations that never block; with neither (the default) it costs
+    one check."""
+    if not obs.active():
         return _search_dispatch(
             index, queries, k, params, prefilter, query_batch, mode, res, dataset, **kwargs
         )
@@ -1614,7 +1615,8 @@ def _search_dispatch(
     ensure_resources(res)
     if params is None:
         params = IvfPqSearchParams(**kwargs)
-    queries = jnp.asarray(queries)
+    with obs.span("ivf_pq.search.put"):
+        queries = jnp.asarray(queries)
     expects(queries.ndim == 2 and queries.shape[1] == index.dim, "bad query shape")
     expects(k >= 1, "k must be >= 1")
     if dataset is not None and params.refine_ratio > 1:

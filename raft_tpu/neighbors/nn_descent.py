@@ -156,7 +156,7 @@ def build(
     an iterations-to-convergence histogram."""
     if params is None:
         params = NNDescentParams(**kwargs)
-    if not obs.is_enabled():
+    if not obs.active():
         return _build_impl(dataset, params, res)
     n = int(np.shape(dataset)[0])
     obs.inc("nn_descent.build.calls")

@@ -156,7 +156,7 @@ def refine(
 
     Returns ``(distances [n_queries, k], indices [n_queries, k])``.
     """
-    if not obs.is_enabled():
+    if not obs.active():
         return _refine_dispatch(
             dataset, queries, candidates, k, metric, metric_arg, query_batch
         )

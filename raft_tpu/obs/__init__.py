@@ -1,5 +1,6 @@
-"""raft_tpu.obs — query-path observability: metrics registry,
-device-sync-aware spans, and Perfetto/Chrome-trace export.
+"""raft_tpu.obs — query-path observability: metrics registry, spans on
+the profiler's clock (device-sync-aware when recorded), and
+Perfetto/Chrome-trace export.
 
 Facade re-exporting the pieces the instrumented layers use::
 
@@ -11,9 +12,10 @@ Facade re-exporting the pieces the instrumented layers use::
     if obs.is_enabled():
         obs.inc("ivf_pq.search.calls", mode=mode)
 
-Disabled by default; enable with ``RAFT_TPU_OBS=1`` or
-``obs.enable()``. See ``docs/observability.md`` for the metric/span
-taxonomy and ``tools/obs_report.py`` for the artifact summarizer.
+Recording is off by default; enable it with ``RAFT_TPU_OBS=1`` or
+``obs.enable()``. Spans also annotate any ``jax.profiler`` session. See
+``docs/observability.md`` for the metric/span taxonomy and
+``tools/obs_report.py`` for the artifact summarizer.
 """
 from raft_tpu.obs.export import (
     chrome_trace,
@@ -46,7 +48,7 @@ from raft_tpu.obs.request import (
 from raft_tpu.obs import recorder, timeseries
 from raft_tpu.obs.recorder import FlightRecorder, list_bundles, load_bundle
 from raft_tpu.obs.slo import SLO, SloStatus, SloTracker
-from raft_tpu.obs.spans import Span, span, traced
+from raft_tpu.obs.spans import Span, active, span
 from raft_tpu.obs.timeseries import (
     Anomaly,
     EwmaDetector,
@@ -73,6 +75,7 @@ __all__ = [
     "SloTracker",
     "Span",
     "TimeSeries",
+    "active",
     "chrome_trace",
     "current_trace",
     "default_detectors",
@@ -92,7 +95,6 @@ __all__ = [
     "span",
     "timeseries",
     "trace_scope",
-    "traced",
     "validate_trace",
     "write_metrics_jsonl",
     "write_trace",

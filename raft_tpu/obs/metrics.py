@@ -9,13 +9,12 @@ registry the hot paths (``ivf_pq.search``, ``cagra.search``,
 ``brute_force``, ``cluster/kmeans``, ``parallel/comms``) write into,
 dumpable as a dict, JSONL, or Prometheus text exposition.
 
-Like :mod:`raft_tpu.core.tracing` (env ``RAFT_TPU_TRACING``) the whole
-subsystem is gated on one process-wide flag — env ``RAFT_TPU_OBS``,
-**default off** — and the disabled path allocates nothing: every
-recording helper checks :func:`is_enabled` first and returns before any
-metric object, label tuple, or span record is created. Instrumented
-call sites keep overhead unmeasurable (<1%) by guarding whole blocks
-with ``if obs.is_enabled():``.
+The whole subsystem is gated on one process-wide flag — env
+``RAFT_TPU_OBS``, **default off** — and the disabled path allocates
+nothing: every recording helper checks :func:`is_enabled` first and
+returns before any metric object, label tuple, or span record is
+created. Instrumented call sites keep overhead unmeasurable (<1%) by
+guarding whole blocks with ``if obs.is_enabled():``.
 
 Metric identity is ``(kind, name, sorted labels)``; names are
 dot-separated (``ivf_pq.search.calls``), labels are ``str -> str``

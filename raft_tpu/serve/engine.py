@@ -20,9 +20,12 @@ kernels were built for:
   degraded response carrying ``coverage < 1.0`` instead of a timeout;
 * the whole path is instrumented with :mod:`raft_tpu.obs`
   (``serve.queue_depth`` gauge, ``serve.time_in_queue_ms`` /
-  ``serve.batch_fill`` histograms, ``serve.rejections`` counter,
-  ``serve.dispatch`` spans) and chaos-testable at the
-  ``serve.dispatch`` fault seam (:mod:`raft_tpu.robust.faults`).
+  ``serve.batch_fill`` histograms, ``serve.rejections`` counter, and a
+  span per phase of a step: ``serve.step`` over ``serve.maintenance``,
+  ``serve.assemble`` and ``serve.dispatch``, which holds
+  ``serve.launch``, ``serve.wait`` and ``serve.fetch``, then
+  ``serve.complete``) and chaos-testable at the ``serve.dispatch``
+  fault seam (:mod:`raft_tpu.robust.faults`).
 
 The engine is **synchronous by design**: :meth:`step` processes at most
 one micro-batch on the caller's thread and :meth:`run_until_idle`
@@ -38,6 +41,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from raft_tpu import obs
@@ -524,26 +528,29 @@ class ServingEngine:
         ``max_wait_ms``) or unconditionally with ``force=True``.
         Returns the number of requests completed (including deadline
         rejections)."""
-        now = self.batcher.now()
-        if now - self._last_maint >= self.maintenance_interval_ms / 1e3:
-            self._last_maint = now
-            self.maintenance_tick()
-        if not self.batcher.ready(now) and not (force and self.batcher.depth_requests()):
-            return 0
-        batch, expired = self.batcher.next_batch(now)
-        for r in expired:
-            obs.inc("serve.rejections", reason="deadline_expired",
-                    index_id=r.group[0])
-            tracker = self._slos.get(r.group[0])
-            if tracker is not None:
-                tracker.record(ok=False)  # shed work burns the budget
-        done = len(expired)
-        if batch:
-            self._dispatch(batch, now)
-            done += len(batch)
-        if obs.is_enabled():
-            obs.set_gauge("serve.queue_depth", self.batcher.depth_rows())
-        return done
+        with obs.span("serve.step"):
+            now = self.batcher.now()
+            if now - self._last_maint >= self.maintenance_interval_ms / 1e3:
+                self._last_maint = now
+                self.maintenance_tick()
+            if not self.batcher.ready(now) and not (force and self.batcher.depth_requests()):
+                return 0
+            with obs.span("serve.assemble"):
+                batch, expired = self.batcher.next_batch(now)
+                shaped = self._assemble(batch) if batch else None
+            for r in expired:
+                obs.inc("serve.rejections", reason="deadline_expired",
+                        index_id=r.group[0])
+                tracker = self._slos.get(r.group[0])
+                if tracker is not None:
+                    tracker.record(ok=False)  # shed work burns the budget
+            done = len(expired)
+            if batch:
+                self._dispatch(batch, now, *shaped)
+                done += len(batch)
+            if obs.is_enabled():
+                obs.set_gauge("serve.queue_depth", self.batcher.depth_rows())
+            return done
 
     def run_until_idle(self, max_steps: int = 1_000_000) -> int:
         """Drive :meth:`step` until the queue is empty; returns requests
@@ -662,14 +669,15 @@ class ServingEngine:
         from :meth:`step` (rate-limited by ``maintenance_interval_ms``)
         so serving loops get background maintenance for free; callable
         directly by deployments with their own schedulers."""
-        for reg in list(self._indexes.values()):
-            if reg.compactor is not None:
-                reg.compactor.tick()
-        self._replan_tick()
-        # flight-recorder sampler tick: retains the serving time series
-        # and drains any fault-latched dump; no-op unless a recorder is
-        # installed AND obs is enabled
-        obs.recorder.tick()
+        with obs.span("serve.maintenance"):
+            for reg in list(self._indexes.values()):
+                if reg.compactor is not None:
+                    reg.compactor.tick()
+            self._replan_tick()
+            # flight-recorder sampler tick: retains the serving time series
+            # and drains any fault-latched dump; no-op unless a recorder is
+            # installed AND obs is enabled
+            obs.recorder.tick()
 
     def shutdown(self, wait: bool = True) -> None:
         """Stop every engine-owned background compactor. Queued
@@ -863,38 +871,39 @@ class ServingEngine:
 
         if not _plan.is_enabled():
             return
-        for reg in list(self._indexes.values()):
-            rp = reg.plan
-            if rp is None:
-                continue
-            traffic = _plan.traffic_from_counts(
-                reg.bucket_counts, reg.ewma_rows_per_s)
-            rows = self._corpus_rows(reg)
-            if not _plan.needs_replan(rp, rows, traffic):
-                continue
-            new = self._plan_registration(
-                reg, k=reg.last_k, traffic=traffic, epoch=rp.epoch + 1)
-            if new is None:
-                continue
-            if rp.same_decisions(new):
-                # drift acknowledged, decisions unchanged: re-anchor
-                # without burning an epoch (or a compile)
-                reg.plan = dataclasses.replace(new, epoch=rp.epoch)
-                obs.inc("serve.plan.recosts", index_id=reg.index_id)
-                continue
-            with obs.span("plan.flip", index_id=reg.index_id,
-                          epoch=new.epoch, algo=reg.algo):
-                self._warm_plan(reg, new)
-                # one assignment: a concurrent dispatch reads the old
-                # plan or the new one, never a mix
-                reg.plan = new
-            reg.bucket_counts = {}
-            obs.inc("serve.plan_flips", index_id=reg.index_id)
-            obs.set_gauge("serve.plan.epoch", float(new.epoch),
-                          index_id=reg.index_id)
-            # flight-recorder trigger: the swap is complete and no
-            # engine lock is held here
-            obs.recorder.note_plan_flip(reg.index_id, int(new.epoch))
+        with obs.span("plan.replan"):
+            for reg in list(self._indexes.values()):
+                rp = reg.plan
+                if rp is None:
+                    continue
+                traffic = _plan.traffic_from_counts(
+                    reg.bucket_counts, reg.ewma_rows_per_s)
+                rows = self._corpus_rows(reg)
+                if not _plan.needs_replan(rp, rows, traffic):
+                    continue
+                new = self._plan_registration(
+                    reg, k=reg.last_k, traffic=traffic, epoch=rp.epoch + 1)
+                if new is None:
+                    continue
+                if rp.same_decisions(new):
+                    # drift acknowledged, decisions unchanged: re-anchor
+                    # without burning an epoch (or a compile)
+                    reg.plan = dataclasses.replace(new, epoch=rp.epoch)
+                    obs.inc("serve.plan.recosts", index_id=reg.index_id)
+                    continue
+                with obs.span("plan.flip", index_id=reg.index_id,
+                              epoch=new.epoch, algo=reg.algo):
+                    self._warm_plan(reg, new)
+                    # one assignment: a concurrent dispatch reads the old
+                    # plan or the new one, never a mix
+                    reg.plan = new
+                reg.bucket_counts = {}
+                obs.inc("serve.plan_flips", index_id=reg.index_id)
+                obs.set_gauge("serve.plan.epoch", float(new.epoch),
+                              index_id=reg.index_id)
+                # flight-recorder trigger: the swap is complete and no
+                # engine lock is held here
+                obs.recorder.note_plan_flip(reg.index_id, int(new.epoch))
 
     def _build_program(self, reg: _Registration, bucket: int, k: int,
                        plan=None) -> Callable:
@@ -963,17 +972,23 @@ class ServingEngine:
 
         return sharded_prog
 
-    def _dispatch(self, batch: Sequence[Request], now: float) -> None:
-        """Pad the batch to its bucket, fetch the compiled program, run
-        it, and complete every request's future. A dispatch failure
+    def _assemble(self, batch: Sequence[Request]) -> Tuple[int, int, np.ndarray]:
+        """The batch's row count, its bucket, and its requests' query
+        rows concatenated and padded to the bucket: ``(n, bucket,
+        padded)``."""
+        rows = np.concatenate([r.queries for r in batch], axis=0)
+        n = rows.shape[0]
+        bucket = bucket_for(n, self.max_batch)
+        return n, bucket, pad_rows(rows, bucket)
+
+    def _dispatch(self, batch: Sequence[Request], now: float, n: int, bucket: int,
+                  padded: np.ndarray) -> None:
+        """Fetch the batch's compiled program, run it on the ``padded``
+        rows, and complete every request's future. A dispatch failure
         fails this batch's futures — typed and visible — and the engine
         keeps serving."""
         reg = self._reg(batch[0].group[0])
         k = batch[0].group[1]
-        rows = np.concatenate([r.queries for r in batch], axis=0)
-        n = rows.shape[0]
-        bucket = bucket_for(n, self.max_batch)
-        padded = pad_rows(rows, bucket)
         # one snapshot per micro-batch: every request in the batch sees
         # the same immutable view, and writers never race the dispatch
         snap = reg.index.snapshot() if reg.algo == "mutable" else None
@@ -1006,26 +1021,34 @@ class ServingEngine:
             if obs.is_enabled() else obs.NULL_SCOPE
         )
         try:
-            program = self.cache.get(
-                key, lambda: self._build_program(reg, bucket, k)
-            )
-            # the chaos seam: one host-level hook before the device work
-            faults.fire(
-                "serve.dispatch",
-                index_id=reg.index_id, algo=reg.algo, bucket=bucket, rows=n,
-            )
-            t0 = time.perf_counter()
             with scope, obs.span(
                 "serve.dispatch", algo=reg.algo, bucket=bucket, rows=n, k=k
-            ) as sp:
-                out = program(padded, snap) if snap is not None else program(padded)
-                sp.sync(tuple(out))
+            ):
+                with obs.span("serve.launch"):
+                    program = self.cache.get(
+                        key, lambda: self._build_program(reg, bucket, k)
+                    )
+                    # the chaos seam: one host-level hook before the device work
+                    faults.fire(
+                        "serve.dispatch",
+                        index_id=reg.index_id, algo=reg.algo, bucket=bucket, rows=n,
+                    )
+                    t0 = time.perf_counter()
+                    out = program(padded, snap) if snap is not None else program(padded)
+                    # queue the copies back behind the program now, so the
+                    # wait below adds no host round trip before them
+                    for a in tuple(out):
+                        if isinstance(a, jax.Array):
+                            a.copy_to_host_async()
+                with obs.span("serve.wait"):
+                    jax.block_until_ready(tuple(out))
+                with obs.span("serve.fetch"):
+                    d_np = np.asarray(out.distances if hasattr(out, "distances") else out[0])
+                    i_np = np.asarray(out.indices if hasattr(out, "indices") else out[1])
+            self.batcher.note_service_time(time.perf_counter() - t0)
             coverage, degraded, failed = 1.0, False, ()
             if hasattr(out, "coverage"):  # DegradedResult from sharded paths
                 coverage, degraded, failed = out.coverage, out.degraded, out.failed_shards
-            d_np = np.asarray(out.distances if hasattr(out, "distances") else out[0])
-            i_np = np.asarray(out.indices if hasattr(out, "indices") else out[1])
-            self.batcher.note_service_time(time.perf_counter() - t0)
         except Exception as e:
             obs.inc("serve.dispatch_errors", index_id=reg.index_id,
                     kind=type(e).__name__)
@@ -1034,49 +1057,50 @@ class ServingEngine:
                 if tracker is not None:
                     tracker.record(ok=False)
             return
-        if obs.is_enabled():
-            obs.inc("serve.batches", index_id=reg.index_id, algo=reg.algo)
-            obs.observe("serve.batch_fill", n / bucket)
-            obs.observe("serve.batch_rows", float(n))
-            # per-index result coverage (1.0 unless a sharded path
-            # degraded) — the coverage-drop drift detector's input
-            obs.set_gauge("serve.coverage", float(coverage),
-                          index_id=reg.index_id)
-            if snap is not None:
-                obs.set_gauge("serve.generation", float(generation),
-                              index_id=reg.index_id)
-        t_done = self.batcher.now() if tracker is not None else now
-        off = 0
-        for r in batch:
-            m = r.n_rows
-            tiq_ms = (now - r.t_arrival) * 1e3
+        with obs.span("serve.complete"):
             if obs.is_enabled():
-                obs.observe("serve.time_in_queue_ms", tiq_ms,
-                            trace_id=r.trace_id or None)
-                if r.trace_id:
-                    # synthetic per-request queue span on its own track
-                    # (tid derived from req_id): the first hop of the
-                    # request's flow chain in the Perfetto export
-                    obs.registry().record_span(
-                        "serve.queue", r.t_submit_us, max(tiq_ms, 0.0) * 1e3,
-                        0x40000000 + (r.req_id % 0x3FFFFFFF), 0,
-                        {"index_id": reg.index_id, "rows": m},
-                        trace=(r.trace_id,),
+                obs.inc("serve.batches", index_id=reg.index_id, algo=reg.algo)
+                obs.observe("serve.batch_fill", n / bucket)
+                obs.observe("serve.batch_rows", float(n))
+                # per-index result coverage (1.0 unless a sharded path
+                # degraded) — the coverage-drop drift detector's input
+                obs.set_gauge("serve.coverage", float(coverage),
+                              index_id=reg.index_id)
+                if snap is not None:
+                    obs.set_gauge("serve.generation", float(generation),
+                                  index_id=reg.index_id)
+            t_done = self.batcher.now() if tracker is not None else now
+            off = 0
+            for r in batch:
+                m = r.n_rows
+                tiq_ms = (now - r.t_arrival) * 1e3
+                if obs.is_enabled():
+                    obs.observe("serve.time_in_queue_ms", tiq_ms,
+                                trace_id=r.trace_id or None)
+                    if r.trace_id:
+                        # synthetic per-request queue span on its own track
+                        # (tid derived from req_id): the first hop of the
+                        # request's flow chain in the Perfetto export
+                        obs.registry().record_span(
+                            "serve.queue", r.t_submit_us, max(tiq_ms, 0.0) * 1e3,
+                            0x40000000 + (r.req_id % 0x3FFFFFFF), 0,
+                            {"index_id": reg.index_id, "rows": m},
+                            trace=(r.trace_id,),
+                        )
+                r.future.set_result(
+                    ServeResult(
+                        distances=d_np[off : off + m],
+                        indices=i_np[off : off + m],
+                        coverage=float(coverage),
+                        degraded=bool(degraded),
+                        failed_shards=tuple(failed),
+                        time_in_queue_ms=tiq_ms,
+                        bucket=bucket,
+                        batch_rows=n,
+                        generation=generation,
+                        trace_id=r.trace_id,
                     )
-            r.future.set_result(
-                ServeResult(
-                    distances=d_np[off : off + m],
-                    indices=i_np[off : off + m],
-                    coverage=float(coverage),
-                    degraded=bool(degraded),
-                    failed_shards=tuple(failed),
-                    time_in_queue_ms=tiq_ms,
-                    bucket=bucket,
-                    batch_rows=n,
-                    generation=generation,
-                    trace_id=r.trace_id,
                 )
-            )
-            if tracker is not None:
-                tracker.record(latency_ms=(t_done - r.t_arrival) * 1e3)
-            off += m
+                if tracker is not None:
+                    tracker.record(latency_ms=(t_done - r.t_arrival) * 1e3)
+                off += m

@@ -137,15 +137,6 @@ def test_span_records_even_when_body_raises(obs_on):
     assert len(obs_on.spans("boom")) == 1
 
 
-def test_traced_decorator(obs_on):
-    @obs.traced("my.fn")
-    def f(a):
-        return a + 1
-
-    assert f(1) == 2
-    assert len(obs_on.spans("my.fn")) == 1
-
-
 # -- chrome-trace export ----------------------------------------------------
 
 

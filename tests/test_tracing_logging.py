@@ -1,22 +1,9 @@
-"""core.tracing (NVTX-range analogs) and core.logging (spdlog analog):
-enable/disable zero-cost paths, annotation labels, callback sink,
-pattern, and level round-trips.
+"""core.logging (spdlog analog): callback sink, pattern, and level
+round-trips.
 """
-import contextlib
-
-import jax.numpy as jnp
 import pytest
 
 from raft_tpu.core import logging as rlog
-from raft_tpu.core import tracing
-
-
-@pytest.fixture
-def tracing_state():
-    """Save/restore the module-global tracing toggle."""
-    was = tracing.is_enabled()
-    yield
-    tracing.enable(was)
 
 
 @pytest.fixture
@@ -25,94 +12,6 @@ def logging_state():
     yield
     rlog.set_callback(None)
     rlog.set_level(rlog.LEVEL_INFO)
-
-
-# -- tracing ----------------------------------------------------------------
-
-
-def test_enable_disable_round_trip(tracing_state):
-    tracing.enable(False)
-    assert not tracing.is_enabled()
-    tracing.enable()
-    assert tracing.is_enabled()
-
-
-def test_push_range_enabled_and_disabled(tracing_state):
-    for flag in (True, False):
-        tracing.enable(flag)
-        with tracing.push_range("unit.range"):
-            x = jnp.arange(4.0) + 1
-        assert float(x.sum()) == 10.0
-    # the RAII alias from the reference is the same contextmanager
-    assert tracing.range is tracing.push_range
-
-
-def test_push_range_disabled_is_bare_yield(tracing_state, monkeypatch):
-    """Zero-cost when off: the profiler annotation must not be built."""
-    import jax
-
-    calls = []
-
-    class Boom:
-        def __init__(self, name):
-            calls.append(name)
-
-        def __enter__(self):
-            return self
-
-        def __exit__(self, *exc):
-            return False
-
-    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Boom)
-    tracing.enable(False)
-    with tracing.push_range("off"):
-        pass
-    assert calls == []
-    tracing.enable(True)
-    with tracing.push_range("on"):
-        pass
-    assert calls == ["on"]
-
-
-def test_annotate_labels_and_passthrough(tracing_state, monkeypatch):
-    import jax
-
-    seen = []
-    monkeypatch.setattr(
-        jax.profiler,
-        "TraceAnnotation",
-        lambda name: (seen.append(name), contextlib.nullcontext())[1],
-    )
-
-    @tracing.annotate()
-    def work(a, b=1):
-        return a + b
-
-    @tracing.annotate("custom.label")
-    def other():
-        return 7
-
-    tracing.enable(True)
-    assert work(2, b=3) == 5
-    assert other() == 7
-    assert seen == [f"raft_tpu::{work.__wrapped__.__qualname__}", "custom.label"]
-    assert work.__name__ == "work"  # functools.wraps preserved
-
-    seen.clear()
-    tracing.enable(False)
-    assert work(1) == 2  # disabled: plain call, no annotation objects
-    assert seen == []
-
-
-def test_named_scope(tracing_state):
-    tracing.enable(False)
-    assert isinstance(tracing.named_scope("off"), contextlib.nullcontext)
-    tracing.enable(True)
-    scope = tracing.named_scope("hlo.scope")
-    assert not isinstance(scope, contextlib.nullcontext)
-    with scope:
-        y = jnp.ones((2,)) * 2
-    assert float(y[0]) == 2.0
 
 
 # -- logging ----------------------------------------------------------------
