@@ -183,7 +183,7 @@ class IvfPqSearchParams:
     # max multi-hot columns materialized per decode chunk (VMEM bound for
     # wide codebooks: K = pq_dim * ksub columns total); 0 = single pass.
     # Always further capped by a VMEM model of the kernel
-    # (pq_scan.vmem_decode_cols) so long lists cannot blow the ~16 MB
+    # (pq_scan.vmem_decode_cols) so long lists cannot blow its
     # scoped-VMEM stack.
     fused_decode_cols: int = 2048
 
@@ -1700,7 +1700,7 @@ def _search_dispatch(
         obs.observe("ivf_pq.search.n_probes", float(n_probes))
 
     if mode == "fused":
-        from raft_tpu.ops.pallas.pq_scan import ivf_pq_fused_search, vmem_decode_cols
+        from raft_tpu.ops.pallas.pq_scan import fused_subtiles, ivf_pq_fused_search
 
         if wants_f32_lut:
             # auto routes f32-LUT requests to the scan path; an EXPLICIT
@@ -1740,7 +1740,17 @@ def _search_dispatch(
         while index.n_lists % group:
             group -= 1
 
+        fused_model = dict(
+            m=index.codes.shape[1], code_mode=code_mode, ksub=ksub,
+            bpr=index.codes.shape[2], qt=params.fused_qt, k=k, g_lists=group,
+            rot_dim=index.rotation.shape[0], merge=params.fused_merge,
+        )
+
         def run_fused(qc):
+            if obs.is_enabled():
+                # sub-tiles sharing each decoded list: static shapes only
+                obs.observe("ivf_pq.search.fused_subtiles",
+                            float(fused_subtiles(qc.shape[0], **fused_model)))
             return ivf_pq_fused_search(
                 index.centers,
                 index.centers_rot,
@@ -1763,23 +1773,8 @@ def _search_dispatch(
                 code_mode=code_mode,
                 ksub=ksub,
                 extract_every=params.fused_extract_every,
-                # VMEM-model cap: wide-codebook decode chunks must fit
-                # the ~16 MB scoped-VMEM stack at any list length. The
-                # budget is derived from the kernel's fixed residents at
-                # THIS shape (vmem_model.pq_decode_chunk_budget), so the
-                # exact qt/k/group/merge config sharpens the cap.
-                decode_cols=vmem_decode_cols(
-                    params.fused_decode_cols,
-                    m=index.codes.shape[1],
-                    code_mode=code_mode,
-                    ksub=ksub,
-                    bpr=index.codes.shape[2],
-                    qt=params.fused_qt,
-                    k=k,
-                    g_lists=group,
-                    rot_dim=index.rotation.shape[0],
-                    merge=params.fused_merge,
-                ),
+                # capped inside to what the VMEM model leaves at this shape
+                decode_cols=params.fused_decode_cols,
                 interpret=jax.default_backend() != "tpu",
             )
 

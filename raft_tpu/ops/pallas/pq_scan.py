@@ -23,6 +23,14 @@ Probe scheduling, tile-coherent query ordering, scalar-prefetch DMA of
 only the probed code blocks, and the bank-merge running top-k are shared
 with the IVF-Flat fused scan (:mod:`raft_tpu.ops.pallas.ivf_scan`).
 
+``S`` depends on the list alone, so the grid is unit-major: for each
+group of ``n_sub`` query sub-tiles it walks the units any of their
+per-sub-tile tables holds, decodes each list once into a VMEM scratch,
+and scores it for the member sub-tiles only (:func:`shared_unit_tables`).
+A query's candidates stay its own sub-tile's lists; ``n_sub`` comes from
+the VMEM model (:func:`fused_subtiles`), and ``n_sub = 1`` is the
+one-tile schedule.
+
 Code layouts (``code_mode``):
 
 * ``"u8"``  — one byte per sub-quantizer code, ``ksub = 2^pq_bits <= 64``.
@@ -157,13 +165,11 @@ _decode_cell_bytes = vmem_model.decode_cell_bytes
 def _decode_chunk_budget(*, m: int, code_mode: str, ksub: int, bpr: int,
                          **model_kwargs) -> int:
     """Bytes of scoped VMEM one decode chunk may use at this shape:
-    ``VMEM_HEADROOM x VMEM_LIMIT`` minus the kernel's fixed residents
-    (W tile, q_rot, bank/acc scratch, double-buffered code+epilogue
-    DMA, dot accumulator) as accounted by
-    :func:`raft_tpu.ops.pallas.vmem_model.pq_decode_chunk_budget`.
-    Replaces the historical hand-calibrated 8 MB constant, which this
-    derivation reproduces within 2% at its calibration shape
-    (m=1152, ksub=256) while adapting to every other shape."""
+    ``VMEM_HEADROOM x PQ_SCAN_VMEM_LIMIT_BYTES`` minus the kernel's
+    fixed residents (LUT rows, queries, multi-hot scratch, bank/acc
+    scratch of every sub-tile, double-buffered code+epilogue DMA, dot
+    accumulator) as accounted by
+    :func:`raft_tpu.ops.pallas.vmem_model.pq_decode_chunk_budget`."""
     return vmem_model.pq_decode_chunk_budget(
         m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, **model_kwargs
     )
@@ -171,148 +177,218 @@ def _decode_chunk_budget(*, m: int, code_mode: str, ksub: int, bpr: int,
 
 def decode_feasible(*, m: int, code_mode: str, ksub: int, bpr: int,
                     **model_kwargs) -> bool:
-    """Whether even a single-group decode chunk fits the derived VMEM
-    budget — false for very long lists with wide codebooks (e.g.
-    ksub=256 with max_list > ~3400), where the fused kernel cannot
+    """Whether the one-list multi-hot scratch plus one lane-aligned
+    decode chunk fit the derived VMEM budget at one sub-tile — false for
+    very long lists with wide codebooks, where the fused kernel cannot
     compile and callers must use the scan path instead."""
-    _, gw = _code_groups(code_mode, ksub, bpr)
     budget = _decode_chunk_budget(
         m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, **model_kwargs
     )
-    return _decode_cell_bytes(code_mode) * m * gw <= budget
+    lanes = vmem_model.decode_lanes(code_mode, ksub, bpr)
+    return _decode_cell_bytes(code_mode) * m * lanes <= budget
 
 
 def vmem_decode_cols(requested: int, *, m: int, code_mode: str, ksub: int,
                      bpr: int, **model_kwargs) -> int:
     """Cap the decode column chunk so the kernel's scoped-VMEM stack fits
-    the TPU's ~16 MB limit.
+    the limit it compiles under.
 
-    A chunk materializes the multi-hot ``S [m, Kc]`` bf16 plus f32
-    byte-spread intermediates (see :func:`_decode_cell_bytes`). Measured
-    at the 1M-row bench shape (m=1152, ksub=256, Kc=2048) the kernel
-    needs 17.19 MiB and the Mosaic compile dies at 16 MiB; capping the
-    chunk to the per-shape budget :func:`_decode_chunk_budget` derives
-    from the kernel's fixed residents keeps the whole stack inside the
-    limit with margin. Chunks cover whole code groups, so the cap rounds
-    down to a multiple of the group width. Raises when even one group
-    cannot fit (use :func:`decode_feasible` to route such shapes to the
-    scan path up front). ``model_kwargs`` (``qt``/``k``/``g_lists``/
-    ``rot_dim``/``merge``) refine the resident accounting; omitted ones
-    fall back to conservative defaults."""
-    n_groups, gw = _code_groups(code_mode, ksub, bpr)
-    K = n_groups * gw
-    if not requested:
-        requested = K
+    A chunk holds the f32 byte-spread and peel temporaries of its
+    columns before they land in the multi-hot scratch (see
+    :func:`_decode_cell_bytes`); capping it to the per-shape budget
+    :func:`_decode_chunk_budget` derives from the kernel's fixed
+    residents keeps the whole stack inside the limit with margin.
+    Chunks cover whole code groups and whole 128-lane tiles, so the cap
+    rounds down to a multiple of :func:`vmem_model.decode_lanes`. Raises
+    when even one such chunk cannot fit (use :func:`decode_feasible` to
+    route such shapes to the scan path up front). ``model_kwargs``
+    (``qt``/``k``/``g_lists``/``rot_dim``/``merge``/``n_sub``) refine
+    the resident accounting; omitted ones fall back to conservative
+    defaults."""
+    Kp = vmem_model.padded_cols(code_mode, ksub, bpr)
+    lanes = vmem_model.decode_lanes(code_mode, ksub, bpr)
     expects(
         decode_feasible(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr,
                         **model_kwargs),
-        "fused PQ decode infeasible: one %d-column group over %d rows "
-        "exceeds the VMEM chunk budget — use mode='scan' or more lists",
-        gw, m,
+        "fused PQ decode infeasible: the %d-column multi-hot of %d rows "
+        "exceeds the VMEM budget — use mode='scan' or more lists",
+        Kp, m,
     )
     budget = _decode_chunk_budget(
         m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, **model_kwargs
     )
     cap = int(budget // (_decode_cell_bytes(code_mode) * max(m, 1)))
-    cap = max(gw, (cap // gw) * gw)
-    return min(requested, cap, K)
+    requested = min(requested or Kp, cap, Kp)
+    return max(lanes, requested // lanes * lanes)
 
 
-def _make_pq_kernel(*, k, metric, merge, qt, m, g_lists, n_steps, K,
+def fused_subtiles(nq: int, *, m: int, code_mode: str, ksub: int, bpr: int,
+                   qt: int = 128, **model_kwargs) -> int:
+    """How many ``qt``-query sub-tiles of an ``nq``-query call share each
+    decoded list (:func:`vmem_model.pq_scan_subtiles`): a function of the
+    static shapes alone, so a caller can observe it without a sync."""
+    return vmem_model.pq_scan_subtiles(
+        -(-nq // qt), m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=qt,
+        **model_kwargs)
+
+
+def _make_pq_kernel(*, k, metric, merge, qt, n_sub, m, g_lists, n_steps, Kp,
                     code_mode, ksub, bpr, extract_every, decode_cols):
     banks = _eff_banks(merge, m, 0)
-    n_groups, gw = _code_groups(code_mode, ksub, bpr)
-    # decode in column chunks so S stays VMEM-resident even for 256-entry
-    # codebooks (K = pq_dim * 256); a chunk covers whole groups
-    chunk_groups = n_groups if not decode_cols else max(1, decode_cols // gw)
-    chunk_groups = min(chunk_groups, n_groups)
+    _, gw = _code_groups(code_mode, ksub, bpr)
+    rows = n_sub * qt
+    # decode in lane-aligned column chunks, so the byte-spread temps of
+    # 256-entry codebooks (Kp = pq_dim * 256) stay inside VMEM
+    lanes = vmem_model.decode_lanes(code_mode, ksub, bpr)
+    chunk = max(lanes, min(decode_cols or Kp, Kp) // lanes * lanes)
 
-    def kernel(pr_ref, pv_ref, w_ref, qrot_ref, crot_ref, cod_ref, ln_ref,
-               outv_ref, outi_ref, accv, acci, bankv, banki):
+    def sub_rows(t):
+        """Rows of sub-tile ``t`` in the grid row's LUT/bank blocks."""
+        return pl.ds(pl.multiple_of(t * qt, qt), qt)
+
+    def kernel(pr_ref, pv_ref, mb_ref, w_ref, qrot_ref, crot_ref, cod_ref,
+               ln_ref, outv_ref, outi_ref, accv, acci, bankv, banki, s_ref):
         i = pl.program_id(0)
         j = pl.program_id(1)
 
+        def extract_subtile(t):
+            """Merge sub-tile ``t``'s banks into its running top-k."""
+            r = sub_rows(t)
+            cv = jnp.concatenate([accv[r, :], bankv[r, :]], axis=1)
+            ci = jnp.concatenate([acci[r, :], banki[r, :]], axis=1)
+            nv, ni = _extract_topk(cv, ci, k)
+            accv[r, :] = nv
+            acci[r, :] = ni
+            bankv[r, :] = jnp.full((qt, banks * 128), jnp.inf, jnp.float32)
+            banki[r, :] = jnp.full((qt, banks * 128), -1, jnp.int32)
+
         @pl.when(j == 0)
         def _():
-            accv[...] = jnp.full((qt, k), jnp.inf, jnp.float32)
-            acci[...] = jnp.full((qt, k), -1, jnp.int32)
-            bankv[...] = jnp.full((qt, banks * 128), jnp.inf, jnp.float32)
-            banki[...] = jnp.full((qt, banks * 128), -1, jnp.int32)
+            accv[...] = jnp.full((rows, k), jnp.inf, jnp.float32)
+            acci[...] = jnp.full((rows, k), -1, jnp.int32)
+            bankv[...] = jnp.full((rows, banks * 128), jnp.inf, jnp.float32)
+            banki[...] = jnp.full((rows, banks * 128), -1, jnp.int32)
 
         @pl.when(pv_ref[i, j] > 0)
         def _():
-            w = w_ref[...]  # [qt, K] bf16
             base = pr_ref[i, j] * (g_lists * m)
-            # coarse q.c term for the DMA'd lists (q_rot.c_rot == q.c under
-            # the orthonormal rotation): one tiny [qt, G] matmul per step
-            qdc = lax.dot_general(
-                qrot_ref[...],
-                crot_ref[0],
-                dimension_numbers=(((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # [qt, G]
-            # one column chunk per list: the q.c coarse term is constant
-            # within a list, so it folds into the chunk epilogue as a
-            # scalar column instead of a [qt, m] pass
             for g in range(g_lists):
+                # decode list g once; every sub-tile whose table holds
+                # this unit scores its own LUT rows against it
                 cod = cod_ref[0, g * m : (g + 1) * m, :]  # [m, bpr] u8
-                dot = jnp.zeros((qt, m), jnp.float32)
-                for g0 in range(0, n_groups, chunk_groups):
-                    ngc = min(chunk_groups, n_groups - g0)
-                    s = _multi_hot(
+                for c0 in range(0, Kp, chunk):
+                    c1 = min(c0 + chunk, Kp)
+                    s_ref[:, c0:c1] = _multi_hot(
                         cod, code_mode=code_mode, ksub=ksub, m=m, bpr=bpr,
-                        g0=g0, ng=ngc,
+                        g0=c0 // gw, ng=(c1 - c0) // gw,
                     )
-                    dot = dot + lax.dot_general(
-                        w[:, g0 * gw : (g0 + ngc) * gw],
-                        s,
-                        dimension_numbers=(((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32,
-                    )  # [qt, m]
                 ln = ln_ref[0, 0, g * m : (g + 1) * m]  # prepared epilogue
-                if metric == DistanceType.InnerProduct:
-                    score = ln[None, :] - dot - qdc[:, g][:, None]
-                else:
-                    score = ln[None, :] - 2.0 * (dot + qdc[:, g][:, None])
-                v, sl = _seg_compress(score, base + g * m, qt, m, banks)
-                take = v < bankv[...]
-                bankv[...] = jnp.where(take, v, bankv[...])
-                banki[...] = jnp.where(take, sl, banki[...])
 
-        if extract_every and extract_every < n_steps:
-            do_extract = ((j + 1) % extract_every == 0) | (j == n_steps - 1)
-        else:
-            do_extract = j == n_steps - 1
+                def score_subtile(t, carry):
+                    @pl.when(mb_ref[i * n_sub + t, j] > 0)
+                    def _():
+                        r = sub_rows(t)
+                        # coarse q.c term (q_rot.c_rot == q.c under the
+                        # orthonormal rotation): constant within a list,
+                        # so it folds into the epilogue as one column
+                        qdc = lax.dot_general(
+                            qrot_ref[r, :],
+                            crot_ref[0],
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )[:, g][:, None]  # [qt, 1]
+                        dot = lax.dot_general(
+                            w_ref[r, :],
+                            s_ref[...],
+                            dimension_numbers=(((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32,
+                        )  # [qt, m]
+                        if metric == DistanceType.InnerProduct:
+                            score = ln[None, :] - dot - qdc
+                        else:
+                            score = ln[None, :] - 2.0 * (dot + qdc)
+                        v, sl = _seg_compress(score, base + g * m, qt, m, banks)
+                        take = v < bankv[r, :]
+                        bankv[r, :] = jnp.where(take, v, bankv[r, :])
+                        banki[r, :] = jnp.where(take, sl, banki[r, :])
 
-        @pl.when(do_extract)
-        def _():
-            cv = jnp.concatenate([accv[...], bankv[...]], axis=1)
-            ci = jnp.concatenate([acci[...], banki[...]], axis=1)
-            nv, ni = _extract_topk(cv, ci, k)
-            accv[...] = nv
-            acci[...] = ni
-            bankv[...] = jnp.full((qt, banks * 128), jnp.inf, jnp.float32)
-            banki[...] = jnp.full((qt, banks * 128), -1, jnp.int32)
+                    return carry
+
+                lax.fori_loop(0, n_sub, score_subtile, 0)
+
+            if extract_every:
+                # a sub-tile's banks merge after every extract_every-th
+                # unit of its own table (member flag 2), as they would
+                # on a walk of that table alone
+                def extract_due(t, carry):
+                    @pl.when(mb_ref[i * n_sub + t, j] > 1)
+                    def _():
+                        extract_subtile(t)
+
+                    return carry
+
+                lax.fori_loop(0, n_sub, extract_due, 0)
 
         @pl.when(j == n_steps - 1)
         def _():
+            def extract_last(t, carry):
+                extract_subtile(t)
+                return carry
+
+            lax.fori_loop(0, n_sub, extract_last, 0)
             outv_ref[...] = accv[...]
             outi_ref[...] = acci[...]
 
     return kernel
 
 
-def kernel_scratch_shapes(qt: int, k: int, banks: int):
+def kernel_scratch_shapes(rows: int, k: int, banks: int, m: int, Kp: int):
     """The fused PQ kernel's scratch declarations: running top-k
-    accumulator pair + bank-merge pair. Split out so tests can assert
-    the VMEM residency model against the shapes the kernel actually
-    allocates (``vmem_model.pq_scan_residency`` mirrors these)."""
+    accumulator pair + bank-merge pair for the ``rows`` queries of every
+    sub-tile of a grid row, and the one-list multi-hot ``[m, Kp]``.
+    Split out so tests can assert the VMEM residency model against the
+    shapes the kernel actually allocates (``vmem_model.pq_scan_residency``
+    mirrors these)."""
     return [
-        pltpu.VMEM((qt, k), jnp.float32),
-        pltpu.VMEM((qt, k), jnp.int32),
-        pltpu.VMEM((qt, banks * 128), jnp.float32),
-        pltpu.VMEM((qt, banks * 128), jnp.int32),
+        pltpu.VMEM((rows, k), jnp.float32),
+        pltpu.VMEM((rows, k), jnp.int32),
+        pltpu.VMEM((rows, banks * 128), jnp.float32),
+        pltpu.VMEM((rows, banks * 128), jnp.int32),
+        pltpu.VMEM((m, Kp), jnp.bfloat16),
     ]
+
+
+def shared_unit_tables(tile_probes, probe_valid, *, n_units: int, n_sub: int,
+                       extract_every: int = 0):
+    """Per-group-of-``n_sub``-sub-tiles walk of the probe units, from the
+    per-sub-tile tables of :func:`ivf_scan.build_tile_probe_tables`.
+
+    Returns ``(unit_probes [n_st, P], unit_valid [n_st, P], member
+    [n_qt, P])``: the ascending units any sub-tile of a group holds
+    (invalid slots re-address the row's last valid unit, as in the
+    per-sub-tile tables), and whether sub-tile ``t``'s own table holds
+    the unit of step ``j`` of its group: 2 where that unit is the
+    ``extract_every``-th of the sub-tile's own table since its last
+    merge. ``P = min(n_units, n_sub * p)``. With ``n_sub == 1`` this is
+    the per-sub-tile table itself."""
+    n_qt, p = tile_probes.shape
+    n_st = n_qt // n_sub
+    P = min(n_units, n_sub * p)
+    held = jnp.zeros((n_qt, n_units), jnp.int32).at[
+        jnp.arange(n_qt)[:, None], tile_probes
+    ].max(probe_valid).reshape(n_st, n_sub, n_units)
+    units = jnp.arange(n_units, dtype=jnp.int32)
+    key = jnp.where(jnp.max(held, axis=1) > 0, units, n_units)
+    unit_probes = jnp.sort(key, axis=1)[:, :P]
+    unit_valid = (unit_probes < n_units).astype(jnp.int32)
+    last = jnp.max(jnp.where(unit_valid > 0, unit_probes, 0), axis=1, keepdims=True)
+    unit_probes = jnp.where(unit_valid > 0, unit_probes, last)
+    member = jnp.take_along_axis(held, unit_probes[:, None, :], axis=2)
+    member = (member * unit_valid[:, None, :]).reshape(n_qt, P)
+    if extract_every:
+        due = (jnp.cumsum(member, axis=1) % extract_every == 0) & (member > 0)
+        member = member + due.astype(jnp.int32)
+    return unit_probes, unit_valid, member
 
 
 def pq_lut(q_rot, books) -> jax.Array:
@@ -333,8 +409,8 @@ def pq_lut(q_rot, books) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "metric", "qt", "merge", "code_mode", "ksub", "extract_every",
-        "decode_cols", "interpret",
+        "k", "metric", "qt", "n_sub", "merge", "code_mode", "ksub",
+        "extract_every", "decode_cols", "interpret",
     ),
 )
 def fused_pq_topk(
@@ -343,12 +419,15 @@ def fused_pq_topk(
     w,            # [nq_pad, K] bf16 per-query LUT rows (tile-sorted)
     q_rot,        # [nq_pad, rot_dim] f32 rotated queries (tile-sorted)
     centers_rot,  # [n_units, G, rot_dim] f32 rotated coarse centers
-    tile_probes,
-    probe_valid,
+    unit_probes,  # [n_st, P] i32 ascending units of each sub-tile group
+    unit_valid,   # [n_st, P] i32
+    member,       # [n_st * n_sub, P] i32: sub-tile's own table holds the unit
+    #               (2: and its banks merge after this step)
     *,
     k: int,
     metric: DistanceType,
     qt: int,
+    n_sub: int = 1,
     merge: str = "bank8",
     code_mode: str = "u8",
     ksub: int = 16,
@@ -357,40 +436,52 @@ def fused_pq_topk(
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
     """Run the fused probed-list PQ scan; returns ``(scores [nq_pad, k]
-    asc, slots [nq_pad, k])`` with slot = unit * (G * max_list) + row."""
+    asc, slots [nq_pad, k])`` with slot = unit * (G * max_list) + row.
+
+    The grid walks, for each group of ``n_sub`` query sub-tiles of
+    ``qt`` rows, the units any of them probes in ascending order
+    (:func:`shared_unit_tables`); each list of a unit is decoded once
+    into a VMEM multi-hot and scored by the member sub-tiles only, so
+    every query sees exactly the lists of its own sub-tile's table."""
     n_units, gm, bpr = codes.shape
     nq_pad, K = w.shape
     rot_dim = q_rot.shape[1]
-    n_qt, n_steps = tile_probes.shape
+    n_st, n_steps = unit_probes.shape
     g_lists = centers_rot.shape[1]
     m = gm // g_lists
-    expects(nq_pad == n_qt * qt, "query rows %d != tiles*qt %d", nq_pad, n_qt * qt)
+    rows = n_sub * qt
+    expects(nq_pad == n_st * rows, "query rows %d != groups*n_sub*qt %d",
+            nq_pad, n_st * rows)
     expects(merge.startswith("bank"), "pq fused scan requires a bank merge mode")
+    Kp = vmem_model.padded_cols(code_mode, ksub, bpr)
+    if Kp != K:
+        w = jnp.pad(w, ((0, 0), (0, Kp - K)))  # zero LUT columns score 0
 
     kernel = _make_pq_kernel(
-        k=k, metric=metric, merge=merge, qt=qt, m=m, g_lists=g_lists,
-        n_steps=n_steps, K=K, code_mode=code_mode, ksub=ksub, bpr=bpr,
-        extract_every=extract_every, decode_cols=decode_cols,
+        k=k, metric=metric, merge=merge, qt=qt, n_sub=n_sub, m=m,
+        g_lists=g_lists, n_steps=n_steps, Kp=Kp, code_mode=code_mode,
+        ksub=ksub, bpr=bpr, extract_every=extract_every,
+        decode_cols=decode_cols,
     )
     banks = _eff_banks(merge, m, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_qt, n_steps),
+        num_scalar_prefetch=3,
+        grid=(n_st, n_steps),
         in_specs=[
-            pl.BlockSpec((qt, K), lambda i, j, pr, pv: (i, 0)),
-            pl.BlockSpec((qt, rot_dim), lambda i, j, pr, pv: (i, 0)),
-            pl.BlockSpec((1, g_lists, rot_dim), lambda i, j, pr, pv: (pr[i, j], 0, 0)),
+            pl.BlockSpec((rows, Kp), lambda i, j, pr, pv, mb: (i, 0)),
+            pl.BlockSpec((rows, rot_dim), lambda i, j, pr, pv, mb: (i, 0)),
+            pl.BlockSpec((1, g_lists, rot_dim), lambda i, j, pr, pv, mb: (pr[i, j], 0, 0)),
             # codes rows are deliberately narrow (bpr = 16-64 B/row is
             # the whole point of PQ): the lane padding the linter sees
             # costs VMEM but the HBM DMA moves only the real code bytes
-            pl.BlockSpec((1, gm, bpr), lambda i, j, pr, pv: (pr[i, j], 0, 0)),  # graft-lint: ignore[tile-align]
-            pl.BlockSpec((1, 1, gm), lambda i, j, pr, pv: (pr[i, j], 0, 0)),
+            pl.BlockSpec((1, gm, bpr), lambda i, j, pr, pv, mb: (pr[i, j], 0, 0)),  # graft-lint: ignore[tile-align]
+            pl.BlockSpec((1, 1, gm), lambda i, j, pr, pv, mb: (pr[i, j], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((qt, k), lambda i, j, pr, pv: (i, 0)),
-            pl.BlockSpec((qt, k), lambda i, j, pr, pv: (i, 0)),
+            pl.BlockSpec((rows, k), lambda i, j, pr, pv, mb: (i, 0)),
+            pl.BlockSpec((rows, k), lambda i, j, pr, pv, mb: (i, 0)),
         ],
-        scratch_shapes=kernel_scratch_shapes(qt, k, banks),
+        scratch_shapes=kernel_scratch_shapes(rows, k, banks, m, Kp),
     )
     return pl.pallas_call(
         kernel,
@@ -399,11 +490,15 @@ def fused_pq_topk(
             jax.ShapeDtypeStruct((nq_pad, k), jnp.float32),
             jax.ShapeDtypeStruct((nq_pad, k), jnp.int32),
         ],
+        # the multi-hot scratch and every sub-tile's banks stay resident:
+        # past the 16 MiB default scope, inside v5e's 128 MiB of VMEM
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES),
         interpret=interpret,
         # the op's name in a profile, which the benchmark's kernel
         # readers match: stable whatever the wrapper is called
         name="fused_pq_topk",
-    )(tile_probes, probe_valid, w, q_rot, centers_rot, codes, ln)
+    )(unit_probes, unit_valid, member, w, q_rot, centers_rot, codes, ln)
 
 
 @functools.partial(
@@ -482,6 +577,14 @@ def ivf_pq_fused_search(
 
     from raft_tpu.ops.pallas._guard import kernel_guard
 
+    # sub-tiles that share each decoded list, and the decode chunk that
+    # fits beside their residents: both from the VMEM model at this shape
+    model = dict(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=qt, k=k,
+                 g_lists=group, rot_dim=rot_dim, merge=merge)
+    n_sub = fused_subtiles(nq, **model)
+    unit_probes, unit_valid, member = shared_unit_tables(
+        tile_probes, probe_valid, n_units=n_units, n_sub=n_sub,
+        extract_every=extract_every)
     gm = group * m
     with kernel_guard("ivf_pq_fused_search"):
         vals, slots = fused_pq_topk(
@@ -490,16 +593,18 @@ def ivf_pq_fused_search(
             w,
             q_rot,
             centers_rot.reshape(n_units, group, rot_dim),
-            tile_probes,
-            probe_valid,
+            unit_probes,
+            unit_valid,
+            member,
             k=k,
             metric=metric,
             qt=qt,
+            n_sub=n_sub,
             merge=merge,
             code_mode=code_mode,
             ksub=ksub,
             extract_every=extract_every,
-            decode_cols=decode_cols,
+            decode_cols=vmem_decode_cols(decode_cols, n_sub=n_sub, **model),
             interpret=interpret,
         )
 

@@ -10,8 +10,8 @@ step of a fused scan keeps live in VMEM, used three ways —
   constant, so scratch-shape drift moves the cap instead of silently
   reintroducing Mosaic compile failures;
 * tests assert the model against the kernel's actual scratch/BlockSpec
-  shapes and against the measured 17.19 MiB residency of the 1M-row
-  bench shape (m=1152, ksub=256) that motivated the cap;
+  shapes and against the scoped allocation the TPU compiler reports
+  for the fused PQ scan at two shapes;
 * ``tools/graft_lint`` cross-checks the shapes it parses out of the
   kernel source against the same accounting.
 
@@ -24,14 +24,15 @@ Accounting rules (see ``docs/static_analysis.md`` for the rationale):
   anyway;
 * scratch buffers contribute their full size once — they persist across
   the whole grid;
-* kernel-body intermediates contribute their peak: for the PQ decode
-  that is one column chunk of the multi-hot ``S`` plus its f32
-  byte-spread temps (:func:`decode_cell_bytes`) and the ``[qt, m]``
-  f32 dot accumulator.
+* kernel-body intermediates contribute their peak: for the PQ scan
+  the ``[qt, m]`` f32 dot accumulator, and, planned as a reserve on
+  top, one decode column chunk's f32 byte-spread temps
+  (:func:`decode_cell_bytes`).
 
-Sub-(8, 128) tiles are modeled at logical size; Mosaic's lane/sublane
-padding of the k-sized accumulators is a second-order effect (<2% at
-every supported shape).
+Sub-(8, 128) tiles are modeled at logical size, except in the PQ scan's
+model, which counts every buffer at its physical (sublane, 128-lane)
+size: its narrow code rows (64 B of a 128-lane row) and k-wide
+accumulators pad by a few MiB at the benchmark shapes.
 """
 from __future__ import annotations
 
@@ -40,9 +41,16 @@ import math
 import re
 from typing import Optional, Tuple
 
-#: Per-core VMEM on current TPU generations (v4/v5): 16 MiB. Mosaic
-#: rejects kernels whose scoped allocation exceeds it.
+#: Mosaic's default scoped-VMEM limit on v4/v5e: 16 MiB. This is the
+#: default scope, not the chip's VMEM (v5e has 128 MiB): Mosaic rejects
+#: a kernel whose scoped allocation exceeds its limit, and a kernel may
+#: raise its own through ``pltpu.CompilerParams(vmem_limit_bytes=...)``.
 VMEM_LIMIT_BYTES = 16 * 1024 * 1024
+
+#: The scoped limit the fused PQ scan compiles under: one list's decoded
+#: multi-hot and the top-k banks of every query sub-tile that shares it
+#: stay resident across the grid. Half of v5e's 128 MiB.
+PQ_SCAN_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 #: Fraction of VMEM the model lets a kernel plan for. The remainder
 #: absorbs what the model cannot see: Mosaic spill slots, semaphores,
@@ -60,6 +68,21 @@ def code_groups(code_mode: str, ksub: int, bpr: int) -> Tuple[int, int]:
         b = int(code_mode[1:])
         return bpr * 8 // b, ksub
     return bpr, (ksub if code_mode == "u8" else 32)
+
+
+def decode_lanes(code_mode: str, ksub: int, bpr: int) -> int:
+    """Column step of a PQ decode chunk: whole code groups AND whole
+    128-lane tiles, so chunk stores and the LUT's column slices stay
+    aligned."""
+    return math.lcm(code_groups(code_mode, ksub, bpr)[1], 128)
+
+
+def padded_cols(code_mode: str, ksub: int, bpr: int) -> int:
+    """The multi-hot's column count rounded up to :func:`decode_lanes`
+    (the LUT is zero-padded to match)."""
+    n_groups, gw = code_groups(code_mode, ksub, bpr)
+    step = decode_lanes(code_mode, ksub, bpr)
+    return -(-n_groups * gw // step) * step
 
 
 def decode_cell_bytes(code_mode: str) -> int:
@@ -90,17 +113,26 @@ class Resident:
     ``kind`` is ``"tile"`` (BlockSpec in/out block), ``"scratch"``
     (``pltpu.VMEM`` scratch), ``"body"`` (peak kernel-body
     intermediate), or ``"chunk"`` (the sizeable *scalable* body
-    intermediate the budget is solved for)."""
+    intermediate the budget is solved for). ``tiled`` counts the
+    buffer at its physical (sublane, 128-lane) layout, as Mosaic
+    allocates it."""
 
     name: str
     shape: Tuple[int, ...]
     itemsize: int
     buffers: int = 1
     kind: str = "tile"
+    tiled: bool = False
 
     @property
     def nbytes(self) -> int:
-        return int(math.prod(self.shape)) * self.itemsize * self.buffers
+        dims = list(self.shape)
+        if self.tiled:
+            dims[-1] = -(-dims[-1] // 128) * 128
+            sublanes = max(8, 32 // self.itemsize)
+            if len(dims) >= 2 and dims[-2] > 1:
+                dims[-2] = -(-dims[-2] // sublanes) * sublanes
+        return int(math.prod(dims)) * self.itemsize * self.buffers
 
 
 @dataclasses.dataclass(frozen=True)
@@ -147,6 +179,7 @@ def pq_scan_residency(
     rot_dim: int = 128,
     merge: str = "bank8",
     decode_cols: int = 0,
+    n_sub: int = 1,
 ) -> KernelResidency:
     """Model ``pq_scan.fused_pq_topk``'s VMEM residency for one grid
     step. Mirrors the kernel's grid spec exactly — the shapes here are
@@ -154,36 +187,45 @@ def pq_scan_residency(
     tests (``test_pq_fused.py``), so the two cannot drift apart
     silently.
 
+    ``n_sub`` query sub-tiles of ``qt`` rows share each decoded list:
+    their LUT rows, queries, outputs, accumulators and banks are
+    resident together, beside the one-list multi-hot scratch.
     ``decode_cols=0`` omits the decode chunk (useful for computing the
     fixed residents the chunk budget is solved against); defaults for
     ``qt``/``k``/``g_lists``/``merge`` match ``IvfPqSearchParams``
     (``k=128`` is a conservative stand-in when the caller's k is
     unknown — the k-sized residents are <3% of the stack)."""
-    n_groups, gw = code_groups(code_mode, ksub, bpr)
-    K = n_groups * gw
+    Kp = padded_cols(code_mode, ksub, bpr)
     gm = g_lists * m
+    rows = n_sub * qt
     banks = merge_banks(merge, m)
+    T = dict(tiled=True)  # the narrow code rows and k columns pad lanes
     residents = [
         # in tiles, in fused_pq_topk's in_specs order. Index maps that
         # reference the inner grid axis j (the probe step) are
         # double-buffered by the DMA pipeline; w/q_rot/outs only move
-        # with the query-tile axis i.
-        Resident("w_tile", (qt, K), 2),                      # bf16 LUT rows
-        Resident("q_rot", (qt, rot_dim), 4),
-        Resident("centers_rot", (1, g_lists, rot_dim), 4, buffers=2),
-        Resident("codes", (1, gm, bpr), 1, buffers=2),
-        Resident("ln", (1, 1, gm), 4, buffers=2),
-        Resident("out_vals", (qt, k), 4),
-        Resident("out_idx", (qt, k), 4),
+        # with the outer (query) axis.
+        Resident("w_rows", (rows, Kp), 2, **T),              # bf16 LUT rows
+        Resident("q_rot", (rows, rot_dim), 4, **T),
+        Resident("centers_rot", (1, g_lists, rot_dim), 4, buffers=2, **T),
+        Resident("codes", (1, gm, bpr), 1, buffers=2, **T),
+        Resident("ln", (1, 1, gm), 4, buffers=2, **T),
+        Resident("out_vals", (rows, k), 4, **T),
+        Resident("out_idx", (rows, k), 4, **T),
         # scratch_shapes, in declaration order
-        Resident("acc_vals", (qt, k), 4, kind="scratch"),
-        Resident("acc_idx", (qt, k), 4, kind="scratch"),
-        Resident("bank_vals", (qt, banks * 128), 4, kind="scratch"),
-        Resident("bank_idx", (qt, banks * 128), 4, kind="scratch"),
+        Resident("acc_vals", (rows, k), 4, kind="scratch", **T),
+        Resident("acc_idx", (rows, k), 4, kind="scratch", **T),
+        Resident("bank_vals", (rows, banks * 128), 4, kind="scratch", **T),
+        Resident("bank_idx", (rows, banks * 128), 4, kind="scratch", **T),
+        Resident("multi_hot", (m, Kp), 2, kind="scratch", **T),
         # peak kernel-body intermediates
-        Resident("dot_acc", (qt, m), 4, kind="body"),
+        Resident("dot_acc", (qt, m), 4, kind="body", **T),
     ]
     if decode_cols:
+        # the chunk's byte-spread temps stream into the multi-hot scratch
+        # and do not show in the scoped allocation the compiler reports
+        # (equal at 512 and 2048 columns); planned all the same, as the
+        # reserve that keeps a chunk's peak off the limit
         residents.append(
             Resident(
                 "decode_chunk", (m, decode_cols), decode_cell_bytes(code_mode),
@@ -204,21 +246,38 @@ def pq_decode_chunk_budget(
     g_lists: int = 8,
     rot_dim: int = 128,
     merge: str = "bank8",
-    limit: int = VMEM_LIMIT_BYTES,
+    n_sub: int = 1,
+    limit: int = PQ_SCAN_VMEM_LIMIT_BYTES,
     headroom: float = VMEM_HEADROOM,
 ) -> int:
     """Bytes one PQ decode chunk may occupy: ``headroom x limit`` minus
-    the kernel's fixed residents at this shape. Replaces the historical
-    hand-calibrated 8 MB ``_DECODE_CHUNK_BUDGET`` — at the calibration
-    shape (m=1152, ksub=256, k<=128) this derives ~7.85 MB, and unlike
-    the constant it shrinks for longer lists / wider code rows whose
-    fixed residents (dot accumulator, code DMA buffers) grow. May be
-    <= 0: no chunk fits, the shape is fused-infeasible."""
+    the kernel's fixed residents at this shape, the scoped limit being
+    the one the kernel compiles under. Replaces the historical
+    hand-calibrated 8 MB ``_DECODE_CHUNK_BUDGET``: the budget shrinks
+    for longer lists, wider code rows and more sub-tiles a decode, whose
+    fixed residents (multi-hot scratch, banks, code DMA buffers) grow.
+    May be <= 0: no chunk fits, the shape is fused-infeasible."""
     fixed = pq_scan_residency(
         m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=qt, k=k,
         g_lists=g_lists, rot_dim=rot_dim, merge=merge, decode_cols=0,
+        n_sub=n_sub,
     ).fixed_bytes
     return int(limit * headroom) - fixed
+
+
+def pq_scan_subtiles(n_qt: int, *, m: int, code_mode: str, ksub: int,
+                     bpr: int, **model_kwargs) -> int:
+    """How many of a call's ``n_qt`` query sub-tiles share one decoded
+    list in ``fused_pq_topk``: the whole call when its residents leave
+    room for one lane-aligned decode chunk, else the largest divisor of
+    ``n_qt`` that does (1, the one-tile schedule, at worst)."""
+    need = decode_cell_bytes(code_mode) * m * decode_lanes(code_mode, ksub, bpr)
+    for n in range(n_qt, 1, -1):
+        if n_qt % n == 0 and pq_decode_chunk_budget(
+            m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, n_sub=n, **model_kwargs
+        ) >= need:
+            return n
+    return 1
 
 
 #: Peak live bytes per (row, rot_dim-column) cell of one RaBitQ decode

@@ -327,21 +327,28 @@ def test_multi_hot_decode_every_width():
 
 def test_vmem_decode_cols_cap():
     """The VMEM model keeps the decode chunk under budget for any list
-    length (the 1M bench shape m=1152, ksub=256 exceeded the 16 MB
-    scoped-VMEM stack before the cap existed)."""
+    length, in whole lane-aligned code groups, beside the one-list
+    multi-hot scratch and the banks of every sub-tile sharing it."""
+    from raft_tpu.ops.pallas import vmem_model
     from raft_tpu.ops.pallas.pq_scan import vmem_decode_cols
 
-    # bench shape: requested 2048 must shrink to a whole-group multiple
-    dc = vmem_decode_cols(2048, m=1152, code_mode="u8", ksub=256, bpr=32)
-    assert dc % 256 == 0 and dc < 2048
-    assert 6 * 1152 * dc <= 8_000_000
+    # bench shape (K = 32 x 256 = 8192 columns): the chunk is whole
+    # 256-column groups and fits what the fixed residents leave
+    dc = vmem_decode_cols(8192, m=1152, code_mode="u8", ksub=256, bpr=32)
+    assert dc % 256 == 0 and dc < 8192
+    budget = vmem_model.pq_decode_chunk_budget(m=1152, code_mode="u8", ksub=256, bpr=32)
+    assert 6 * 1152 * dc <= budget < 6 * 1152 * (dc + 256)
+    # more sub-tiles sharing one decode leave less room for the chunk
+    assert vmem_decode_cols(8192, m=1152, code_mode="u8", ksub=256, bpr=32,
+                            n_sub=4) < dc
     # short lists keep the request
     assert vmem_decode_cols(2048, m=256, code_mode="u8", ksub=256, bpr=32) == 2048
     # 0 = "single pass" still resolves to a bounded chunk
     dc0 = vmem_decode_cols(0, m=1152, code_mode="u8", ksub=256, bpr=32)
     assert dc0 == dc
-    # lists too long for even one group are infeasible: flagged up front
-    # (ivf_pq.search auto-routes those to the scan path) and refused here
+    # lists too long for the multi-hot scratch are infeasible: flagged up
+    # front (ivf_pq.search auto-routes those to the scan path) and
+    # refused here
     from raft_tpu.core.errors import RaftError
     from raft_tpu.ops.pallas.pq_scan import decode_feasible
 
@@ -350,29 +357,45 @@ def test_vmem_decode_cols_cap():
         vmem_decode_cols(2048, m=100_000, code_mode="u8", ksub=256, bpr=32)
     # narrow layouts (nib8: 32 cols/group) are usually uncapped
     assert vmem_decode_cols(1024, m=1152, code_mode="nib8", ksub=16, bpr=32) == 1024
+    # a request off the 128-lane grid rounds down onto it
+    assert vmem_decode_cols(1000, m=1152, code_mode="nib8", ksub=16, bpr=32) == 896
     # spanning bit layouts carry a heavier per-cell footprint (two f32
     # byte-spreads + peel temps), so their cap is tighter than u8's
-    assert vmem_decode_cols(4096, m=1152, code_mode="b5", ksub=32, bpr=20) < \
-        vmem_decode_cols(4096, m=1152, code_mode="u8", ksub=32, bpr=32)
+    assert vmem_decode_cols(4096, m=4000, code_mode="b5", ksub=32, bpr=20) < \
+        vmem_decode_cols(4096, m=4000, code_mode="u8", ksub=32, bpr=32)
 
 
-def test_vmem_model_reproduces_measured_residency():
-    """The residency model must land within 5% of the measured 17.19 MiB
-    scoped-VMEM allocation of the 1M-row bench shape (m=1152, ksub=256,
-    qt=128, k=10, decode_cols=2048) — the configuration whose Mosaic
-    compile failure motivated the decode cap in the first place."""
+@pytest.mark.parametrize(
+    "shape, measured_mib",
+    [
+        # the 1M-row bench shape, one 128-query sub-tile: the 18 MiB
+        # multi-hot of 256-entry books dominates
+        (dict(m=1152, code_mode="u8", ksub=256, bpr=32, qt=128, k=10), 24.28),
+        # the glove200-pq cell: eight sub-tiles share each decoded list
+        (dict(m=1280, code_mode="nib8", ksub=16, bpr=64, qt=128, k=80,
+              rot_dim=256, n_sub=8), 23.44),
+    ],
+    ids=["bench_u8_256", "glove_nib8_8sub"],
+)
+def test_vmem_model_reproduces_measured_residency(shape, measured_mib):
+    """The residency model must land within 5% of the scoped-VMEM
+    allocation the TPU compiler reports for ``fused_pq_topk`` compiled
+    for a v5e at these shapes (the size in its refusal at any lower
+    limit; the compile passes at 25 and 24 MiB). The decode chunk's
+    temporaries do not enter that allocation (the same at 512 and 2048
+    columns); the model plans them on top, as a reserve."""
     from raft_tpu.ops.pallas import vmem_model
 
-    res = vmem_model.pq_scan_residency(
-        m=1152, code_mode="u8", ksub=256, bpr=32, qt=128, k=10,
-        decode_cols=2048,
-    )
-    measured = 17.19 * 2**20
-    err = abs(res.total_bytes - measured) / measured
-    assert err < 0.05, f"{res.total_bytes} B vs measured 17.19 MiB " \
+    res = vmem_model.pq_scan_residency(**shape)
+    measured = measured_mib * 2**20
+    err = abs(res.fixed_bytes - measured) / measured
+    assert err < 0.05, f"{res.fixed_bytes} B vs measured {measured_mib} MiB " \
         f"({err:.1%}):\n{res.table()}"
-    # the decode chunk dominates — it is the right knob to solve for
-    assert res.by_name("decode_chunk").nbytes > res.fixed_bytes
+    planned = vmem_model.pq_scan_residency(decode_cols=2048, **shape)
+    assert planned.total_bytes > measured
+    # the scratch that persists across the grid dominates
+    scratch = sum(r.nbytes for r in res.residents if r.kind == "scratch")
+    assert scratch > res.fixed_bytes / 2
 
 
 def test_vmem_model_matches_kernel_scratch_shapes():
@@ -384,17 +407,23 @@ def test_vmem_model_matches_kernel_scratch_shapes():
     from raft_tpu.ops.pallas.ivf_scan import _eff_banks
     from raft_tpu.ops.pallas.pq_scan import kernel_scratch_shapes
 
-    for m, merge, qt, k in [
-        (1152, "bank8", 128, 10), (256, "bank8", 128, 128),
-        (1152, "bank4", 64, 10), (100, "bank8", 128, 10),
+    for m, merge, qt, k, n_sub, mode, ksub, bpr in [
+        (1152, "bank8", 128, 10, 1, "u8", 256, 32),
+        (256, "bank8", 128, 128, 4, "u8", 256, 32),
+        (1152, "bank4", 64, 10, 2, "nib8", 16, 64),
+        (100, "bank8", 128, 10, 8, "u8", 16, 20),  # K = 320 pads to 384
     ]:
         banks = _eff_banks(merge, m, 0)
         assert vmem_model.merge_banks(merge, m) == banks, (merge, m)
         res = vmem_model.pq_scan_residency(
-            m=m, code_mode="u8", ksub=256, bpr=32, qt=qt, k=k, merge=merge,
+            m=m, code_mode=mode, ksub=ksub, bpr=bpr, qt=qt, k=k, merge=merge,
+            n_sub=n_sub,
         )
         model_scratch = [r for r in res.residents if r.kind == "scratch"]
-        decls = kernel_scratch_shapes(qt, k, banks)
+        Kp = vmem_model.padded_cols(mode, ksub, bpr)
+        assert Kp % 128 == 0 and Kp >= vmem_model.code_groups(mode, ksub, bpr)[0] * \
+            vmem_model.code_groups(mode, ksub, bpr)[1]
+        decls = kernel_scratch_shapes(n_sub * qt, k, banks, m, Kp)
         assert len(model_scratch) == len(decls)
         for r, decl in zip(model_scratch, decls):
             assert tuple(decl.shape) == r.shape, r.name
@@ -403,27 +432,217 @@ def test_vmem_model_matches_kernel_scratch_shapes():
 
 def test_decode_budget_is_derived_not_hardcoded():
     """The hand-calibrated 8 MB ``_DECODE_CHUNK_BUDGET`` constant is
-    gone; the budget now comes from the residency model (headroom x
-    16 MiB minus fixed residents) and therefore moves with shape."""
+    gone; the budget comes from the residency model (headroom x the
+    scoped limit the kernel compiles under, minus fixed residents) and
+    therefore moves with shape."""
     from raft_tpu.ops.pallas import pq_scan, vmem_model
 
     assert not hasattr(pq_scan, "_DECODE_CHUNK_BUDGET")
-    # at the calibration shape the derivation reproduces the historical
-    # constant (that is what pinned VMEM_HEADROOM = 0.75)
-    budget = vmem_model.pq_decode_chunk_budget(
-        m=1152, code_mode="u8", ksub=256, bpr=32, k=10,
-    )
-    assert abs(budget - 8_000_000) / 8_000_000 < 0.02, budget
-    # unlike the constant, the budget shrinks as fixed residents grow
-    # (longer lists -> bigger dot accumulator + code DMA buffers)
-    wider = vmem_model.pq_decode_chunk_budget(
-        m=4608, code_mode="u8", ksub=256, bpr=32, k=10,
-    )
-    assert wider < budget
+    shape = dict(m=1152, code_mode="u8", ksub=256, bpr=32, k=10)
+    budget = vmem_model.pq_decode_chunk_budget(**shape)
+    fixed = vmem_model.pq_scan_residency(**shape).fixed_bytes
+    # the kernel's raised scope, not the 16 MiB default
+    assert budget == int(0.75 * vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES) - fixed
+    assert vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES > vmem_model.VMEM_LIMIT_BYTES
+    # under the default scope the one-list multi-hot alone overflows
+    assert vmem_model.pq_decode_chunk_budget(
+        limit=vmem_model.VMEM_LIMIT_BYTES, **shape) < 0
+    # the budget shrinks as fixed residents grow: longer lists (bigger
+    # multi-hot, dot accumulator, code DMA buffers) and more sub-tiles
+    # sharing a decode (their LUT rows and banks)
+    assert vmem_model.pq_decode_chunk_budget(**dict(shape, m=2304)) < budget
+    assert vmem_model.pq_decode_chunk_budget(n_sub=2, **shape) < budget
     # and the kernel-side wrapper agrees with the model
-    assert pq_scan._decode_chunk_budget(
-        m=1152, code_mode="u8", ksub=256, bpr=32, k=10,
-    ) == budget
+    assert pq_scan._decode_chunk_budget(**shape) == budget
+
+
+@pytest.mark.parametrize("nq, n_sub", [(1024, 8), (128, 1), (300, 3)])
+def test_glove_shape_shares_one_decode_per_bucket(nq, n_sub):
+    """At the glove200-pq cell's shape (m 1,280, nib8 with 64 bytes a
+    row, k 80, group 8) one decode serves the whole bucket under the
+    raised limit; a bucket of one sub-tile keeps the one-tile schedule."""
+    from raft_tpu.ops.pallas import pq_scan, vmem_model
+
+    shape = dict(m=1280, code_mode="nib8", ksub=16, bpr=64, qt=128, k=80,
+                 g_lists=8, rot_dim=256, merge="bank8")
+    assert pq_scan.fused_subtiles(nq, **shape) == n_sub
+    # ... with the whole 2,048-column multi-hot decoded in one chunk
+    assert pq_scan.vmem_decode_cols(2048, n_sub=n_sub, **shape) == 2048
+    res = vmem_model.pq_scan_residency(n_sub=n_sub, decode_cols=2048, **shape)
+    assert res.total_bytes <= 0.75 * vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES
+
+
+def _subtile_reference(idx, qs, k, *, n_probes, probe_factor, group, qt, keep=None):
+    """Exact top-k of each query over exactly the lists of its own
+    ``qt``-query sub-tile's probe table (``build_tile_probe_tables``),
+    scored by ADC from the materialized books, in float64. The LUT
+    entries are rounded to bfloat16 as the fused kernel's LUT is (for
+    nibble books, each half of the additive pair on its own), so ids can
+    be compared exactly. Returns ``(ids, dists, dists_f32_books, cand)``
+    with ``cand[q]`` the query's candidate row ids."""
+    import jax
+    from raft_tpu.neighbors.ivf_common import probe_selection
+    from raft_tpu.ops.pallas.ivf_scan import build_tile_probe_tables
+
+    nq = qs.shape[0]
+    qf = jnp.asarray(qs, jnp.float32)
+    coarse, probed = probe_selection(idx.centers, qf, n_probes, idx.metric)
+    order_pad, tp, pv = build_tile_probe_tables(
+        coarse, probed, idx.center_rank, nq=nq, qt=qt, n_lists=idx.n_lists,
+        group=group, n_probes=n_probes, probe_factor=probe_factor)
+    order, tp, pv = np.asarray(order_pad)[:nq], np.asarray(tp), np.asarray(pv)
+
+    rot = np.asarray(idx.rotation, np.float64)
+    q_rot = np.asarray(qs, np.float64) @ rot.T
+    grid = np.asarray(idx.pq_centers, np.float64)  # [pq_dim, 256, pq_len]
+    pq_dim, n_codes, pq_len = grid.shape
+    q_sub = q_rot.reshape(nq, pq_dim, pq_len)
+    bf16 = lambda x: np.asarray(jnp.asarray(x, jnp.float32).astype(jnp.bfloat16), np.float64)
+    lut_f32 = np.einsum("npl,pcl->npc", q_sub, grid)  # the books' exact ADC terms
+    if idx.additive:  # grid[j, hi*16+lo] = A[hi] + B[lo]
+        a = np.einsum("npl,phl->nph", q_sub, grid[:, 0::16, :])
+        b = np.einsum("npl,pbl->npb", q_sub, grid[:, 0:16, :] - grid[:, 0:1, :])
+        lut = (bf16(a)[:, :, :, None] + bf16(b)[:, :, None, :]).reshape(nq, pq_dim, n_codes)
+    else:
+        lut = bf16(lut_f32)
+    codes = np.asarray(idx.codes_unpacked()).astype(np.int64)  # [n_lists, m, pq_dim]
+    ids = np.asarray(idx.list_indices)
+    sqn = np.asarray(idx.rot_sqnorms, np.float64)
+    crot = np.asarray(idx.centers_rot, np.float64)
+    ip = idx.metric == DistanceType.InnerProduct
+    out_i = np.full((nq, k), -1, np.int64)
+    out_d = np.zeros((nq, k))
+    out_f = np.zeros((nq, k))
+    cand = []
+    row_of = np.empty(nq, np.int64)
+    row_of[order] = np.arange(nq)
+    for q in range(nq):
+        t = row_of[q] // qt
+        lists = (tp[t][pv[t] > 0][:, None] * group + np.arange(group)).ravel()
+        li = ids[lists].ravel()
+        ok = li >= 0
+        if keep is not None:
+            ok &= keep[np.clip(li, 0, None)]
+        cod = codes[lists].reshape(-1, pq_dim)[ok]
+        cq = np.repeat(q_rot[q] @ crot[lists].T, codes.shape[1])[ok]
+        cand.append(set(li[ok].tolist()))
+        j = np.arange(pq_dim)
+        adc = lut[q, j, cod].sum(1) + cq
+        adc_f = lut_f32[q, j, cod].sum(1) + cq
+        if ip:
+            d, d_f = adc, adc_f
+            top = np.argsort(-d, kind="stable")[:k]
+        else:
+            base = q_rot[q] @ q_rot[q] + sqn[lists].ravel()[ok]
+            d, d_f = np.maximum(base - 2 * adc, 0.0), base - 2 * adc_f
+            top = np.argsort(d, kind="stable")[:k]
+        out_i[q, : len(top)] = li[ok][top]
+        out_d[q, : len(top)] = d[top]
+        out_f[q, : len(top)] = d_f[top]
+    return out_i, out_d, out_f, cand
+
+
+@pytest.fixture(scope="module")
+def subtile_indexes():
+    """One index per (code layout, metric), built once for the parity
+    cases: 32 lists, so a sub-tile's 8-unit table leaves lists out."""
+    ds, qs = _data(seed=31, n=3000, nq=300)
+    out = {}
+    for kind in ("nibble", "kmeans"):
+        for metric in (DistanceType.L2Expanded, DistanceType.InnerProduct):
+            out[kind, metric] = ivf_pq.build(ds, ivf_pq.IvfPqIndexParams(
+                kmeans_n_iters=5, n_lists=32, pq_dim=16, pq_bits=8, pq_kind=kind,
+                metric=metric, seed=5))
+    return ds, qs, out
+
+
+@pytest.mark.parametrize("filtered", [False, True], ids=["all", "prefilter"])
+@pytest.mark.parametrize("metric", [DistanceType.L2Expanded, DistanceType.InnerProduct],
+                         ids=["l2", "ip"])
+@pytest.mark.parametrize("kind, code_mode", [("nibble", "nib8"), ("kmeans", "u8")])
+def test_fused_matches_subtile_reference(subtile_indexes, kind, code_mode, metric, filtered):
+    """300 queries span three 128-query sub-tiles (the last partial), all
+    sharing each decoded list. Every query must be scored against exactly
+    the lists of its own sub-tile's table: the fused ids equal the
+    per-sub-tile reference's, never a list of a neighbouring sub-tile's
+    table. ``extract_every=1`` with one-list units keeps every candidate
+    of a step in its own bank slot, so the kernel's top-k is exact; with
+    the default schedule (bank merges over the whole walk) the ids must
+    still come from the query's own candidate set."""
+    from raft_tpu import obs
+    from raft_tpu.core.bitset import Bitset
+
+    ds, qs, indexes = subtile_indexes
+    idx = indexes[kind, metric]
+    from raft_tpu.neighbors.ivf_pq import _fused_code_layout
+
+    assert _fused_code_layout(idx)[0] == code_mode
+    k, n_probes, pf = 10, 4, 2
+    keep = prefilter = None
+    if filtered:
+        keep = np.ones(ds.shape[0], bool)
+        keep[::3] = False
+        prefilter = Bitset.from_unset_indices(ds.shape[0], jnp.asarray(np.flatnonzero(~keep), jnp.int32))
+    sp = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=128, fused_probe_factor=pf,
+                                  fused_group=1, fused_extract_every=1)
+    reg = obs.registry()
+    reg.reset()
+    obs.enable()
+    try:
+        d, i = ivf_pq.search(idx, qs, k, sp, prefilter=prefilter, mode="fused")
+        hist = obs.registry().as_dict()["histograms"]["ivf_pq.search.fused_subtiles"]
+    finally:
+        obs.disable()
+        reg.reset()
+    assert hist["count"] == 1 and hist["sum"] == 3.0  # 300 queries: 3 sub-tiles, one decode
+    ref_i, ref_d, ref_f, cand = _subtile_reference(
+        idx, qs, k, n_probes=n_probes, probe_factor=pf, group=1, qt=128, keep=keep)
+    i, d = np.asarray(i), np.asarray(d)
+    np.testing.assert_array_equal(i, ref_i)
+    # a LUT entry on a bfloat16 rounding boundary may round the other way
+    # from float64 than from the kernel's float32: one ulp of one term
+    np.testing.assert_allclose(d, ref_d, rtol=1e-3, atol=1e-3)
+    # the bf16 LUT stays close to the materialized books' exact ADC
+    scale = np.median(np.abs(ref_f))
+    np.testing.assert_allclose(d, ref_f, rtol=0.02, atol=0.02 * scale)
+    if keep is not None:
+        assert keep[i[i >= 0]].all()
+    # the default schedule: one bank walk over the whole union, 4-list
+    # units; still no query sees a list outside its own sub-tile's table
+    sp0 = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=128, fused_probe_factor=pf,
+                                   fused_group=4)
+    _, i0 = ivf_pq.search(idx, qs, k, sp0, prefilter=prefilter, mode="fused")
+    _, _, _, cand4 = _subtile_reference(
+        idx, qs, k, n_probes=n_probes, probe_factor=pf, group=4, qt=128, keep=keep)
+    for q, row in enumerate(np.asarray(i0)):
+        assert set(row[row >= 0].tolist()) <= cand4[q], q
+
+
+@pytest.mark.parametrize("extract_every", [0, 2])
+def test_subtile_sharing_keeps_ids(subtile_indexes, extract_every, monkeypatch):
+    """How many sub-tiles share a decode changes the schedule, never the
+    result: one sub-tile a grid row (the one-tile schedule) and all three
+    of a 300-query call give identical ids and distances, banks and
+    their merge order included."""
+    from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
+    from raft_tpu.ops.pallas import pq_scan
+
+    ds, qs, indexes = subtile_indexes
+    idx = indexes["nibble", DistanceType.L2Expanded]
+    code_mode, ksub = _fused_code_layout(idx)
+    args = (idx.centers, idx.centers_rot, idx.center_rank, idx.rotation,
+            nibble_books(idx.pq_centers), idx.codes, idx.list_indices,
+            idx.rot_sqnorms, jnp.asarray(qs), None)
+    kw = dict(k=10, n_probes=4, metric=idx.metric, qt=128, probe_factor=2, group=4,
+              code_mode=code_mode, ksub=ksub, extract_every=extract_every,
+              decode_cols=256, interpret=True)
+    out = {}
+    for n_sub in (1, 3):
+        monkeypatch.setattr(pq_scan, "fused_subtiles", lambda nq, n=n_sub, **_: n)
+        d, i = pq_scan.ivf_pq_fused_search.__wrapped__(*args, **kw)
+        out[n_sub] = np.asarray(d), np.asarray(i)
+    np.testing.assert_array_equal(out[1][1], out[3][1])
+    np.testing.assert_array_equal(out[1][0], out[3][0])
 
 
 def test_explicit_fused_f32_lut_warns():

@@ -113,6 +113,46 @@ def test_pq_scan_compiles(one_chip):
         interpret=False))
 
 
+def test_pq_scan_compiles_glove_bucket(one_chip):
+    """The glove200-pq benchmark cell's shape: 1,183,514 x 200 by inner
+    product in 1,024 lists capped at 1,280 rows, nibble codes of 64 bytes
+    a row, k 80 (10 x refine 8), a 1,024-query bucket. Its eight 128-query
+    sub-tiles share each decoded list under the kernel's raised VMEM
+    limit, and the 2,048-column multi-hot decodes in one chunk."""
+    from raft_tpu.neighbors import ivf_pq
+    from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
+    from raft_tpu.ops.pallas.pq_scan import (
+        fused_subtiles,
+        ivf_pq_fused_search,
+        vmem_decode_cols,
+    )
+
+    d, m, nq, k = 200, 1280, 1024, 80
+    x = np.random.default_rng(0).standard_normal((2048, d)).astype(np.float32)
+    idx = ivf_pq.build(jnp.asarray(x), ivf_pq.IvfPqIndexParams(
+        n_lists=8, pq_dim=64, pq_bits=8, pq_kind="nibble", kmeans_n_iters=2,
+        metric=DistanceType.InnerProduct))
+    code_mode, ksub = _fused_code_layout(idx)
+    books = nibble_books(idx.pq_centers)
+    bpr, rot = idx.codes.shape[2], idx.rotation.shape[0]
+    model = dict(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=128, k=k,
+                 g_lists=8, rot_dim=rot, merge="bank8")
+    assert (code_mode, bpr) == ("nib8", 64) and fused_subtiles(nq, **model) == 8
+    _assert_kernel(ivf_pq_fused_search.lower(
+        _spec((N_LISTS, d), jnp.float32, one_chip),
+        _spec((N_LISTS, rot), jnp.float32, one_chip),
+        _spec((N_LISTS,), jnp.int32, one_chip),
+        _spec(idx.rotation.shape, jnp.float32, one_chip),
+        _spec(books.shape, jnp.float32, one_chip),
+        _spec((N_LISTS, m, bpr), jnp.uint8, one_chip),
+        _spec((N_LISTS, m), jnp.int32, one_chip),
+        _spec((N_LISTS, m), jnp.float32, one_chip),
+        _spec((nq, d), jnp.float32, one_chip), None,
+        k=k, n_probes=30, metric=DistanceType.InnerProduct, qt=128, probe_factor=32,
+        group=8, merge="bank8", code_mode=code_mode, ksub=ksub,
+        decode_cols=vmem_decode_cols(2048, **model), interpret=False))
+
+
 def test_rabitq_scan_compiles(one_chip):
     from raft_tpu.ops.pallas.rabitq_scan import ivf_rabitq_fused_search, vmem_decode_rows
 
