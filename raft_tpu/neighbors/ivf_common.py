@@ -64,21 +64,39 @@ def probe_selection(centers, qf, n_probes: int, metric) -> Tuple[jax.Array, jax.
     return coarse, probed
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _topk_block(xb, centers, cn, *, k: int):
-    score = 2.0 * (xb @ centers.T) - cn[None, :]  # max == nearest
-    _, idx = lax.top_k(score, k)
-    return idx.astype(jnp.int32)
+#: elements of the ``[rows, n_lists]`` score block :func:`topk_labels`
+#: holds at once (512 MiB of f32): 131,072 rows at 1,024 lists, 16,384 at
+#: 8,192
+TOPK_BLOCK_ELEMS = 131072 * 1024
 
 
-def topk_labels(ds_f32: jax.Array, centers: jax.Array, k: int = 4, block: int = 131072):
-    """Per-row k nearest center ids ``[n, k]`` — rankwise L2 via the norm
-    trick, blocked so [block, n_lists] is the peak temporary."""
-    n = ds_f32.shape[0]
-    k = min(k, centers.shape[0])
+@functools.partial(jax.jit, static_argnames=("k", "block"))
+def _topk_labels(x, centers, *, k: int, block: int):
+    """One program over ``block``-row slices of ``x``; the last slice
+    ends at the last row (overlapping its predecessor), so every slice
+    has the same shape and no padded copy of ``x`` is made."""
+    n = x.shape[0]
     cn = jnp.sum(centers * centers, axis=1)
-    outs = [_topk_block(ds_f32[s : s + block], centers, cn, k=k) for s in range(0, n, block)]
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+
+    def body(b, out):
+        s = jnp.minimum(b * block, n - block)
+        xb = lax.dynamic_slice_in_dim(x, s, block)
+        score = 2.0 * (xb @ centers.T) - cn[None, :]  # max == nearest
+        _, idx = lax.top_k(score, k)
+        return lax.dynamic_update_slice_in_dim(out, idx.astype(jnp.int32), s, axis=0)
+
+    return lax.fori_loop(0, -(-n // block), body, jnp.zeros((n, k), jnp.int32))
+
+
+def topk_labels(ds_f32: jax.Array, centers: jax.Array, k: int = 4) -> jax.Array:
+    """Per-row k nearest center ids ``[n, k]`` — rankwise L2 via the norm
+    trick, in row blocks of ``TOPK_BLOCK_ELEMS // n_lists`` rows, so the
+    ``[block, n_lists]`` scores are the peak temporary whatever the
+    number of lists."""
+    n, n_lists = ds_f32.shape[0], centers.shape[0]
+    k = min(k, n_lists)
+    block = max(1, min(n, TOPK_BLOCK_ELEMS // n_lists))
+    return _topk_labels(ds_f32, centers, k=k, block=block)
 
 
 def assign_slots(cand_labels, *, n_lists: int, max_list: int) -> jax.Array:
@@ -87,9 +105,10 @@ def assign_slots(cand_labels, *, n_lists: int, max_list: int) -> jax.Array:
     ``cand_labels [n, c]`` ranks each row's candidate lists nearest-first.
     One pass per candidate column (nearest list while it has room, then the
     next candidate, ...), then a final pass dropping stragglers into any
-    free slot. Returns ``slot [n] int32`` with every row placed (requires
-    ``n <= n_lists * max_list``); the final list of a row is
-    ``slot // max_list``.
+    free slot. A pass sorts only the rows still unplaced, and the passes
+    stop once every row is placed. Returns ``slot [n] int32`` with every
+    row placed (requires ``n <= n_lists * max_list``); the final list of
+    a row is ``slot // max_list``.
 
     Runs in numpy on the host (one ``[n, c]`` fetch). The same passes as
     one XLA program took 83 s to compile for a v5e at 1M rows and 1086 s
@@ -101,26 +120,28 @@ def assign_slots(cand_labels, *, n_lists: int, max_list: int) -> jax.Array:
     n, n_cand = cand.shape
     total = n_lists * max_list
 
-    def rank_within(labels, active):
-        """Stable rank of each active row within its label group."""
-        key = np.where(active, labels, n_lists)
-        order = np.argsort(key, kind="stable")
-        counts = np.bincount(key, minlength=n_lists + 1)
+    def rank_within(lab):
+        """Stable rank of each entry of ``lab`` within its label group."""
+        order = np.argsort(lab, kind="stable")
+        counts = np.bincount(lab, minlength=n_lists)
         start = np.cumsum(counts) - counts
-        rank = np.empty(n, np.int64)
-        rank[order] = np.arange(n) - start[key[order]]
+        rank = np.empty(lab.size, np.int64)
+        rank[order] = np.arange(lab.size) - start[lab[order]]
         return rank
 
     slot = np.full(n, total, np.int64)
     placed = np.zeros(n, bool)
     used = np.zeros(n_lists, np.int64)
     for c in range(n_cand):
-        lc = cand[:, c]
-        rank = rank_within(lc, ~placed)
-        fits = ~placed & (used[lc] + rank < max_list)
-        slot = np.where(fits, lc * max_list + used[lc] + rank, slot)
+        rows = np.flatnonzero(~placed)
+        if rows.size == 0:
+            break
+        lc = cand[rows, c]
+        dest = used[lc] + rank_within(lc)
+        fits = dest < max_list
+        slot[rows[fits]] = lc[fits] * max_list + dest[fits]
         used += np.bincount(lc[fits], minlength=n_lists)
-        placed |= fits
+        placed[rows[fits]] = True
 
     # final pass: leftovers, in row order, into the free slots in order
     filled = np.zeros(total, np.int8)

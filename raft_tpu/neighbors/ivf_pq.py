@@ -102,6 +102,14 @@ _SUPPORTED = (
 PER_SUBSPACE = "per_subspace"
 PER_CLUSTER = "per_cluster"
 
+#: Trainset rows a per-subspace codebook entry is trained on (the
+#: reference's ``max_train_points_per_pq_code`` default): books see a
+#: seeded subsample of at most this x 2^pq_bits rows. The residuals are
+#: held ``[rows, pq_dim, pq_len]``, whose short minor axis pads to a
+#: 128-lane tile on a TPU: DEEP-10M's whole 999,000-row trainset would
+#: need 32 GB there.
+MAX_TRAIN_POINTS_PER_PQ_CODE = 256
+
 
 def _default_pq_dim(dim: int) -> int:
     """Reference heuristic (``ivf_pq_types.hpp:588-601 calculate_pq_dim``):
@@ -611,6 +619,92 @@ def _rabitq_encode_all(ds_f32, labels, centers, rotation, centers_rot, metric, c
     return jnp.concatenate(codes, axis=0), jnp.concatenate(auxs, axis=0)
 
 
+def _assign(ds_f32, centers, n: int, n_lists: int, cap_factor: float):
+    """``(slot [n], max_list)``: each row's place in the capped list
+    layout, from its 8 nearest centers (``ivf_common``)."""
+    with obs.span("ivf_pq.build.assign", n=n, n_lists=n_lists) as sp:
+        cand = ivf_common.topk_labels(ds_f32, centers, k=8)
+        max_list = ivf_common.choose_max_list(cand[:, 0], n, n_lists, cap_factor)
+        slot = sp.sync(ivf_common.assign_slots(cand, n_lists=n_lists, max_list=max_list))
+    return slot, max_list
+
+
+def _train_codebooks(trainset, centers, rotation, params, *, pq_dim: int, pq_len: int,
+                     ksub: int, n_lists: int, per_cluster: bool, nibble: bool, k_cb):
+    """PQ codebooks trained on the trainset's rotated residuals: nibble
+    sum grids, one ``ksub``-center book per subspace, or one per cluster.
+    Per-subspace books see at most ``MAX_TRAIN_POINTS_PER_PQ_CODE *
+    ksub`` rows of the trainset, a seeded subsample."""
+    n_pq = MAX_TRAIN_POINTS_PER_PQ_CODE * ksub
+    if not per_cluster and n_pq < trainset.shape[0]:
+        rng = np.random.default_rng(params.seed + 1)
+        trainset = trainset[jnp.asarray(np.sort(rng.permutation(trainset.shape[0])[:n_pq]))]
+    t_labels, _ = min_cluster_and_distance(trainset, centers, metric=DistanceType.L2Expanded)
+    t_resid = _rotated_residuals(trainset, t_labels, centers, rotation, pq_dim)  # [nt, pq_dim, pq_len]
+    nt = t_resid.shape[0]
+
+    if nibble:
+        pq_centers = _train_nibble_books(t_resid, k_cb, params.kmeans_n_iters)
+    elif not per_cluster:
+        # [pq_dim, nt, pq_len] stacks; one vmapped Lloyd trains all subspaces.
+        Xs = jnp.transpose(t_resid, (1, 0, 2))
+        mask = jnp.ones((pq_dim, nt), jnp.float32)
+        init = _seed_books(k_cb, Xs, ksub)
+        pq_centers = _batched_lloyd(Xs, mask, init, k=ksub, n_iters=params.kmeans_n_iters)
+    else:
+        # Pool each cluster's residual subvectors (all subspaces), pad to a
+        # fixed per-cluster budget, and train all clusters in vmapped chunks.
+        lab_np = np.asarray(t_labels)
+        flat = np.asarray(t_resid).reshape(nt * pq_dim, pq_len)
+        row_cluster = np.repeat(lab_np, pq_dim)
+        order = np.argsort(row_cluster, kind="stable")
+        counts = np.bincount(row_cluster, minlength=n_lists)
+        budget = max(ksub, min(int(counts.max()) if n_lists else ksub, 4096))
+        n_trunc = int((counts > budget).sum())
+        if n_trunc:
+            logger.info(
+                "ivf_pq per-cluster codebooks: %d/%d clusters exceed the %d-row "
+                "training budget; a seeded random subsample of each is used "
+                "(lower kmeans_trainset_fraction or raise n_lists to avoid it)",
+                n_trunc,
+                n_lists,
+                budget,
+            )
+        sub_rng = np.random.default_rng(params.seed + 0x5EED)
+        Xc = np.zeros((n_lists, budget, pq_len), np.float32)
+        Mc = np.zeros((n_lists, budget), np.float32)
+        starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
+        for c in range(n_lists):
+            cnt = int(counts[c])
+            take = min(cnt, budget)
+            sel = order[starts[c] : starts[c] + cnt]
+            if cnt > budget:
+                # unbiased subsample instead of the first rows (which are
+                # ordered by training-set position, not representative)
+                sel = sel[sub_rng.choice(cnt, size=budget, replace=False)]
+            rows = flat[sel]
+            Xc[c, :take] = rows
+            Mc[c, :take] = 1.0
+            if take < ksub and take > 0:  # ensure >= ksub seed rows
+                Xc[c, take:ksub] = rows[np.arange(ksub - take) % take]
+        init = jnp.asarray(Xc[:, :ksub, :])
+        chunk = max(1, 128 // max(1, budget // 1024))
+        parts = []
+        Xc_j, Mc_j = jnp.asarray(Xc), jnp.asarray(Mc)
+        for s in range(0, n_lists, chunk):
+            parts.append(
+                _batched_lloyd(
+                    Xc_j[s : s + chunk],
+                    Mc_j[s : s + chunk],
+                    init[s : s + chunk],
+                    k=ksub,
+                    n_iters=params.kmeans_n_iters,
+                )
+            )
+        pq_centers = jnp.concatenate(parts, axis=0)
+    return pq_centers
+
+
 def build(
     dataset,
     params: Optional[IvfPqIndexParams] = None,
@@ -693,23 +787,26 @@ def build(
         trainset = ds_f32[jnp.asarray(rng.permutation(n)[:train_n])]
 
     # -- coarse quantizer (kmeans_balanced, as in the reference) ------------
-    centers = kmeans_balanced.fit(
-        trainset,
-        BalancedKMeansParams(
-            n_clusters=n_lists,
-            n_iters=params.kmeans_n_iters,
-            metric=DistanceType.L2Expanded,
-            seed=params.seed,
-        ),
-    )
-    # Physically order the lists by the PCA-bisection spatial rank of
-    # their centers (same as IVF-Flat v3): the fused Pallas scan's
-    # probe-coherent query tiles and group-granular DMA both assume
-    # spatially nearby lists sit next to each other.
-    from raft_tpu.ops.pallas.ivf_scan import spatial_center_rank
+    # Each phase of the build is a span (``ivf_pq.build.<phase>``): its
+    # outputs are synced at its end when observability records it.
+    with obs.span("ivf_pq.build.coarse", n=n, n_lists=n_lists, n_train=train_n) as sp:
+        centers = kmeans_balanced.fit(
+            trainset,
+            BalancedKMeansParams(
+                n_clusters=n_lists,
+                n_iters=params.kmeans_n_iters,
+                metric=DistanceType.L2Expanded,
+                seed=params.seed,
+            ),
+        )
+        # Physically order the lists by the PCA-bisection spatial rank of
+        # their centers (same as IVF-Flat v3): the fused Pallas scan's
+        # probe-coherent query tiles and group-granular DMA both assume
+        # spatially nearby lists sit next to each other.
+        from raft_tpu.ops.pallas.ivf_scan import spatial_center_rank
 
-    srank = spatial_center_rank(np.asarray(centers))
-    centers = jnp.asarray(np.asarray(centers)[np.argsort(srank)])
+        srank = spatial_center_rank(np.asarray(centers))
+        centers = sp.sync(jnp.asarray(np.asarray(centers)[np.argsort(srank)]))
     center_rank = jnp.arange(n_lists, dtype=jnp.int32)
 
     # -- rotation + rotated centers ----------------------------------------
@@ -725,19 +822,21 @@ def build(
         # pq_centers stays a [1, 1, 1] placeholder (pq_len/ksub properties
         # are meaningless for this kind and never consulted).
         pq_centers = jnp.zeros((1, 1, 1), jnp.float32)
-        cand = ivf_common.topk_labels(ds_f32, centers, k=8)
-        max_list = ivf_common.choose_max_list(cand[:, 0], n, n_lists, params.list_cap_factor)
-        slot = ivf_common.assign_slots(cand, n_lists=n_lists, max_list=max_list)
+        slot, max_list = _assign(ds_f32, centers, n, n_lists, params.list_cap_factor)
         final_labels = (slot // max_list).astype(jnp.int32)
-        codes_dev, aux_dev = _rabitq_encode_all(
-            ds_f32, final_labels, centers, rotation, centers_rot, metric
-        )
-        codes, list_indices, list_sizes = ivf_common.scatter_rows(
-            codes_dev, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists, max_list=max_list
-        )
-        aux, _, _ = ivf_common.scatter_rows(
-            aux_dev, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists, max_list=max_list
-        )
+        with obs.span("ivf_pq.build.encode", n=n) as sp:
+            codes_dev, aux_dev = sp.sync(_rabitq_encode_all(
+                ds_f32, final_labels, centers, rotation, centers_rot, metric
+            ))
+        with obs.span("ivf_pq.build.layout", n=n, max_list=max_list) as sp:
+            codes, list_indices, list_sizes = ivf_common.scatter_rows(
+                codes_dev, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists,
+                max_list=max_list,
+            )
+            aux, _, _ = sp.sync(ivf_common.scatter_rows(
+                aux_dev, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists,
+                max_list=max_list,
+            ))
         return IvfPqIndex(
             centers=centers,
             centers_rot=centers_rot,
@@ -760,90 +859,33 @@ def build(
         )
 
     # -- codebook training on trainset residuals ---------------------------
-    t_labels, _ = min_cluster_and_distance(trainset, centers, metric=DistanceType.L2Expanded)
-    t_resid = _rotated_residuals(trainset, t_labels, centers, rotation, pq_dim)  # [nt, pq_dim, pq_len]
-    nt = t_resid.shape[0]
-
-    if nibble:
-        pq_centers = _train_nibble_books(t_resid, k_cb, params.kmeans_n_iters)
-    elif not per_cluster:
-        # [pq_dim, nt, pq_len] stacks; one vmapped Lloyd trains all subspaces.
-        Xs = jnp.transpose(t_resid, (1, 0, 2))
-        mask = jnp.ones((pq_dim, nt), jnp.float32)
-        init = _seed_books(k_cb, Xs, ksub)
-        pq_centers = _batched_lloyd(Xs, mask, init, k=ksub, n_iters=params.kmeans_n_iters)
-    else:
-        # Pool each cluster's residual subvectors (all subspaces), pad to a
-        # fixed per-cluster budget, and train all clusters in vmapped chunks.
-        lab_np = np.asarray(t_labels)
-        flat = np.asarray(t_resid).reshape(nt * pq_dim, pq_len)
-        row_cluster = np.repeat(lab_np, pq_dim)
-        order = np.argsort(row_cluster, kind="stable")
-        counts = np.bincount(row_cluster, minlength=n_lists)
-        budget = max(ksub, min(int(counts.max()) if n_lists else ksub, 4096))
-        n_trunc = int((counts > budget).sum())
-        if n_trunc:
-            logger.info(
-                "ivf_pq per-cluster codebooks: %d/%d clusters exceed the %d-row "
-                "training budget; a seeded random subsample of each is used "
-                "(lower kmeans_trainset_fraction or raise n_lists to avoid it)",
-                n_trunc,
-                n_lists,
-                budget,
-            )
-        sub_rng = np.random.default_rng(params.seed + 0x5EED)
-        Xc = np.zeros((n_lists, budget, pq_len), np.float32)
-        Mc = np.zeros((n_lists, budget), np.float32)
-        starts = np.concatenate([[0], np.cumsum(counts)[:-1]])
-        for c in range(n_lists):
-            cnt = int(counts[c])
-            take = min(cnt, budget)
-            sel = order[starts[c] : starts[c] + cnt]
-            if cnt > budget:
-                # unbiased subsample instead of the first rows (which are
-                # ordered by training-set position, not representative)
-                sel = sel[sub_rng.choice(cnt, size=budget, replace=False)]
-            rows = flat[sel]
-            Xc[c, :take] = rows
-            Mc[c, :take] = 1.0
-            if take < ksub and take > 0:  # ensure >= ksub seed rows
-                Xc[c, take:ksub] = rows[np.arange(ksub - take) % take]
-        init = jnp.asarray(Xc[:, :ksub, :])
-        chunk = max(1, 128 // max(1, budget // 1024))
-        parts = []
-        Xc_j, Mc_j = jnp.asarray(Xc), jnp.asarray(Mc)
-        for s in range(0, n_lists, chunk):
-            parts.append(
-                _batched_lloyd(
-                    Xc_j[s : s + chunk],
-                    Mc_j[s : s + chunk],
-                    init[s : s + chunk],
-                    k=ksub,
-                    n_iters=params.kmeans_n_iters,
-                )
-            )
-        pq_centers = jnp.concatenate(parts, axis=0)
+    with obs.span("ivf_pq.build.codebooks", pq_dim=pq_dim, ksub=ksub) as sp:
+        pq_centers = sp.sync(_train_codebooks(
+            trainset, centers, rotation, params, pq_dim=pq_dim, pq_len=pq_len, ksub=ksub,
+            n_lists=n_lists, per_cluster=per_cluster, nibble=nibble, k_cb=k_cb))
 
     # -- encode + pack the full dataset (on device) -------------------------
     # Capacity-capped assignment first (spilled rows encode against their
     # FINAL list's center so ADC distances stay consistent), then encode,
     # then one scatter into the padded layout. See ivf_common.py.
-    cand = ivf_common.topk_labels(ds_f32, centers, k=8)
-    max_list = ivf_common.choose_max_list(cand[:, 0], n, n_lists, params.list_cap_factor)
-    slot = ivf_common.assign_slots(cand, n_lists=n_lists, max_list=max_list)
+    slot, max_list = _assign(ds_f32, centers, n, n_lists, params.list_cap_factor)
     final_labels = (slot // max_list).astype(jnp.int32)
-    codes_dev = _encode_all(ds_f32, final_labels, centers, rotation, pq_centers, pq_dim, per_cluster)
-    codes, list_indices, list_sizes = ivf_common.scatter_rows(
-        codes_dev, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists, max_list=max_list
-    )
-    rot_sqnorms = _sqnorms_for(codes, centers_rot, pq_centers, per_cluster)
+    with obs.span("ivf_pq.build.encode", n=n) as sp:
+        codes_dev = sp.sync(_encode_all(
+            ds_f32, final_labels, centers, rotation, pq_centers, pq_dim, per_cluster))
     # bit-pack sub-byte widths whenever the row bitstream is byte-aligned
     # (4: two per byte; 3/5/6/7: spanning little-endian — all decoded by
     # the fused kernel's generic b-mode). Reference:
     # ivf_pq_types.hpp:129-164.
     packed = not nibble and params.pq_bits < 8 and (pq_dim * params.pq_bits) % 8 == 0
-    if packed:
-        codes = pack_codes_bits(codes, params.pq_bits)
+    with obs.span("ivf_pq.build.layout", n=n, max_list=max_list) as sp:
+        codes, list_indices, list_sizes = ivf_common.scatter_rows(
+            codes_dev, jnp.arange(n, dtype=jnp.int32), slot, n_lists=n_lists, max_list=max_list
+        )
+        rot_sqnorms = _sqnorms_for(codes, centers_rot, pq_centers, per_cluster)
+        if packed:
+            codes = pack_codes_bits(codes, params.pq_bits)
+        sp.sync((codes, list_indices, list_sizes, rot_sqnorms))
 
     return IvfPqIndex(
         centers=centers,
@@ -1747,11 +1789,12 @@ def _search_dispatch(
         )
 
         def run_fused(qc):
-            if obs.is_enabled():
+            stats = obs.is_enabled()
+            if stats:
                 # sub-tiles sharing each decoded list: static shapes only
                 obs.observe("ivf_pq.search.fused_subtiles",
                             float(fused_subtiles(qc.shape[0], **fused_model)))
-            return ivf_pq_fused_search(
+            out = ivf_pq_fused_search(
                 index.centers,
                 index.centers_rot,
                 rank,
@@ -1776,7 +1819,16 @@ def _search_dispatch(
                 # capped inside to what the VMEM model leaves at this shape
                 decode_cols=params.fused_decode_cols,
                 interpret=jax.default_backend() != "tpu",
+                stats=stats,
             )
+            if not stats:
+                return out
+            # what the sub-tile tables left out, read back only with obs on
+            dist, ind, dropped, units = out
+            obs.inc("ivf_pq.search.probes_dropped", float(dropped))
+            for u in np.asarray(units):
+                obs.observe("ivf_pq.search.tile_units", float(u))
+            return dist, ind
 
         from raft_tpu.neighbors.ivf_flat import _batched_search
 

@@ -66,6 +66,7 @@ from raft_tpu.ops.pallas.ivf_scan import (
     _extract_topk,
     _seg_compress,
     build_tile_probe_tables,
+    tile_table_stats,
 )
 
 _SUPPORTED = frozenset(
@@ -506,7 +507,7 @@ def fused_pq_topk(
     static_argnames=(
         "k", "n_probes", "metric", "qt", "probe_factor", "group",
         "has_filter", "merge", "code_mode", "ksub", "extract_every",
-        "decode_cols", "interpret",
+        "decode_cols", "interpret", "stats",
     ),
 )
 def ivf_pq_fused_search(
@@ -534,13 +535,18 @@ def ivf_pq_fused_search(
     extract_every: int = 0,
     decode_cols: int = 2048,
     interpret: bool = False,
-) -> Tuple[jax.Array, jax.Array]:
+    stats: bool = False,
+):
     """IVF-PQ search through the Pallas fused scan. Candidate-set
     semantics match the probe path whenever the tile probe union fits the
     table (see :func:`ivf_scan.ivf_flat_fused_search`); scores are exact
     ADC distances of the (possibly additive-nibble) codebooks, so pairing
     with :func:`raft_tpu.neighbors.refine.refine` mirrors the reference's
-    refinement ratio workflow."""
+    refinement ratio workflow.
+
+    Returns ``(dist [nq, k], ind [nq, k])``; with ``stats`` also the
+    tables' ``(probes_dropped, tile_units [n_qt])`` of
+    :func:`ivf_scan.tile_table_stats`, computed in the same program."""
     nq, d = queries.shape
     n_lists, m, bpr = codes.shape
     qf = queries.astype(jnp.float32)
@@ -623,4 +629,7 @@ def ivf_pq_fused_search(
     order = order_pad[:nq]
     dist = jnp.zeros((nq, k), jnp.float32).at[order].set(out[:nq])
     ind = jnp.full((nq, k), -1, jnp.int32).at[order].set(idx[:nq])
+    if stats:
+        return (dist, ind) + tile_table_stats(
+            probed, order_pad, tile_probes, probe_valid, nq=nq, qt=qt, group=group)
     return dist, ind

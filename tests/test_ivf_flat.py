@@ -189,6 +189,29 @@ def test_assign_slots_matches_plain_reference(n, n_lists, n_cand, max_list):
     np.testing.assert_array_equal(got, _slots_one_row_at_a_time(cand, n_lists, max_list))
 
 
+@pytest.mark.parametrize("n,n_lists,block_rows", [
+    (1000, 64, 7),    # the last block overlaps its predecessor
+    (1000, 64, 250),  # blocks tile the rows exactly
+    (5, 16, 2),       # fewer rows than one default block
+])
+def test_topk_labels_blocked_matches_unblocked(n, n_lists, block_rows, monkeypatch):
+    """``topk_labels`` under a tiny score budget (``block_rows`` rows of
+    ``n_lists`` scores at once) returns the one-block result, and that
+    holds each row's nearest centers by squared L2."""
+    from raft_tpu.neighbors import ivf_common
+
+    rng = np.random.default_rng(n_lists)
+    x = jnp.asarray(rng.standard_normal((n, 12)), jnp.float32)
+    c = jnp.asarray(rng.standard_normal((n_lists, 12)), jnp.float32)
+    whole = np.asarray(ivf_common.topk_labels(x, c, k=8))  # one block: n <= the default
+    monkeypatch.setattr(ivf_common, "TOPK_BLOCK_ELEMS", block_rows * n_lists)
+    got = np.asarray(ivf_common.topk_labels(x, c, k=8))
+    assert got.shape == (n, 8) and got.dtype == np.int32
+    np.testing.assert_array_equal(got, whole)
+    d2 = ((np.asarray(x, np.float64)[:, None, :] - np.asarray(c, np.float64)[None]) ** 2).sum(-1)
+    np.testing.assert_allclose(np.take_along_axis(d2, got, 1), np.sort(d2, 1)[:, :8], rtol=1e-4)
+
+
 def test_query_batching(data, index):
     _, queries = data
     d1, i1 = ivf_flat.search(index, queries, K, n_probes=8, query_batch=64)

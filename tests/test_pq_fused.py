@@ -645,6 +645,95 @@ def test_subtile_sharing_keeps_ids(subtile_indexes, extract_every, monkeypatch):
     np.testing.assert_array_equal(out[1][0], out[3][0])
 
 
+@pytest.mark.parametrize("probe_factor, p", [(2, 8), (8, 32)], ids=["p8_drops", "p32_holds"])
+def test_sparse_tables_count_dropped_probes(subtile_indexes, probe_factor, p):
+    """32 one-list units, 4 probes a query, three 128-query sub-tiles
+    spread over the whole index: a sub-tile's queries probe more units
+    than the 8 a table of ``probe_factor`` 2 keeps, so that sizing drops
+    probes, and ``ivf_pq.search.probes_dropped`` counts exactly the
+    (query, list) pairs that no table of their sub-tile holds. A table of
+    every unit (factor 8) drops none; each query is then scored against
+    all its own lists, and every id of the scan path (which scores each
+    query's own lists) is among its fused candidates."""
+    from raft_tpu import obs
+    from raft_tpu.neighbors.ivf_common import probe_selection
+    from raft_tpu.ops.pallas.ivf_scan import build_tile_probe_tables
+
+    ds, qs, indexes = subtile_indexes
+    idx = indexes["nibble", DistanceType.InnerProduct]
+    k, n_probes, qt = 10, 4, 128
+    sp = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=qt, fused_probe_factor=probe_factor,
+                                  fused_group=1, fused_extract_every=1)
+    reg = obs.registry()
+    reg.reset()
+    obs.enable()
+    try:
+        _, i = ivf_pq.search(idx, qs, k, sp, mode="fused")
+        snap = obs.registry().as_dict()
+    finally:
+        obs.disable()
+        reg.reset()
+    counted = snap["counters"]["ivf_pq.search.probes_dropped"]
+    units = snap["histograms"]["ivf_pq.search.tile_units"]
+
+    # the same count, plainly: each query's probed lists against its table
+    nq = qs.shape[0]
+    coarse, probed = probe_selection(idx.centers, jnp.asarray(qs), n_probes, idx.metric)
+    order, tp, pv = build_tile_probe_tables(
+        coarse, probed, idx.center_rank, nq=nq, qt=qt, n_lists=idx.n_lists, group=1, n_probes=n_probes, probe_factor=probe_factor)
+    assert tp.shape == (3, p)
+    order, tp, pv, probed = (np.asarray(a) for a in (order, tp, pv, probed))
+    missing = 0
+    for row, q in enumerate(order[:nq]):
+        table = set(tp[row // qt][pv[row // qt] > 0].tolist())
+        missing += len(set(np.flatnonzero(probed[q]).tolist()) - table)
+    assert counted == missing
+    assert units["count"] == 3 and units["sum"] == pv.sum()
+    if p < idx.n_lists:
+        assert missing > 0  # the old sizing loses lists its queries probe
+        return
+    assert missing == 0
+    ref_i, _, _, cand = _subtile_reference(
+        idx, qs, k, n_probes=n_probes, probe_factor=probe_factor, group=1, qt=qt)
+    np.testing.assert_array_equal(np.asarray(i), ref_i)
+    _, i_scan = ivf_pq.search(idx, qs, k, ivf_pq.IvfPqSearchParams(n_probes=n_probes),
+                              mode="scan")
+    for q, row in enumerate(np.asarray(i_scan)):
+        assert set(row[row >= 0].tolist()) <= cand[q], q
+
+
+def test_deep_shape_fused_refine_is_exact():
+    """DEEP-10M's code shape at a small size: dim 96 by inner product on
+    unit rows, pq_dim 64 (pq_len 2, rot_dim 128), nibble books. The fused
+    scan plus an 8x exact refine reaches the recall floor against an
+    exact top-k in jax.numpy at HIGHEST, and every refined distance is
+    the exact inner product of the id it comes with."""
+    import jax
+
+    rng = np.random.default_rng(96)
+    centers = rng.standard_normal((40, 96)).astype(np.float32)
+    ds = centers[rng.integers(0, 40, 4000)] + rng.standard_normal((4000, 96)).astype(np.float32)
+    qs = centers[rng.integers(0, 40, 128)] + rng.standard_normal((128, 96)).astype(np.float32)
+    ds /= np.linalg.norm(ds, axis=1, keepdims=True)
+    qs /= np.linalg.norm(qs, axis=1, keepdims=True)
+    ip = DistanceType.InnerProduct
+    idx = ivf_pq.build(ds, ivf_pq.IvfPqIndexParams(
+        n_lists=32, pq_dim=64, pq_bits=8, pq_kind="nibble", metric=ip, kmeans_n_iters=5,
+        list_cap_factor=1.1, seed=7))
+    assert (idx.pq_len, idx.rotation.shape[0], idx.additive) == (2, 128, True)
+    k = 10
+    sp = ivf_pq.IvfPqSearchParams(n_probes=8, refine_ratio=8, fused_probe_factor=32)
+    d, i = ivf_pq.search(idx, qs, k, sp, mode="fused", dataset=jnp.asarray(ds))
+    d, i = np.asarray(d), np.asarray(i)
+
+    dot = jnp.dot(jnp.asarray(qs), jnp.asarray(ds).T, precision=jax.lax.Precision.HIGHEST)
+    ref_d, ref_i = jax.lax.top_k(dot, k)
+    rec = float(neighborhood_recall(i, np.asarray(ref_i)))
+    assert rec >= 0.9, rec
+    exact = np.einsum("qd,qkd->qk", qs.astype(np.float64), ds[i].astype(np.float64))
+    np.testing.assert_allclose(d, exact, rtol=0, atol=1e-5)
+
+
 def test_explicit_fused_f32_lut_warns():
     """An explicit mode="fused" + lut_dtype=float32 is a precision request
     the bf16 kernel cannot honor — it must warn, not silently ignore it.

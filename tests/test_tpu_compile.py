@@ -153,6 +153,50 @@ def test_pq_scan_compiles_glove_bucket(one_chip):
         decode_cols=vmem_decode_cols(2048, **model), interpret=False))
 
 
+def test_pq_scan_compiles_deep_bucket(one_chip):
+    """The deep10m-pq benchmark cell's shape: 9,990,000 x 96 by inner
+    product in 8,192 lists capped at 1,408 rows, nibble codes of 64 bytes
+    a row (pq_len 2, rot_dim 128), k 80, a 1,024-query bucket. Probe
+    factor 128 gives each sub-tile a table of all 1,024 units, so the
+    kernel's walk and its scalar-prefetch tables are [1, 1,024]; eight
+    sub-tiles share each decoded list."""
+    from raft_tpu.neighbors import ivf_pq
+    from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
+    from raft_tpu.ops.pallas.pq_scan import (
+        fused_subtiles,
+        ivf_pq_fused_search,
+        vmem_decode_cols,
+    )
+
+    d, m, nq, k, n_lists = 96, 1408, 1024, 80, 8192
+    x = np.random.default_rng(0).standard_normal((2048, d)).astype(np.float32)
+    idx = ivf_pq.build(jnp.asarray(x), ivf_pq.IvfPqIndexParams(
+        n_lists=8, pq_dim=64, pq_bits=8, pq_kind="nibble", kmeans_n_iters=2,
+        metric=DistanceType.InnerProduct))
+    code_mode, ksub = _fused_code_layout(idx)
+    books = nibble_books(idx.pq_centers)
+    bpr, rot = idx.codes.shape[2], idx.rotation.shape[0]
+    model = dict(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=128, k=k,
+                 g_lists=8, rot_dim=rot, merge="bank8")
+    assert (code_mode, bpr, rot, idx.pq_len) == ("nib8", 64, 128, 2)
+    assert fused_subtiles(nq, **model) == 8
+    lowered = ivf_pq_fused_search.lower(
+        _spec((n_lists, d), jnp.float32, one_chip),
+        _spec((n_lists, rot), jnp.float32, one_chip),
+        _spec((n_lists,), jnp.int32, one_chip),
+        _spec(idx.rotation.shape, jnp.float32, one_chip),
+        _spec(books.shape, jnp.float32, one_chip),
+        _spec((n_lists, m, bpr), jnp.uint8, one_chip),
+        _spec((n_lists, m), jnp.int32, one_chip),
+        _spec((n_lists, m), jnp.float32, one_chip),
+        _spec((nq, d), jnp.float32, one_chip), None,
+        k=k, n_probes=64, metric=DistanceType.InnerProduct, qt=128, probe_factor=128,
+        group=8, merge="bank8", code_mode=code_mode, ksub=ksub,
+        decode_cols=vmem_decode_cols(2048, **model), interpret=False)
+    assert "tensor<1x1024xi32>" in lowered.as_text()  # the unit walk's prefetch tables
+    _assert_kernel(lowered)
+
+
 def test_rabitq_scan_compiles(one_chip):
     from raft_tpu.ops.pallas.rabitq_scan import ivf_rabitq_fused_search, vmem_decode_rows
 
