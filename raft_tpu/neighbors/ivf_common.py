@@ -32,36 +32,82 @@ from raft_tpu.ops.distance import DistanceType
 from raft_tpu.utils.math import round_up
 
 
-def coarse_scores(centers, qf, metric) -> jax.Array:
+def coarse_scores(centers, qf, metric, row_norm: float = 0.0) -> jax.Array:
     """[nq, n_lists] coarse scores, smaller = better — the shared
     ``select_clusters`` ranking (``ivf_pq_search.cuh:67``) used by the
     probe mask, the fused Pallas path, and IVF-PQ. For cosine, ``qf`` must
-    already be unit-normalized (centers trained on normalized data)."""
-    q_dot_c = qf @ centers.T
+    already be unit-normalized (centers trained on normalized data).
+    ``row_norm`` is the index's :func:`one_norm` (see
+    :func:`coarse_from_dots`)."""
+    return coarse_from_dots(qf @ centers.T, centers, qf, metric, row_norm)
+
+
+def coarse_from_dots(q_dot_c, centers, qf, metric, row_norm: float = 0.0) -> jax.Array:
+    """:func:`coarse_scores` from the ``[nq, n_lists]`` products ``q.c``.
+
+    L2 and cosine rank by ``|c|^2 - 2 q.c`` (rankwise the L2 distance to
+    the center) and inner product by ``-q.c``, as ``select_clusters``
+    does. One case departs from it: an inner-product index whose rows all
+    have one norm ``s = row_norm > 0``. The build places every row by L2
+    (:func:`topk_labels`), and the inner-product order of rows of norm
+    ``s`` is their L2 order from the point ``s q / |q|``; so such an index ranks
+    its lists by the L2 distance from that point, ``|c|^2 - 2 s q.c /
+    |q|``, and a query probes the lists its best rows were placed in.
+    Ranked by ``q.c``, it passes over the low-norm centers that hold the
+    rows that overflowed their own list. Rows of differing norms keep
+    ``-q.c``: L2 placement says little about their inner products, and
+    the L2 score would favour low-norm lists."""
     if metric == DistanceType.InnerProduct:
-        return -q_dot_c
-    c_norm = jnp.sum(centers * centers, axis=1)
-    return c_norm[None, :] - 2.0 * q_dot_c
+        if not row_norm:
+            return -q_dot_c
+        qn = jnp.linalg.norm(qf, axis=1, keepdims=True)
+        q_dot_c = q_dot_c * (row_norm / jnp.maximum(qn, 1e-30))
+    return jnp.sum(centers * centers, axis=1)[None, :] - 2.0 * q_dot_c
 
 
-def probe_selection(centers, qf, n_probes: int, metric) -> Tuple[jax.Array, jax.Array]:
+#: widest relative spread of row norms, ``(max - min) / max``, under which
+#: :func:`one_norm` counts a dataset's rows as of one norm
+ONE_NORM_RTOL = 1e-2
+
+
+def one_norm(data) -> float:
+    """The norm every row of ``data`` has, to :data:`ONE_NORM_RTOL`
+    (their largest), or 0.0 when the rows differ in norm. One host sync
+    of two scalars."""
+    sq = jnp.sum(jnp.square(data.astype(jnp.float32)), axis=1)
+    lo, hi = (float(v) for v in jax.device_get((jnp.min(sq), jnp.max(sq))))
+    if hi <= 0.0 or math.sqrt(hi) - math.sqrt(lo) > ONE_NORM_RTOL * math.sqrt(hi):
+        return 0.0
+    return math.sqrt(hi)
+
+
+def probe_selection(centers, qf, n_probes: int, metric,
+                    row_norm: float = 0.0) -> Tuple[jax.Array, jax.Array]:
     """``(coarse [nq, n_lists], probed [nq, n_lists] bool)`` — the shared
     coarse ranking plus the per-query probe mask (``select_clusters``,
     ``ivf_flat_search-inl.cuh:145``). Single home for probe selection so
     the scan, probe, and fused paths cannot diverge."""
-    from raft_tpu.ops.select_k import select_k
-
     nq = qf.shape[0]
     n_lists = centers.shape[0]
-    coarse = coarse_scores(centers, qf, metric)
+    coarse = coarse_scores(centers, qf, metric, row_norm)
     if n_probes < n_lists:
-        _, probes = select_k(coarse, n_probes, select_min=True)
-        probed = jnp.zeros((nq, n_lists), bool).at[
-            jnp.arange(nq)[:, None], probes
-        ].set(True)
+        probes = probe_lists(coarse, n_probes)
+        probed = jnp.zeros((nq, n_lists), bool).at[jnp.arange(nq)[:, None], probes].set(True)
     else:
         probed = jnp.ones((nq, n_lists), bool)
     return coarse, probed
+
+
+def probe_lists(coarse, n_probes: int) -> jax.Array:
+    """``[nq, n_probes]`` the lists each query probes, nearest first, from
+    :func:`coarse_scores` — the selection :func:`probe_selection` masks;
+    every list when ``n_probes`` covers them all."""
+    from raft_tpu.ops.select_k import select_k
+
+    nq, n_lists = coarse.shape
+    if n_probes < n_lists:
+        return select_k(coarse, n_probes, select_min=True)[1]
+    return jnp.broadcast_to(jnp.arange(n_lists, dtype=jnp.int32), (nq, n_lists))
 
 
 #: elements of the ``[rows, n_lists]`` score block :func:`topk_labels`

@@ -183,10 +183,18 @@ class IvfPqSearchParams:
     # scan path, which honors it; ``mode="fused"`` always computes the
     # LUT in bf16 regardless.
     lut_dtype: Optional[jnp.dtype] = None
+    # rows of a fused kernel tile: queries for RaBitQ, (query, probe)
+    # pairs for PQ
     fused_qt: int = 128
+    # RaBitQ only: a query tile's probe table holds
+    # fused_probe_factor * n_probes lists. The PQ fused path ignores it:
+    # it scores every (query, probe) pair, so no probe can be dropped.
     fused_probe_factor: int = 32
     fused_group: int = 8
     fused_merge: str = "bank8"
+    # RaBitQ only: bank merges every this many units of a tile's table
+    # (0 = once at the end). The PQ fused path ignores it: a pair row
+    # sees one list, so its banks merge once.
     fused_extract_every: int = 0
     # max multi-hot columns materialized per decode chunk (VMEM bound for
     # wide codebooks: K = pq_dim * ksub columns total); 0 = single pass.
@@ -226,6 +234,10 @@ class IvfPqIndex:
     center_rank: Optional[jax.Array] = None  # [n_lists] spatial rank (v3+)
     rabitq: bool = False  # 1-bit sign codes + corrections (pq_kind="rabitq")
     corrections: Optional[jax.Array] = None  # [n_lists, max_list] f32 rabitq g
+    # inner product only: the one norm every indexed row has, 0.0 when
+    # they differ (v5+); it picks the coarse ranking
+    # (ivf_common.coarse_from_dots)
+    row_norm: float = 0.0
 
     def tree_flatten(self):
         return (
@@ -244,6 +256,7 @@ class IvfPqIndex:
             (
                 self.metric, self.codebook_kind, self.pq_bits, self.size,
                 self.list_cap_factor, self.additive, self.packed, self.rabitq,
+                self.row_norm,
             ),
         )
 
@@ -261,6 +274,7 @@ class IvfPqIndex:
             center_rank=children[8],
             rabitq=aux[7],
             corrections=children[9],
+            row_norm=aux[8],
         )
 
     @property
@@ -780,6 +794,7 @@ def build(
     k_rot, k_cb = jax.random.split(key)
 
     ds_f32 = dataset.astype(jnp.float32)
+    row_norm = ivf_common.one_norm(ds_f32) if metric == DistanceType.InnerProduct else 0.0
     train_n = max(n_lists, int(n * params.kmeans_trainset_fraction))
     trainset = ds_f32
     if train_n < n:
@@ -856,6 +871,7 @@ def build(
             center_rank=center_rank,
             rabitq=True,
             corrections=aux[..., 1],
+            row_norm=row_norm,
         )
 
     # -- codebook training on trainset residuals ---------------------------
@@ -904,6 +920,7 @@ def build(
         additive=nibble,
         packed=packed,
         center_rank=center_rank,
+        row_norm=row_norm,
     )
 
 
@@ -921,6 +938,11 @@ def extend(index: IvfPqIndex, new_vectors, new_ids=None) -> IvfPqIndex:
     vec_f32 = new_vectors.astype(jnp.float32)
     per_cluster = index.codebook_kind == PER_CLUSTER
     n_lists = index.n_lists
+    row_norm = index.row_norm
+    if row_norm:  # rows of another norm end the one-norm ranking
+        new_norm = ivf_common.one_norm(vec_f32)
+        if abs(new_norm - row_norm) > ivf_common.ONE_NORM_RTOL * row_norm:
+            row_norm = 0.0
 
     # Existing codes keep their list assignment (their residuals were
     # encoded against that center); compact them to the front on device.
@@ -978,6 +1000,7 @@ def extend(index: IvfPqIndex, new_vectors, new_ids=None) -> IvfPqIndex:
             rot_sqnorms=aux[..., 0],
             corrections=aux[..., 1],
             size=index.size + n_new,
+            row_norm=row_norm,
         )
     new_codes = _encode_all(
         vec_f32,
@@ -1000,6 +1023,7 @@ def extend(index: IvfPqIndex, new_vectors, new_ids=None) -> IvfPqIndex:
         list_sizes=list_sizes,
         rot_sqnorms=sqn,
         size=index.size + n_new,
+        row_norm=row_norm,
     )
 
 
@@ -1018,6 +1042,7 @@ def extend(index: IvfPqIndex, new_vectors, new_ids=None) -> IvfPqIndex:
         "has_filter",
         "chunk_lists",
         "bf16",
+        "row_norm",
     ),
 )
 def _ivf_pq_scan_impl(
@@ -1038,6 +1063,7 @@ def _ivf_pq_scan_impl(
     has_filter: bool,
     chunk_lists: int,
     bf16: bool,
+    row_norm: float = 0.0,
 ):
     """Dense decode-and-score scan — the TPU replacement for the reference's
     shared-memory LUT kernel (``detail/ivf_pq_compute_similarity-inl.cuh``).
@@ -1059,11 +1085,7 @@ def _ivf_pq_scan_impl(
     with obs.span("ivf_pq.search.coarse_probe", nq=nq, n_probes=n_probes) as sp:
         # coarse scores double as the probe selector AND the q.c_l term
         q_dot_c = qf @ centers.T  # [nq, n_lists]
-        if metric == DistanceType.InnerProduct:
-            coarse = -q_dot_c
-        else:
-            c_norm = jnp.sum(centers * centers, axis=1)
-            coarse = c_norm[None, :] - 2.0 * q_dot_c
+        coarse = ivf_common.coarse_from_dots(q_dot_c, centers, qf, metric, row_norm)
         n_lists = centers.shape[0]
         probed = jnp.zeros((nq, n_lists), bool)
         if n_probes < n_lists:
@@ -1216,7 +1238,8 @@ def pq_scan_core(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "n_probes", "metric", "per_cluster", "has_filter", "lut_dtype"),
+    static_argnames=("k", "n_probes", "metric", "per_cluster", "has_filter", "lut_dtype",
+                     "row_norm"),
 )
 def _ivf_pq_search_impl(
     centers,
@@ -1234,6 +1257,7 @@ def _ivf_pq_search_impl(
     per_cluster: bool,
     has_filter: bool,
     lut_dtype,
+    row_norm: float = 0.0,
 ):
     nq, d = queries.shape
     n_lists = centers.shape[0]
@@ -1242,11 +1266,7 @@ def _ivf_pq_search_impl(
 
     # -- coarse: nearest centers (select_clusters, ivf_pq_search.cuh:67) ----
     q_dot_c = qf @ centers.T
-    if metric == DistanceType.InnerProduct:
-        coarse = -q_dot_c
-    else:
-        c_norm = jnp.sum(centers * centers, axis=1)
-        coarse = c_norm[None, :] - 2.0 * q_dot_c
+    coarse = ivf_common.coarse_from_dots(q_dot_c, centers, qf, metric, row_norm)
     _, probes = select_k(coarse, n_probes, select_min=True)  # [nq, n_probes]
 
     q_rot = qf @ rotation.T  # [nq, rot_dim]
@@ -1318,7 +1338,7 @@ def _ivf_pq_search_impl(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("k", "n_probes", "metric", "has_filter", "chunk_lists"),
+    static_argnames=("k", "n_probes", "metric", "has_filter", "chunk_lists", "row_norm"),
 )
 def _ivf_rabitq_scan_impl(
     centers,
@@ -1335,6 +1355,7 @@ def _ivf_rabitq_scan_impl(
     metric: DistanceType,
     has_filter: bool,
     chunk_lists: int,
+    row_norm: float = 0.0,
 ):
     """Dense RaBitQ scan: the same probe/schedule skeleton as
     :func:`_ivf_pq_scan_impl` with the one-hot decode matmul replaced by a
@@ -1345,11 +1366,7 @@ def _ivf_rabitq_scan_impl(
 
     with obs.span("ivf_pq.search.coarse_probe", nq=nq, n_probes=n_probes) as sp:
         q_dot_c = qf @ centers.T  # [nq, n_lists]
-        if metric == DistanceType.InnerProduct:
-            coarse = -q_dot_c
-        else:
-            c_norm = jnp.sum(centers * centers, axis=1)
-            coarse = c_norm[None, :] - 2.0 * q_dot_c
+        coarse = ivf_common.coarse_from_dots(q_dot_c, centers, qf, metric, row_norm)
         n_lists = centers.shape[0]
         probed = jnp.zeros((nq, n_lists), bool)
         if n_probes < n_lists:
@@ -1472,7 +1489,7 @@ def rabitq_scan_core(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("k", "n_probes", "metric", "has_filter")
+    jax.jit, static_argnames=("k", "n_probes", "metric", "has_filter", "row_norm")
 )
 def _ivf_rabitq_probe_impl(
     centers,
@@ -1488,6 +1505,7 @@ def _ivf_rabitq_probe_impl(
     n_probes: int,
     metric: DistanceType,
     has_filter: bool,
+    row_norm: float = 0.0,
 ):
     """Probe-at-a-time RaBitQ estimator (memory-lean analog of
     :func:`_ivf_pq_search_impl`): gathers one list per query per step and
@@ -1498,11 +1516,7 @@ def _ivf_rabitq_probe_impl(
     D = bpr * 8
 
     q_dot_c = qf @ centers.T
-    if metric == DistanceType.InnerProduct:
-        coarse = -q_dot_c
-    else:
-        c_norm = jnp.sum(centers * centers, axis=1)
-        coarse = c_norm[None, :] - 2.0 * q_dot_c
+    coarse = ivf_common.coarse_from_dots(q_dot_c, centers, qf, metric, row_norm)
     _, probes = select_k(coarse, n_probes, select_min=True)  # [nq, n_probes]
 
     q_rot = qf @ rotation.T  # [nq, D]
@@ -1709,8 +1723,7 @@ def _search_dispatch(
         fused_ok = decode_feasible(
             m=index.codes.shape[1], code_mode=_cm, ksub=_ks,
             bpr=index.codes.shape[2],
-            qt=params.fused_qt, k=k, rot_dim=index.rotation.shape[0],
-            merge=params.fused_merge,
+            qt=params.fused_qt, k=k, merge=params.fused_merge,
         )
     # the fused kernel's LUT is bf16 by construction; an explicit float32
     # request is a precision demand auto must honor via the scan path
@@ -1742,7 +1755,7 @@ def _search_dispatch(
         obs.observe("ivf_pq.search.n_probes", float(n_probes))
 
     if mode == "fused":
-        from raft_tpu.ops.pallas.pq_scan import fused_subtiles, ivf_pq_fused_search
+        from raft_tpu.ops.pallas.pq_scan import ivf_pq_fused_search
 
         if wants_f32_lut:
             # auto routes f32-LUT requests to the scan path; an EXPLICIT
@@ -1769,35 +1782,15 @@ def _search_dispatch(
         # spanning bitstream peeled from (low, high) byte pairs, W in
         # natural j-major order
         books = nibble_books(index.pq_centers) if index.additive else index.pq_centers
-        rank = index.center_rank
-        group = params.fused_group
-        if rank is None:
-            # pre-v4 index: lists are in arbitrary k-means order — compute
-            # a rank for tile coherence, single-list DMA units for safety
-            from raft_tpu.neighbors.ivf_flat import _legacy_rank_cache
-
-            rank = _legacy_rank_cache(index.centers)
-            group = 1
-        group = max(1, min(group, index.n_lists))
+        group = max(1, min(params.fused_group, index.n_lists))
         while index.n_lists % group:
             group -= 1
 
-        fused_model = dict(
-            m=index.codes.shape[1], code_mode=code_mode, ksub=ksub,
-            bpr=index.codes.shape[2], qt=params.fused_qt, k=k, g_lists=group,
-            rot_dim=index.rotation.shape[0], merge=params.fused_merge,
-        )
-
         def run_fused(qc):
             stats = obs.is_enabled()
-            if stats:
-                # sub-tiles sharing each decoded list: static shapes only
-                obs.observe("ivf_pq.search.fused_subtiles",
-                            float(fused_subtiles(qc.shape[0], **fused_model)))
             out = ivf_pq_fused_search(
                 index.centers,
                 index.centers_rot,
-                rank,
                 index.rotation,
                 books,
                 index.codes,
@@ -1808,14 +1801,13 @@ def _search_dispatch(
                 k=k,
                 n_probes=n_probes,
                 metric=index.metric,
+                row_norm=index.row_norm,
                 qt=params.fused_qt,
-                probe_factor=params.fused_probe_factor,
                 group=group,
                 has_filter=filter_bits is not None,
                 merge=params.fused_merge,
                 code_mode=code_mode,
                 ksub=ksub,
-                extract_every=params.fused_extract_every,
                 # capped inside to what the VMEM model leaves at this shape
                 decode_cols=params.fused_decode_cols,
                 interpret=jax.default_backend() != "tpu",
@@ -1823,9 +1815,9 @@ def _search_dispatch(
             )
             if not stats:
                 return out
-            # what the sub-tile tables left out, read back only with obs on
-            dist, ind, dropped, units = out
-            obs.inc("ivf_pq.search.probes_dropped", float(dropped))
+            # the walk's MXU passes, read back only with obs on
+            dist, ind, passes, units = out
+            obs.inc("ivf_pq.search.list_passes", float(passes))
             for u in np.asarray(units):
                 obs.observe("ivf_pq.search.tile_units", float(u))
             return dist, ind
@@ -1873,6 +1865,7 @@ def _search_dispatch(
                 has_filter=filter_bits is not None,
                 chunk_lists=g,
                 bf16=scan_bf16(params.lut_dtype),
+                row_norm=index.row_norm,
             )
             if bpad:
                 v, i = v[:-bpad], i[:-bpad]
@@ -1916,6 +1909,7 @@ def _search_dispatch(
                     per_cluster=index.codebook_kind == PER_CLUSTER,
                     has_filter=filter_bits is not None,
                     lut_dtype=jnp.dtype(params.lut_dtype or jnp.float32).name,
+                    row_norm=index.row_norm,
                 )
             )
         if bpad:
@@ -2010,6 +2004,7 @@ def _rabitq_modes(
                 k=k,
                 n_probes=n_probes,
                 metric=index.metric,
+                row_norm=index.row_norm,
                 qt=params.fused_qt,
                 probe_factor=params.fused_probe_factor,
                 group=group,
@@ -2067,6 +2062,7 @@ def _rabitq_modes(
                 metric=index.metric,
                 has_filter=filter_bits is not None,
                 chunk_lists=g,
+                row_norm=index.row_norm,
             )
             if bpad:
                 v, i = v[:-bpad], i[:-bpad]
@@ -2102,6 +2098,7 @@ def _rabitq_modes(
                     n_probes=n_probes,
                     metric=index.metric,
                     has_filter=filter_bits is not None,
+                    row_norm=index.row_norm,
                 )
             )
         if bpad:
@@ -2118,7 +2115,7 @@ def _rabitq_modes(
 # ---------------------------------------------------------------------------
 
 _KIND = "ivf_pq"
-_VERSION = 4  # v4 adds the rabitq flag + corrections array
+_VERSION = 5  # v4 adds the rabitq flag + corrections array, v5 row_norm
 
 
 def _write_body(index: IvfPqIndex, stream: BinaryIO) -> None:
@@ -2131,6 +2128,7 @@ def _write_body(index: IvfPqIndex, stream: BinaryIO) -> None:
     ser.serialize_scalar(stream, int(index.packed), "int32")
     ser.serialize_scalar(stream, int(index.center_rank is not None), "int32")
     ser.serialize_scalar(stream, int(index.rabitq), "int32")
+    ser.serialize_scalar(stream, float(index.row_norm), "float64")
     ser.serialize_array(stream, index.centers)
     ser.serialize_array(stream, index.centers_rot)
     ser.serialize_array(stream, index.rotation)
@@ -2167,6 +2165,7 @@ def load(stream: BinaryIO, res: Optional[Resources] = None) -> IvfPqIndex:
         has_rank = bool(ser.deserialize_scalar(stream, "int32"))
     if version >= 4:
         rabitq = bool(ser.deserialize_scalar(stream, "int32"))
+    row_norm = float(ser.deserialize_scalar(stream, "float64")) if version >= 5 else 0.0
     centers = ser.deserialize_array(stream)
     centers_rot = ser.deserialize_array(stream)
     rotation = ser.deserialize_array(stream)
@@ -2199,6 +2198,7 @@ def load(stream: BinaryIO, res: Optional[Resources] = None) -> IvfPqIndex:
         center_rank=center_rank,
         rabitq=rabitq,
         corrections=corrections,
+        row_norm=row_norm,
     )
 
 

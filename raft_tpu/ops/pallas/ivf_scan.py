@@ -464,25 +464,6 @@ def build_tile_probe_tables(
     return order_pad, tile_probes, probe_valid
 
 
-def tile_table_stats(probed, order_pad, tile_probes, probe_valid, *, nq: int, qt: int,
-                     group: int):
-    """``(probes_dropped, tile_units [n_qt])`` of the tables of
-    :func:`build_tile_probe_tables`: the probed (query, list) pairs whose
-    unit no valid entry of the query's sub-tile table holds (a table of
-    ``p`` entries keeps the ``p`` units its queries probe most), and the
-    valid units of each sub-tile's table."""
-    n_qt = tile_probes.shape[0]
-    n_units = probed.shape[1] // group
-    row_real = (jnp.arange(n_qt * qt) < nq)[:, None]
-    lists_in_unit = (probed[order_pad] & row_real).reshape(n_qt, qt, n_units, group)
-    lists_in_unit = jnp.sum(lists_in_unit.astype(jnp.int32), axis=3)
-    held = jnp.zeros((n_qt, n_units), jnp.int32).at[
-        jnp.arange(n_qt)[:, None], tile_probes
-    ].max(probe_valid)
-    dropped = jnp.sum(jnp.where(held[:, None, :] > 0, 0, lists_in_unit))
-    return dropped, jnp.sum(probe_valid, axis=1)
-
-
 # ---------------------------------------------------------------------------
 # full search wrapper
 # ---------------------------------------------------------------------------

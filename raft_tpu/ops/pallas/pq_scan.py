@@ -9,7 +9,7 @@ and accumulates ``sum_j LUT[j, code_j]`` over the probed list's codes.
 TPU design
 ----------
 TPUs have no fast per-lane gather, so the LUT lookup becomes a **multi-hot
-matmul**: per query tile, the LUT ``W[q, (j, c)] = <q_sub[j], books[j, c]>``
+matmul**: per query batch, the LUT ``W[q, (j, c)] = <q_sub[j], books[j, c]>``
 is computed ONCE outside the kernel ([nq, K] bf16, K = pq_dim * ksub) and
 the kernel scores a code block by expanding its codes to a multi-hot
 ``S [rows, K]`` (pq_dim ones per row, built with VPU compares) and taking
@@ -19,17 +19,20 @@ multiple of the raw-vector scan's — and the DMA drops to the CODE bytes
 on bandwidth-bound hardware the compressed index scans faster than raw
 vectors and an order of magnitude beyond what fits in HBM raw.
 
-Probe scheduling, tile-coherent query ordering, scalar-prefetch DMA of
-only the probed code blocks, and the bank-merge running top-k are shared
-with the IVF-Flat fused scan (:mod:`raft_tpu.ops.pallas.ivf_scan`).
-
-``S`` depends on the list alone, so the grid is unit-major: for each
-group of ``n_sub`` query sub-tiles it walks the units any of their
-per-sub-tile tables holds, decodes each list once into a VMEM scratch,
-and scores it for the member sub-tiles only (:func:`shared_unit_tables`).
-A query's candidates stay its own sub-tile's lists; ``n_sub`` comes from
-the VMEM model (:func:`fused_subtiles`), and ``n_sub = 1`` is the
-one-tile schedule.
+The kernel walks (query, probe) **pairs**, not queries. The wrapper
+sorts a call's ``nq * n_probes`` pairs by list and cuts them into tiles
+of ``qt`` pairs; each pair row carries its query's LUT row and the
+coarse ``q.c`` term of its list. ``S`` depends on the list alone, and a
+tile's lists form one ascending run, so the 1-D grid walks the (tile,
+unit) steps in ascending list order (:func:`pair_walk`): each list a
+tile holds is decoded once into a VMEM scratch and scored by one
+``[qt, K] @ S^T`` matmul, and only the rows of its own pairs keep a
+finite score. Each query sees exactly its own ``n_probes`` lists, and
+the MXU makes one pass a list plus one at each tile boundary. The
+scalar-prefetched unit ids drive the DMA of only the probed code
+blocks; on a tile's last step the bank merge of
+:mod:`raft_tpu.ops.pallas.ivf_scan` gives each pair its top ``k``, and
+the wrapper takes each query's top ``k`` of its pairs' in XLA.
 
 Code layouts (``code_mode``):
 
@@ -50,7 +53,7 @@ reference's PQ metric set).
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -61,13 +64,8 @@ from jax.experimental.pallas import tpu as pltpu
 from raft_tpu.core.errors import expects
 from raft_tpu.ops.distance import DistanceType
 from raft_tpu.ops.pallas import vmem_model
-from raft_tpu.ops.pallas.ivf_scan import (
-    _eff_banks,
-    _extract_topk,
-    _seg_compress,
-    build_tile_probe_tables,
-    tile_table_stats,
-)
+from raft_tpu.ops.pallas.ivf_scan import _eff_banks, _extract_topk, _seg_compress
+from raft_tpu.utils.math import cdiv
 
 _SUPPORTED = frozenset(
     {
@@ -167,9 +165,8 @@ def _decode_chunk_budget(*, m: int, code_mode: str, ksub: int, bpr: int,
                          **model_kwargs) -> int:
     """Bytes of scoped VMEM one decode chunk may use at this shape:
     ``VMEM_HEADROOM x PQ_SCAN_VMEM_LIMIT_BYTES`` minus the kernel's
-    fixed residents (LUT rows, queries, multi-hot scratch, bank/acc
-    scratch of every sub-tile, double-buffered code+epilogue DMA, dot
-    accumulator) as accounted by
+    fixed residents (pair LUT rows, multi-hot scratch, bank scratch,
+    double-buffered code+epilogue DMA, dot accumulator) as accounted by
     :func:`raft_tpu.ops.pallas.vmem_model.pq_decode_chunk_budget`."""
     return vmem_model.pq_decode_chunk_budget(
         m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, **model_kwargs
@@ -179,7 +176,7 @@ def _decode_chunk_budget(*, m: int, code_mode: str, ksub: int, bpr: int,
 def decode_feasible(*, m: int, code_mode: str, ksub: int, bpr: int,
                     **model_kwargs) -> bool:
     """Whether the one-list multi-hot scratch plus one lane-aligned
-    decode chunk fit the derived VMEM budget at one sub-tile — false for
+    decode chunk fit the derived VMEM budget — false for
     very long lists with wide codebooks, where the fused kernel cannot
     compile and callers must use the scan path instead."""
     budget = _decode_chunk_budget(
@@ -203,7 +200,7 @@ def vmem_decode_cols(requested: int, *, m: int, code_mode: str, ksub: int,
     rounds down to a multiple of :func:`vmem_model.decode_lanes`. Raises
     when even one such chunk cannot fit (use :func:`decode_feasible` to
     route such shapes to the scan path up front). ``model_kwargs``
-    (``qt``/``k``/``g_lists``/``rot_dim``/``merge``/``n_sub``) refine
+    (``qt``/``k``/``g_lists``/``merge``) refine
     the resident accounting; omitted ones fall back to conservative
     defaults."""
     Kp = vmem_model.padded_cols(code_mode, ksub, bpr)
@@ -223,59 +220,30 @@ def vmem_decode_cols(requested: int, *, m: int, code_mode: str, ksub: int,
     return max(lanes, requested // lanes * lanes)
 
 
-def fused_subtiles(nq: int, *, m: int, code_mode: str, ksub: int, bpr: int,
-                   qt: int = 128, **model_kwargs) -> int:
-    """How many ``qt``-query sub-tiles of an ``nq``-query call share each
-    decoded list (:func:`vmem_model.pq_scan_subtiles`): a function of the
-    static shapes alone, so a caller can observe it without a sync."""
-    return vmem_model.pq_scan_subtiles(
-        -(-nq // qt), m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=qt,
-        **model_kwargs)
-
-
-def _make_pq_kernel(*, k, metric, merge, qt, n_sub, m, g_lists, n_steps, Kp,
-                    code_mode, ksub, bpr, extract_every, decode_cols):
-    banks = _eff_banks(merge, m, 0)
+def _make_pq_kernel(*, k, metric, qt, m, g_lists, Kp, banks, code_mode, ksub,
+                    bpr, decode_cols):
     _, gw = _code_groups(code_mode, ksub, bpr)
-    rows = n_sub * qt
     # decode in lane-aligned column chunks, so the byte-spread temps of
     # 256-entry codebooks (Kp = pq_dim * 256) stay inside VMEM
     lanes = vmem_model.decode_lanes(code_mode, ksub, bpr)
     chunk = max(lanes, min(decode_cols or Kp, Kp) // lanes * lanes)
+    first_bit, last_bit = 1 << g_lists, 1 << (g_lists + 1)
 
-    def sub_rows(t):
-        """Rows of sub-tile ``t`` in the grid row's LUT/bank blocks."""
-        return pl.ds(pl.multiple_of(t * qt, qt), qt)
+    def kernel(tile_ref, unit_ref, flags_ref, w_ref, plist_ref, pterm_ref,
+               cod_ref, ln_ref, outv_ref, outi_ref, bankv, banki, s_ref):
+        s = pl.program_id(0)
+        flags = flags_ref[s]
 
-    def kernel(pr_ref, pv_ref, mb_ref, w_ref, qrot_ref, crot_ref, cod_ref,
-               ln_ref, outv_ref, outi_ref, accv, acci, bankv, banki, s_ref):
-        i = pl.program_id(0)
-        j = pl.program_id(1)
-
-        def extract_subtile(t):
-            """Merge sub-tile ``t``'s banks into its running top-k."""
-            r = sub_rows(t)
-            cv = jnp.concatenate([accv[r, :], bankv[r, :]], axis=1)
-            ci = jnp.concatenate([acci[r, :], banki[r, :]], axis=1)
-            nv, ni = _extract_topk(cv, ci, k)
-            accv[r, :] = nv
-            acci[r, :] = ni
-            bankv[r, :] = jnp.full((qt, banks * 128), jnp.inf, jnp.float32)
-            banki[r, :] = jnp.full((qt, banks * 128), -1, jnp.int32)
-
-        @pl.when(j == 0)
+        @pl.when((flags & first_bit) != 0)
         def _():
-            accv[...] = jnp.full((rows, k), jnp.inf, jnp.float32)
-            acci[...] = jnp.full((rows, k), -1, jnp.int32)
-            bankv[...] = jnp.full((rows, banks * 128), jnp.inf, jnp.float32)
-            banki[...] = jnp.full((rows, banks * 128), -1, jnp.int32)
+            bankv[...] = jnp.full((qt, banks * 128), jnp.inf, jnp.float32)
+            banki[...] = jnp.full((qt, banks * 128), -1, jnp.int32)
 
-        @pl.when(pv_ref[i, j] > 0)
-        def _():
-            base = pr_ref[i, j] * (g_lists * m)
-            for g in range(g_lists):
-                # decode list g once; every sub-tile whose table holds
-                # this unit scores its own LUT rows against it
+        for g in range(g_lists):
+            # decode each list of the unit that a pair of the tile
+            # probes, once, and score the whole tile against it
+            @pl.when(((flags >> g) & 1) != 0)
+            def _(g=g):
                 cod = cod_ref[0, g * m : (g + 1) * m, :]  # [m, bpr] u8
                 for c0 in range(0, Kp, chunk):
                     c1 = min(c0 + chunk, Kp)
@@ -283,113 +251,125 @@ def _make_pq_kernel(*, k, metric, merge, qt, n_sub, m, g_lists, n_steps, Kp,
                         cod, code_mode=code_mode, ksub=ksub, m=m, bpr=bpr,
                         g0=c0 // gw, ng=(c1 - c0) // gw,
                     )
+                lst = unit_ref[s] * g_lists + g
+                # a row whose pair probes another list scores +inf; the
+                # pair's coarse q.c term is constant within its list
+                term = jnp.where(plist_ref[...] == lst, pterm_ref[...], jnp.inf)
+                dot = lax.dot_general(
+                    w_ref[...],
+                    s_ref[...],
+                    dimension_numbers=(((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                )  # [qt, m]
                 ln = ln_ref[0, 0, g * m : (g + 1) * m]  # prepared epilogue
+                if metric == DistanceType.InnerProduct:
+                    score = ln[None, :] - dot + term
+                else:
+                    score = ln[None, :] - 2.0 * dot + term
+                v, sl = _seg_compress(score, lst * m, qt, m, banks)
+                take = v < bankv[...]
+                bankv[...] = jnp.where(take, v, bankv[...])
+                banki[...] = jnp.where(take, sl, banki[...])
 
-                def score_subtile(t, carry):
-                    @pl.when(mb_ref[i * n_sub + t, j] > 0)
-                    def _():
-                        r = sub_rows(t)
-                        # coarse q.c term (q_rot.c_rot == q.c under the
-                        # orthonormal rotation): constant within a list,
-                        # so it folds into the epilogue as one column
-                        qdc = lax.dot_general(
-                            qrot_ref[r, :],
-                            crot_ref[0],
-                            dimension_numbers=(((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                        )[:, g][:, None]  # [qt, 1]
-                        dot = lax.dot_general(
-                            w_ref[r, :],
-                            s_ref[...],
-                            dimension_numbers=(((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32,
-                        )  # [qt, m]
-                        if metric == DistanceType.InnerProduct:
-                            score = ln[None, :] - dot - qdc
-                        else:
-                            score = ln[None, :] - 2.0 * (dot + qdc)
-                        v, sl = _seg_compress(score, base + g * m, qt, m, banks)
-                        take = v < bankv[r, :]
-                        bankv[r, :] = jnp.where(take, v, bankv[r, :])
-                        banki[r, :] = jnp.where(take, sl, banki[r, :])
-
-                    return carry
-
-                lax.fori_loop(0, n_sub, score_subtile, 0)
-
-            if extract_every:
-                # a sub-tile's banks merge after every extract_every-th
-                # unit of its own table (member flag 2), as they would
-                # on a walk of that table alone
-                def extract_due(t, carry):
-                    @pl.when(mb_ref[i * n_sub + t, j] > 1)
-                    def _():
-                        extract_subtile(t)
-
-                    return carry
-
-                lax.fori_loop(0, n_sub, extract_due, 0)
-
-        @pl.when(j == n_steps - 1)
+        @pl.when((flags & last_bit) != 0)
         def _():
-            def extract_last(t, carry):
-                extract_subtile(t)
-                return carry
-
-            lax.fori_loop(0, n_sub, extract_last, 0)
-            outv_ref[...] = accv[...]
-            outi_ref[...] = acci[...]
+            # a pair's candidates all come from its one list: one
+            # extraction of the banks gives its top k
+            outv_ref[...], outi_ref[...] = _extract_topk(bankv[...], banki[...], k)
 
     return kernel
 
 
-def kernel_scratch_shapes(rows: int, k: int, banks: int, m: int, Kp: int):
-    """The fused PQ kernel's scratch declarations: running top-k
-    accumulator pair + bank-merge pair for the ``rows`` queries of every
-    sub-tile of a grid row, and the one-list multi-hot ``[m, Kp]``.
-    Split out so tests can assert the VMEM residency model against the
-    shapes the kernel actually allocates (``vmem_model.pq_scan_residency``
+def kernel_scratch_shapes(qt: int, banks: int, m: int, Kp: int):
+    """The fused PQ kernel's scratch declarations: the bank-merge pair of
+    one ``qt``-pair tile and the one-list multi-hot ``[m, Kp]``. Split
+    out so tests can assert the VMEM residency model against the shapes
+    the kernel actually allocates (``vmem_model.pq_scan_residency``
     mirrors these)."""
     return [
-        pltpu.VMEM((rows, k), jnp.float32),
-        pltpu.VMEM((rows, k), jnp.int32),
-        pltpu.VMEM((rows, banks * 128), jnp.float32),
-        pltpu.VMEM((rows, banks * 128), jnp.int32),
+        pltpu.VMEM((qt, banks * 128), jnp.float32),
+        pltpu.VMEM((qt, banks * 128), jnp.int32),
         pltpu.VMEM((m, Kp), jnp.bfloat16),
     ]
 
 
-def shared_unit_tables(tile_probes, probe_valid, *, n_units: int, n_sub: int,
-                       extract_every: int = 0):
-    """Per-group-of-``n_sub``-sub-tiles walk of the probe units, from the
-    per-sub-tile tables of :func:`ivf_scan.build_tile_probe_tables`.
+class PairWalk(NamedTuple):
+    """A call's (query, probe) pairs sorted by list, cut into tiles of
+    ``qt`` pairs, and the kernel's walk over them (:func:`pair_walk`).
 
-    Returns ``(unit_probes [n_st, P], unit_valid [n_st, P], member
-    [n_qt, P])``: the ascending units any sub-tile of a group holds
-    (invalid slots re-address the row's last valid unit, as in the
-    per-sub-tile tables), and whether sub-tile ``t``'s own table holds
-    the unit of step ``j`` of its group: 2 where that unit is the
-    ``extract_every``-th of the sub-tile's own table since its last
-    merge. ``P = min(n_units, n_sub * p)``. With ``n_sub == 1`` this is
-    the per-sub-tile table itself."""
-    n_qt, p = tile_probes.shape
-    n_st = n_qt // n_sub
-    P = min(n_units, n_sub * p)
-    held = jnp.zeros((n_qt, n_units), jnp.int32).at[
-        jnp.arange(n_qt)[:, None], tile_probes
-    ].max(probe_valid).reshape(n_st, n_sub, n_units)
-    units = jnp.arange(n_units, dtype=jnp.int32)
-    key = jnp.where(jnp.max(held, axis=1) > 0, units, n_units)
-    unit_probes = jnp.sort(key, axis=1)[:, :P]
-    unit_valid = (unit_probes < n_units).astype(jnp.int32)
-    last = jnp.max(jnp.where(unit_valid > 0, unit_probes, 0), axis=1, keepdims=True)
-    unit_probes = jnp.where(unit_valid > 0, unit_probes, last)
-    member = jnp.take_along_axis(held, unit_probes[:, None, :], axis=2)
-    member = (member * unit_valid[:, None, :]).reshape(n_qt, P)
-    if extract_every:
-        due = (jnp.cumsum(member, axis=1) % extract_every == 0) & (member > 0)
-        member = member + due.astype(jnp.int32)
-    return unit_probes, unit_valid, member
+    Per sorted pair (``n_pad = n_tiles * qt``): ``pair_query`` and
+    ``pair_list`` (ascending; ``n_lists`` on padding pairs, which match
+    no list). ``inverse [nq * n_probes]``: the sorted position of pair
+    ``q * n_probes + j``. Per walk step: ``step_tile``, ``step_unit``
+    and ``step_flags`` (bit ``g`` set where the tile holds list ``g`` of
+    the unit; bits ``group`` and ``group + 1`` mark the tile's first and
+    last step). ``list_passes``: the (tile, list) decodes and matmuls of
+    the walk; ``tile_units [n_tiles]``: the units each tile holds."""
+
+    pair_query: jax.Array
+    pair_list: jax.Array
+    inverse: jax.Array
+    step_tile: jax.Array
+    step_unit: jax.Array
+    step_flags: jax.Array
+    list_passes: jax.Array
+    tile_units: jax.Array
+
+
+def pair_walk(probes, *, n_lists: int, group: int, qt: int) -> PairWalk:
+    """Sort the ``[nq, n_probes]`` (query, list) pairs of ``probes`` by
+    list, cut them into tiles of ``qt`` pairs, and lay out the walk of
+    (tile, unit) steps, ``unit`` = ``group`` adjacent lists, in
+    ascending list order.
+
+    A tile's lists form one ascending run and neighbouring tiles share
+    at most one, so the walk has at most ``n_tiles + n_units - 1`` steps
+    (and no more than there are pairs); the rest of the static length
+    are padding steps that hold no list and re-address the last blocks.
+    Each list is scored once, plus once more for each tile boundary that
+    falls inside its pairs."""
+    nq, n_probes = probes.shape
+    n_pairs = nq * n_probes
+    n_tiles = cdiv(n_pairs, qt)
+    n_pad = n_tiles * qt
+    n_units = n_lists // group
+    expects(n_lists % group == 0, "n_lists %d not divisible by group %d", n_lists, group)
+    expects(group <= 29, "a unit of %d lists overflows the step flags", group)
+    flat = jnp.pad(probes.reshape(-1).astype(jnp.int32), (0, n_pad - n_pairs),
+                   constant_values=n_lists)
+    perm = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    plist = flat[perm]
+    valid = perm < n_pairs
+    pquery = jnp.where(valid, perm // n_probes, 0)
+    inverse = jnp.zeros((n_pad,), jnp.int32).at[perm].set(
+        jnp.arange(n_pad, dtype=jnp.int32))[:n_pairs]
+
+    pos = jnp.arange(n_pad, dtype=jnp.int32)
+    tile = pos // qt
+    starts = pos % qt == 0
+    prev = jnp.concatenate([jnp.full((1,), -1, jnp.int32), plist[:-1]])
+    unit = plist // group
+    new_list = valid & (starts | (plist != prev))  # a (tile, list) pass
+    new_unit = valid & (starts | (unit != prev // group))  # a walk step
+    item = jnp.cumsum(new_unit.astype(jnp.int32)) - 1
+
+    n_steps = min(n_tiles + n_units - 1, n_pairs)
+    at = jnp.where(new_unit, item, n_steps)  # out of range: dropped
+    step_tile = jnp.full((n_steps,), n_tiles - 1, jnp.int32).at[at].set(tile, mode="drop")
+    step_unit = jnp.full((n_steps,), unit[n_pairs - 1], jnp.int32).at[at].set(
+        unit, mode="drop")
+    held = jnp.zeros((n_steps,), jnp.int32).at[jnp.where(new_list, item, n_steps)].add(
+        jnp.left_shift(1, plist % group), mode="drop")
+    edge = jnp.full((1,), -1, jnp.int32)
+    first = step_tile != jnp.concatenate([edge, step_tile[:-1]])
+    last = step_tile != jnp.concatenate([step_tile[1:], edge])
+    flags = (held | (first.astype(jnp.int32) << group)
+             | (last.astype(jnp.int32) << (group + 1)))
+    return PairWalk(
+        pquery, plist, inverse, step_tile, step_unit, flags,
+        jnp.sum(new_list.astype(jnp.int32)),
+        jnp.sum(new_unit.reshape(n_tiles, qt).astype(jnp.int32), axis=1),
+    )
 
 
 def pq_lut(q_rot, books) -> jax.Array:
@@ -410,110 +390,101 @@ def pq_lut(q_rot, books) -> jax.Array:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "metric", "qt", "n_sub", "merge", "code_mode", "ksub",
-        "extract_every", "decode_cols", "interpret",
+        "k", "metric", "qt", "group", "merge", "code_mode", "ksub",
+        "decode_cols", "interpret",
     ),
 )
 def fused_pq_topk(
-    codes,        # [n_units, gm, bpr] u8
-    ln,           # [n_units, 1, gm] f32 prepared epilogue (sqn/pen, +inf invalid)
-    w,            # [nq_pad, K] bf16 per-query LUT rows (tile-sorted)
-    q_rot,        # [nq_pad, rot_dim] f32 rotated queries (tile-sorted)
-    centers_rot,  # [n_units, G, rot_dim] f32 rotated coarse centers
-    unit_probes,  # [n_st, P] i32 ascending units of each sub-tile group
-    unit_valid,   # [n_st, P] i32
-    member,       # [n_st * n_sub, P] i32: sub-tile's own table holds the unit
-    #               (2: and its banks merge after this step)
+    codes,        # [n_units, group * m, bpr] u8
+    ln,           # [n_units, 1, group * m] f32 prepared epilogue (+inf invalid)
+    w,            # [n_pad, K] bf16: each sorted pair's LUT row
+    pair_list,    # [n_pad, 1] i32: each sorted pair's list
+    pair_term,    # [n_pad, 1] f32: each sorted pair's coarse epilogue term
+    step_tile,    # [n_steps] i32 }
+    step_unit,    # [n_steps] i32 } the walk of :func:`pair_walk`
+    step_flags,   # [n_steps] i32 }
     *,
     k: int,
     metric: DistanceType,
     qt: int,
-    n_sub: int = 1,
+    group: int,
     merge: str = "bank8",
     code_mode: str = "u8",
     ksub: int = 16,
-    extract_every: int = 0,
     decode_cols: int = 2048,
     interpret: bool = False,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Run the fused probed-list PQ scan; returns ``(scores [nq_pad, k]
-    asc, slots [nq_pad, k])`` with slot = unit * (G * max_list) + row.
+    """Run the fused PQ scan over a pair walk; returns ``(scores [n_pad,
+    k] asc, slots [n_pad, k])`` of each sorted pair, slot = list *
+    max_list + row.
 
-    The grid walks, for each group of ``n_sub`` query sub-tiles of
-    ``qt`` rows, the units any of them probes in ascending order
-    (:func:`shared_unit_tables`); each list of a unit is decoded once
-    into a VMEM multi-hot and scored by the member sub-tiles only, so
-    every query sees exactly the lists of its own sub-tile's table."""
+    The 1-D grid walks the (tile, unit) steps in ascending list order;
+    each list of a step that the tile holds is decoded once into a VMEM
+    multi-hot and scored for the tile's ``qt`` rows, of which only the
+    rows of its own pairs keep a finite score, so every pair sees
+    exactly its own list."""
     n_units, gm, bpr = codes.shape
-    nq_pad, K = w.shape
-    rot_dim = q_rot.shape[1]
-    n_st, n_steps = unit_probes.shape
-    g_lists = centers_rot.shape[1]
-    m = gm // g_lists
-    rows = n_sub * qt
-    expects(nq_pad == n_st * rows, "query rows %d != groups*n_sub*qt %d",
-            nq_pad, n_st * rows)
+    n_pad, K = w.shape
+    (n_steps,) = step_tile.shape
+    m = gm // group
+    expects(n_pad % qt == 0, "pair rows %d not a multiple of the tile %d", n_pad, qt)
     expects(merge.startswith("bank"), "pq fused scan requires a bank merge mode")
     Kp = vmem_model.padded_cols(code_mode, ksub, bpr)
     if Kp != K:
         w = jnp.pad(w, ((0, 0), (0, Kp - K)))  # zero LUT columns score 0
+    banks = _eff_banks(merge, m, 0)
 
     kernel = _make_pq_kernel(
-        k=k, metric=metric, merge=merge, qt=qt, n_sub=n_sub, m=m,
-        g_lists=g_lists, n_steps=n_steps, Kp=Kp, code_mode=code_mode,
-        ksub=ksub, bpr=bpr, extract_every=extract_every,
-        decode_cols=decode_cols,
+        k=k, metric=metric, qt=qt, m=m, g_lists=group, Kp=Kp, banks=banks,
+        code_mode=code_mode, ksub=ksub, bpr=bpr, decode_cols=decode_cols,
     )
-    banks = _eff_banks(merge, m, 0)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n_st, n_steps),
+        grid=(n_steps,),
         in_specs=[
-            pl.BlockSpec((rows, Kp), lambda i, j, pr, pv, mb: (i, 0)),
-            pl.BlockSpec((rows, rot_dim), lambda i, j, pr, pv, mb: (i, 0)),
-            pl.BlockSpec((1, g_lists, rot_dim), lambda i, j, pr, pv, mb: (pr[i, j], 0, 0)),
+            pl.BlockSpec((qt, Kp), lambda s, tile, unit, flags: (tile[s], 0)),
+            pl.BlockSpec((qt, 1), lambda s, tile, unit, flags: (tile[s], 0)),
+            pl.BlockSpec((qt, 1), lambda s, tile, unit, flags: (tile[s], 0)),
             # codes rows are deliberately narrow (bpr = 16-64 B/row is
             # the whole point of PQ): the lane padding the linter sees
             # costs VMEM but the HBM DMA moves only the real code bytes
-            pl.BlockSpec((1, gm, bpr), lambda i, j, pr, pv, mb: (pr[i, j], 0, 0)),  # graft-lint: ignore[tile-align]
-            pl.BlockSpec((1, 1, gm), lambda i, j, pr, pv, mb: (pr[i, j], 0, 0)),
+            pl.BlockSpec((1, gm, bpr), lambda s, tile, unit, flags: (unit[s], 0, 0)),  # graft-lint: ignore[tile-align]
+            pl.BlockSpec((1, 1, gm), lambda s, tile, unit, flags: (unit[s], 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((rows, k), lambda i, j, pr, pv, mb: (i, 0)),
-            pl.BlockSpec((rows, k), lambda i, j, pr, pv, mb: (i, 0)),
+            pl.BlockSpec((qt, k), lambda s, tile, unit, flags: (tile[s], 0)),
+            pl.BlockSpec((qt, k), lambda s, tile, unit, flags: (tile[s], 0)),
         ],
-        scratch_shapes=kernel_scratch_shapes(rows, k, banks, m, Kp),
+        scratch_shapes=kernel_scratch_shapes(qt, banks, m, Kp),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((nq_pad, k), jnp.float32),
-            jax.ShapeDtypeStruct((nq_pad, k), jnp.int32),
+            jax.ShapeDtypeStruct((n_pad, k), jnp.float32),
+            jax.ShapeDtypeStruct((n_pad, k), jnp.int32),
         ],
-        # the multi-hot scratch and every sub-tile's banks stay resident:
-        # past the 16 MiB default scope, inside v5e's 128 MiB of VMEM
+        # the one-list multi-hot scratch is past the 16 MiB default scope
+        # for 256-entry books; inside v5e's 128 MiB of VMEM
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES),
         interpret=interpret,
         # the op's name in a profile, which the benchmark's kernel
         # readers match: stable whatever the wrapper is called
         name="fused_pq_topk",
-    )(unit_probes, unit_valid, member, w, q_rot, centers_rot, codes, ln)
+    )(step_tile, step_unit, step_flags, w, pair_list, pair_term, codes, ln)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "n_probes", "metric", "qt", "probe_factor", "group",
-        "has_filter", "merge", "code_mode", "ksub", "extract_every",
-        "decode_cols", "interpret", "stats",
+        "k", "n_probes", "metric", "qt", "group", "has_filter", "merge",
+        "code_mode", "ksub", "decode_cols", "interpret", "stats", "row_norm",
     ),
 )
 def ivf_pq_fused_search(
     centers,
     centers_rot,
-    center_rank,
     rotation,
     books,        # [pq_dim_eff, ksub_eff, pq_len] f32, W column order
     codes,        # [n_lists, max_list, bpr] u8
@@ -526,47 +497,51 @@ def ivf_pq_fused_search(
     n_probes: int,
     metric: DistanceType,
     qt: int = 128,
-    probe_factor: int = 32,
     group: int = 8,
     has_filter: bool = False,
     merge: str = "bank8",
     code_mode: str = "u8",
     ksub: int = 16,
-    extract_every: int = 0,
     decode_cols: int = 2048,
     interpret: bool = False,
     stats: bool = False,
+    row_norm: float = 0.0,
 ):
-    """IVF-PQ search through the Pallas fused scan. Candidate-set
-    semantics match the probe path whenever the tile probe union fits the
-    table (see :func:`ivf_scan.ivf_flat_fused_search`); scores are exact
-    ADC distances of the (possibly additive-nibble) codebooks, so pairing
-    with :func:`raft_tpu.neighbors.refine.refine` mirrors the reference's
+    """IVF-PQ search through the Pallas fused scan. Each query is scored
+    against exactly its own ``n_probes`` lists, the candidate set of the
+    probe path (ranked by :func:`ivf_common.coarse_scores` with the
+    index's ``row_norm``); scores are exact ADC distances of the (possibly
+    additive-nibble) codebooks over a bf16 LUT, so pairing with
+    :func:`raft_tpu.neighbors.refine.refine` mirrors the reference's
     refinement ratio workflow.
 
+    The kernel gives each (query, probe) pair its top ``k``
+    (:func:`fused_pq_topk` over :func:`pair_walk`); each query's exact
+    top ``k`` of its ``n_probes * k`` pair candidates is taken here.
     Returns ``(dist [nq, k], ind [nq, k])``; with ``stats`` also the
-    tables' ``(probes_dropped, tile_units [n_qt])`` of
-    :func:`ivf_scan.tile_table_stats`, computed in the same program."""
+    walk's ``(list_passes, tile_units [n_tiles])``, computed in the same
+    program."""
     nq, d = queries.shape
     n_lists, m, bpr = codes.shape
     qf = queries.astype(jnp.float32)
 
-    from raft_tpu.neighbors.ivf_common import probe_selection
+    from raft_tpu.neighbors.ivf_common import coarse_scores, probe_lists
 
-    coarse, probed = probe_selection(centers, qf, n_probes, metric)
-    order_pad, tile_probes, probe_valid = build_tile_probe_tables(
-        coarse, probed, center_rank, nq=nq, qt=qt, n_lists=n_lists,
-        group=group, n_probes=n_probes, probe_factor=probe_factor,
-    )
-    nq_pad = order_pad.shape[0]
-    qs = qf[order_pad]
+    probes = probe_lists(coarse_scores(centers, qf, metric, row_norm), n_probes)
+    walk = pair_walk(probes, n_lists=n_lists, group=group, qt=qt)
 
-    # per-query LUT, in tile order (the q.c coarse term is computed
-    # in-kernel from q_rot x centers_rot — rotation-invariant)
-    q_rot = qs @ rotation.T
+    # per-query LUT, zero-padded to the multi-hot's columns, then one
+    # row for each sorted pair
+    q_rot = qf @ rotation.T
     w = pq_lut(q_rot, books)
-    n_units = n_lists // group
-    rot_dim = rotation.shape[0]
+    Kp = vmem_model.padded_cols(code_mode, ksub, bpr)
+    w = jnp.pad(w, ((0, 0), (0, Kp - w.shape[1])))
+    # each pair's coarse term q_rot.c_rot (== q.c under the orthonormal
+    # rotation), summed in f32 for the pairs alone, in the form the
+    # epilogue adds
+    pair_center = centers_rot[jnp.minimum(walk.pair_list, n_lists - 1)]
+    qdc = jnp.sum(q_rot[walk.pair_query] * pair_center, axis=1)
+    term = -qdc if metric == DistanceType.InnerProduct else -2.0 * qdc
 
     # prepared epilogue: sqn (+inf invalid) for L2, 0/+inf penalty for IP,
     # with the prefilter folded in
@@ -583,53 +558,50 @@ def ivf_pq_fused_search(
 
     from raft_tpu.ops.pallas._guard import kernel_guard
 
-    # sub-tiles that share each decoded list, and the decode chunk that
-    # fits beside their residents: both from the VMEM model at this shape
-    model = dict(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=qt, k=k,
-                 g_lists=group, rot_dim=rot_dim, merge=merge)
-    n_sub = fused_subtiles(nq, **model)
-    unit_probes, unit_valid, member = shared_unit_tables(
-        tile_probes, probe_valid, n_units=n_units, n_sub=n_sub,
-        extract_every=extract_every)
+    n_units = n_lists // group
     gm = group * m
     with kernel_guard("ivf_pq_fused_search"):
         vals, slots = fused_pq_topk(
             codes.reshape(n_units, gm, bpr),
             ln.reshape(n_units, 1, gm),
-            w,
-            q_rot,
-            centers_rot.reshape(n_units, group, rot_dim),
-            unit_probes,
-            unit_valid,
-            member,
+            w[walk.pair_query],
+            walk.pair_list[:, None],
+            term[:, None],
+            walk.step_tile,
+            walk.step_unit,
+            walk.step_flags,
             k=k,
             metric=metric,
             qt=qt,
-            n_sub=n_sub,
+            group=group,
             merge=merge,
             code_mode=code_mode,
             ksub=ksub,
-            extract_every=extract_every,
-            decode_cols=vmem_decode_cols(decode_cols, n_sub=n_sub, **model),
+            # capped to what the VMEM model leaves at this shape
+            decode_cols=vmem_decode_cols(
+                decode_cols, m=m, code_mode=code_mode, ksub=ksub, bpr=bpr,
+                qt=qt, k=k, g_lists=group, merge=merge),
             interpret=interpret,
         )
 
+    # each query's exact top k of its n_probes x k pair candidates
+    vals = vals[walk.inverse].reshape(nq, n_probes * k)
+    slots = slots[walk.inverse].reshape(nq, n_probes * k)
+    neg, best = lax.top_k(-vals, k)
+    vals = -neg
+    slots = jnp.take_along_axis(slots, best, axis=1)
+
     # postprocess (mirrors _ivf_pq_scan_impl's tail)
     flat_ids = list_indices.reshape(-1)
-    idx = jnp.where(slots >= 0, flat_ids[jnp.clip(slots, 0, None)], -1)
+    ind = jnp.where(slots >= 0, flat_ids[jnp.clip(slots, 0, None)], -1)
     if metric == DistanceType.InnerProduct:
-        out = -vals
+        dist = -vals
     else:
         qn = jnp.sum(q_rot * q_rot, axis=1)
-        out = jnp.maximum(qn[:, None] + vals, 0.0)
+        dist = jnp.maximum(qn[:, None] + vals, 0.0)
         if metric == DistanceType.L2SqrtExpanded:
-            out = jnp.sqrt(out)
-        out = jnp.where(idx >= 0, out, jnp.inf)
-
-    order = order_pad[:nq]
-    dist = jnp.zeros((nq, k), jnp.float32).at[order].set(out[:nq])
-    ind = jnp.full((nq, k), -1, jnp.int32).at[order].set(idx[:nq])
+            dist = jnp.sqrt(dist)
+        dist = jnp.where(ind >= 0, dist, jnp.inf)
     if stats:
-        return (dist, ind) + tile_table_stats(
-            probed, order_pad, tile_probes, probe_valid, nq=nq, qt=qt, group=group)
+        return dist, ind, walk.list_passes, walk.tile_units
     return dist, ind

@@ -327,6 +327,7 @@ def fused_rabitq_topk(
     static_argnames=(
         "k", "n_probes", "metric", "qt", "probe_factor", "group",
         "has_filter", "merge", "extract_every", "decode_rows", "interpret",
+        "row_norm",
     ),
 )
 def ivf_rabitq_fused_search(
@@ -352,6 +353,7 @@ def ivf_rabitq_fused_search(
     extract_every: int = 0,
     decode_rows: int = 0,
     interpret: bool = False,
+    row_norm: float = 0.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """IVF-RaBitQ search through the Pallas fused scan. Candidate-set
     semantics match the probe path whenever the tile probe union fits the
@@ -365,7 +367,7 @@ def ivf_rabitq_fused_search(
 
     from raft_tpu.neighbors.ivf_common import probe_selection
 
-    coarse, probed = probe_selection(centers, qf, n_probes, metric)
+    coarse, probed = probe_selection(centers, qf, n_probes, metric, row_norm)
     order_pad, tile_probes, probe_valid = build_tile_probe_tables(
         coarse, probed, center_rank, nq=nq, qt=qt, n_lists=n_lists,
         group=group, n_probes=n_probes, probe_factor=probe_factor,

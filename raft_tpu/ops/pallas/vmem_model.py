@@ -48,8 +48,8 @@ from typing import Optional, Tuple
 VMEM_LIMIT_BYTES = 16 * 1024 * 1024
 
 #: The scoped limit the fused PQ scan compiles under: one list's decoded
-#: multi-hot and the top-k banks of every query sub-tile that shares it
-#: stay resident across the grid. Half of v5e's 128 MiB.
+#: multi-hot of 256-entry books alone passes the 16 MiB default scope at
+#: the 1M-row bench shape. Half of v5e's 128 MiB.
 PQ_SCAN_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 #: Fraction of VMEM the model lets a kernel plan for. The remainder
@@ -167,6 +167,12 @@ class KernelResidency:
         return "\n".join(rows)
 
 
+#: Rounds of the PQ scan's top-k extraction whose (value, id) columns
+#: the TPU compiler keeps live at once (an empirical fit: its scoped
+#: allocation grows by one lane tile pair a round up to here).
+TOPK_LIVE_ROUNDS = 32
+
+
 def pq_scan_residency(
     *,
     m: int,
@@ -176,10 +182,8 @@ def pq_scan_residency(
     qt: int = 128,
     k: int = 128,
     g_lists: int = 8,
-    rot_dim: int = 128,
     merge: str = "bank8",
     decode_cols: int = 0,
-    n_sub: int = 1,
 ) -> KernelResidency:
     """Model ``pq_scan.fused_pq_topk``'s VMEM residency for one grid
     step. Mirrors the kernel's grid spec exactly — the shapes here are
@@ -187,9 +191,10 @@ def pq_scan_residency(
     tests (``test_pq_fused.py``), so the two cannot drift apart
     silently.
 
-    ``n_sub`` query sub-tiles of ``qt`` rows share each decoded list:
-    their LUT rows, queries, outputs, accumulators and banks are
-    resident together, beside the one-list multi-hot scratch.
+    One tile of ``qt`` (query, probe) pairs is resident: its LUT rows,
+    list ids, coarse terms and outputs, and its banks, beside the
+    one-list multi-hot scratch. The grid is one walk of (tile, unit)
+    steps, so every block moves along it and is double-buffered.
     ``decode_cols=0`` omits the decode chunk (useful for computing the
     fixed residents the chunk budget is solved against); defaults for
     ``qt``/``k``/``g_lists``/``merge`` match ``IvfPqSearchParams``
@@ -197,29 +202,28 @@ def pq_scan_residency(
     unknown — the k-sized residents are <3% of the stack)."""
     Kp = padded_cols(code_mode, ksub, bpr)
     gm = g_lists * m
-    rows = n_sub * qt
     banks = merge_banks(merge, m)
     T = dict(tiled=True)  # the narrow code rows and k columns pad lanes
     residents = [
-        # in tiles, in fused_pq_topk's in_specs order. Index maps that
-        # reference the inner grid axis j (the probe step) are
-        # double-buffered by the DMA pipeline; w/q_rot/outs only move
-        # with the outer (query) axis.
-        Resident("w_rows", (rows, Kp), 2, **T),              # bf16 LUT rows
-        Resident("q_rot", (rows, rot_dim), 4, **T),
-        Resident("centers_rot", (1, g_lists, rot_dim), 4, buffers=2, **T),
+        # in tiles, in fused_pq_topk's in_specs order
+        Resident("w_rows", (qt, Kp), 2, buffers=2, **T),     # bf16 LUT rows
+        Resident("pair_list", (qt, 1), 4, buffers=2, **T),
+        Resident("pair_term", (qt, 1), 4, buffers=2, **T),
         Resident("codes", (1, gm, bpr), 1, buffers=2, **T),
         Resident("ln", (1, 1, gm), 4, buffers=2, **T),
-        Resident("out_vals", (rows, k), 4, **T),
-        Resident("out_idx", (rows, k), 4, **T),
+        Resident("out_vals", (qt, k), 4, buffers=2, **T),
+        Resident("out_idx", (qt, k), 4, buffers=2, **T),
         # scratch_shapes, in declaration order
-        Resident("acc_vals", (rows, k), 4, kind="scratch", **T),
-        Resident("acc_idx", (rows, k), 4, kind="scratch", **T),
-        Resident("bank_vals", (rows, banks * 128), 4, kind="scratch", **T),
-        Resident("bank_idx", (rows, banks * 128), 4, kind="scratch", **T),
+        Resident("bank_vals", (qt, banks * 128), 4, kind="scratch", **T),
+        Resident("bank_idx", (qt, banks * 128), 4, kind="scratch", **T),
         Resident("multi_hot", (m, Kp), 2, kind="scratch", **T),
-        # peak kernel-body intermediates
+        # peak kernel-body intermediates: the [qt, m] dot, and the top-k
+        # extraction's per-round (value, id) columns, each [qt, 1] at a
+        # whole lane tile; the compiler's figure grows with k up to ~32
+        # rounds and no further (fitted at k 10, 40 and 80)
         Resident("dot_acc", (qt, m), 4, kind="body", **T),
+        Resident("topk_rounds", (2 * min(k, TOPK_LIVE_ROUNDS), qt, 1), 4,
+                 kind="body", **T),
     ]
     if decode_cols:
         # the chunk's byte-spread temps stream into the multi-hot scratch
@@ -244,9 +248,7 @@ def pq_decode_chunk_budget(
     qt: int = 128,
     k: int = 128,
     g_lists: int = 8,
-    rot_dim: int = 128,
     merge: str = "bank8",
-    n_sub: int = 1,
     limit: int = PQ_SCAN_VMEM_LIMIT_BYTES,
     headroom: float = VMEM_HEADROOM,
 ) -> int:
@@ -254,30 +256,14 @@ def pq_decode_chunk_budget(
     the kernel's fixed residents at this shape, the scoped limit being
     the one the kernel compiles under. Replaces the historical
     hand-calibrated 8 MB ``_DECODE_CHUNK_BUDGET``: the budget shrinks
-    for longer lists, wider code rows and more sub-tiles a decode, whose
-    fixed residents (multi-hot scratch, banks, code DMA buffers) grow.
-    May be <= 0: no chunk fits, the shape is fused-infeasible."""
+    for longer lists and wider code rows, whose fixed residents
+    (multi-hot scratch, code DMA buffers) grow. May be <= 0: no chunk
+    fits, the shape is fused-infeasible."""
     fixed = pq_scan_residency(
         m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=qt, k=k,
-        g_lists=g_lists, rot_dim=rot_dim, merge=merge, decode_cols=0,
-        n_sub=n_sub,
+        g_lists=g_lists, merge=merge, decode_cols=0,
     ).fixed_bytes
     return int(limit * headroom) - fixed
-
-
-def pq_scan_subtiles(n_qt: int, *, m: int, code_mode: str, ksub: int,
-                     bpr: int, **model_kwargs) -> int:
-    """How many of a call's ``n_qt`` query sub-tiles share one decoded
-    list in ``fused_pq_topk``: the whole call when its residents leave
-    room for one lane-aligned decode chunk, else the largest divisor of
-    ``n_qt`` that does (1, the one-tile schedule, at worst)."""
-    need = decode_cell_bytes(code_mode) * m * decode_lanes(code_mode, ksub, bpr)
-    for n in range(n_qt, 1, -1):
-        if n_qt % n == 0 and pq_decode_chunk_budget(
-            m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, n_sub=n, **model_kwargs
-        ) >= need:
-            return n
-    return 1
 
 
 #: Peak live bytes per (row, rot_dim-column) cell of one RaBitQ decode

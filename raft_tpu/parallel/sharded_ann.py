@@ -40,6 +40,7 @@ import functools
 
 from raft_tpu.core.errors import expects
 from raft_tpu.neighbors import cagra as cagra_mod, ivf_flat as ivf_flat_mod, ivf_pq as ivf_pq_mod
+from raft_tpu.neighbors.ivf_common import coarse_from_dots
 from raft_tpu.ops.distance import DistanceType
 from raft_tpu.ops.pallas._guard import kernel_guard
 from raft_tpu.ops.select_k import merge_parts, worst_value
@@ -312,7 +313,7 @@ def sharded_cagra_search(
 
 @functools.lru_cache(maxsize=64)
 def _ivf_pq_lists_fn(mesh, axis, k, n_probes, metric, g, bf16, l_local, masked=False,
-                     merge_mode="gather"):
+                     merge_mode="gather", row_norm=0.0):
     """Lists-sharded PQ search program: replicated centers/quantizers,
     per-shard decode scan over the local list slice, cross-shard exchange
     + merge (``merge_mode`` engine). ``masked=True`` adds the replicated
@@ -322,11 +323,7 @@ def _ivf_pq_lists_fn(mesh, axis, k, n_probes, metric, g, bf16, l_local, masked=F
         rank = lax.axis_index(axis)
         qf = q.astype(jnp.float32)
         q_dot_c = qf @ centers.T
-        if metric == DistanceType.InnerProduct:
-            coarse = -q_dot_c
-        else:
-            c_norm = jnp.sum(centers * centers, axis=1)
-            coarse = c_norm[None, :] - 2.0 * q_dot_c
+        coarse = coarse_from_dots(q_dot_c, centers, qf, metric, row_norm)
         nq = q.shape[0]
         n_lists = centers.shape[0]
         from raft_tpu.ops.select_k import select_k as _sk
@@ -415,7 +412,8 @@ def sharded_ivf_pq_lists_search(
     if masked:
         args.append(put(_health_array(health, n_shards), P()))
     build = lambda m: _ivf_pq_lists_fn(
-        mesh, axis, k, n_probes, index.metric, g, bf16, l_local, masked, m
+        mesh, axis, k, n_probes, index.metric, g, bf16, l_local, masked, m,
+        index.row_norm,
     )
     return _run_with_ring_fallback(build, args, mode)
 
@@ -836,6 +834,7 @@ def sharded_ivf_pq_build(
     if n_lists % n_shards == 0:
         sqnorms = per_shard(sqnorms, rows, rows, rep)
     rot_sqnorms = sqnorms(codes, centers_rot, books)
+    metric = ivf_pq_mod.resolve_metric(params.metric)
     return ivf_pq_mod.IvfPqIndex(
         centers=centers,
         centers_rot=centers_rot,
@@ -845,22 +844,25 @@ def sharded_ivf_pq_build(
         list_indices=list_indices,
         list_sizes=list_sizes,
         rot_sqnorms=rot_sqnorms,
-        metric=ivf_pq_mod.resolve_metric(params.metric),
+        metric=metric,
         codebook_kind=ivf_pq_mod.PER_SUBSPACE,
         pq_bits=params.pq_bits,
         size=n,
         list_cap_factor=params.list_cap_factor,
         center_rank=None,
+        row_norm=(ivf_common.one_norm(dataset)
+                  if metric == DistanceType.InnerProduct else 0.0),
     )
 
 
 @functools.lru_cache(maxsize=64)
-def _ivf_pq_fn(mesh, axis, k, n_probes, metric, per_cluster, g, bf16):
+def _ivf_pq_fn(mesh, axis, k, n_probes, metric, per_cluster, g, bf16, row_norm=0.0):
     def local(centers, centers_rot, rotation, pq_centers, codes, li, sqn, q):
         return ivf_pq_mod._ivf_pq_scan_impl(
             centers, centers_rot, rotation, pq_centers, codes, li, sqn, q, None,
             k=k, n_probes=n_probes, metric=metric,
             per_cluster=per_cluster, has_filter=False, chunk_lists=g, bf16=bf16,
+            row_norm=row_norm,
         )
 
     return jax.jit(
@@ -898,7 +900,8 @@ def sharded_ivf_pq_search(
     per_cluster = index.codebook_kind == ivf_pq_mod.PER_CLUSTER
     bf16 = ivf_pq_mod.scan_bf16(params.lut_dtype)
 
-    fn = _ivf_pq_fn(mesh, axis, k, n_probes, index.metric, per_cluster, g, bf16)
+    fn = _ivf_pq_fn(mesh, axis, k, n_probes, index.metric, per_cluster, g, bf16,
+                    index.row_norm)
     put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
     return fn(
         put(index.centers, P()),

@@ -214,3 +214,112 @@ class TestIvfPqExtendSerialize:
         np.testing.assert_allclose(np.asarray(v1), np.asarray(v2), rtol=1e-6)
         assert loaded.pq_bits == index.pq_bits
         assert loaded.codebook_kind == index.codebook_kind
+
+
+def _ip_blobs(norms, d=64, n_blobs=500, n=10000, nq=300, seed=0):
+    """Rows and queries drawn from unit-variance blobs around N(0, 1)
+    centers, scaled to unit length (``"one"``: an angular dataset served
+    as inner product) and then, for ``"mixed"``, to log-normal norms."""
+    rng = np.random.default_rng(seed)
+    c = rng.standard_normal((n_blobs, d))
+    x = c[rng.integers(0, n_blobs, n + nq)] + rng.standard_normal((n + nq, d))
+    x /= np.linalg.norm(x, axis=1, keepdims=True)
+    if norms == "mixed":
+        x *= np.exp(0.5 * rng.standard_normal(n + nq))[:, None]
+    x = x.astype(np.float32)
+    return x[:n], x[n:]
+
+
+@pytest.mark.parametrize("norms", ["one", "mixed"])
+def test_ip_coarse_ranking_follows_row_norms(norms):
+    """An inner-product index ranks its lists by the L2 placement score
+    only when its rows have one norm (``ivf_common.coarse_from_dots``).
+    Recall@10 after exact refine, under the index's own ranking and
+    under the other one, at 8 of 128 lists with lists capped at 1.1x:
+    rows of one norm find more under the placement score than under
+    ``q.c``; rows of log-normal norms find far less under it, so their
+    index keeps ``q.c``."""
+    import dataclasses
+
+    x, q = _ip_blobs(norms)
+    index = ivf_pq.build(x, IvfPqIndexParams(
+        n_lists=128, metric=DistanceType.InnerProduct, list_cap_factor=1.1,
+        kmeans_n_iters=10, pq_dim=16, seed=0))
+    if norms == "one":
+        assert index.row_norm == pytest.approx(1.0, rel=1e-5)
+        other = dataclasses.replace(index, row_norm=0.0)
+    else:
+        assert index.row_norm == 0.0
+        other = dataclasses.replace(index, row_norm=float(np.sqrt(np.mean(np.sum(x * x, 1)))))
+    _, truth = _exact(x, q, 10, DistanceType.InnerProduct)
+    sp = IvfPqSearchParams(n_probes=8)
+    recall = [float(neighborhood_recall(
+        np.asarray(ivf_pq.search(ix, q, 10, sp, mode="scan", dataset=x)[1]),
+        np.asarray(truth))) for ix in (index, other)]
+    assert recall[0] > recall[1] + (0.015 if norms == "one" else 0.08), recall
+
+
+def test_one_norm_rows_probe_where_they_were_placed():
+    """For rows of one norm, the ranking puts first the lists the build
+    placed the row in (``topk_labels``: L2 to the center), whatever the
+    query's own scale; with centers of unequal norms ``q.c`` alone would
+    send many rows' own queries elsewhere."""
+    import jax.numpy as jnp
+
+    from raft_tpu.neighbors import ivf_common
+
+    rng = np.random.default_rng(5)
+    x = rng.standard_normal((400, 16)).astype(np.float32)
+    x /= np.linalg.norm(x, axis=1, keepdims=True)
+    c = rng.standard_normal((32, 16)).astype(np.float32)
+    c *= np.linspace(0.2, 1.0, 32, dtype=np.float32)[:, None] / np.linalg.norm(c, axis=1, keepdims=True)
+    assert ivf_common.one_norm(jnp.asarray(x)) == pytest.approx(1.0, rel=1e-5)
+    x, c = jnp.asarray(x), jnp.asarray(c)
+    placed = np.asarray(ivf_common.topk_labels(x, c, k=3))
+    ip = DistanceType.InnerProduct
+    for scale in (1.0, 3.0):
+        coarse = ivf_common.coarse_scores(c, scale * x, ip, row_norm=1.0)
+        np.testing.assert_array_equal(np.asarray(ivf_common.probe_lists(coarse, 3)), placed)
+    by_dot = np.asarray(ivf_common.probe_lists(ivf_common.coarse_scores(c, x, ip), 1))
+    assert (by_dot[:, 0] != placed[:, 0]).mean() > 0.1
+
+
+@pytest.mark.parametrize("case", ["unit", "scaled", "mixed", "zero"])
+def test_one_norm(case):
+    """``one_norm`` gives the rows' common norm, and 0.0 when they differ
+    or are all zero."""
+    import jax.numpy as jnp
+
+    from raft_tpu.neighbors import ivf_common
+
+    rng = np.random.default_rng(3)
+    x = rng.standard_normal((500, 24)).astype(np.float32)
+    x /= np.linalg.norm(x, axis=1, keepdims=True)
+    want = {"unit": 1.0, "scaled": 2.5, "mixed": 0.0, "zero": 0.0}[case]
+    if case == "scaled":
+        x *= 2.5
+    elif case == "mixed":
+        x[::7] *= 1.1
+    elif case == "zero":
+        x[:] = 0.0
+    assert ivf_common.one_norm(jnp.asarray(x)) == pytest.approx(want, rel=1e-5)
+
+
+@pytest.mark.parametrize("step", ["save_load", "extend_same", "extend_other"])
+def test_row_norm_survives_save_and_extend(step):
+    """The one-norm flag of an inner-product index is saved and loaded,
+    kept by an extend with rows of the same norm, and cleared by one
+    with rows of another."""
+    x, _ = _ip_blobs("one", d=16, n_blobs=20, n=1200, nq=0)
+    index = ivf_pq.build(x[:1000], IvfPqIndexParams(
+        n_lists=8, metric=DistanceType.InnerProduct, kmeans_n_iters=5, pq_dim=8, seed=1))
+    assert index.row_norm == pytest.approx(1.0, rel=1e-5)
+    if step == "save_load":
+        buf = io.BytesIO()
+        ivf_pq.save(index, buf)
+        buf.seek(0)
+        assert ivf_pq.load(buf).row_norm == index.row_norm
+    elif step == "extend_same":
+        assert ivf_pq.extend(index, x[1000:]).row_norm == index.row_norm
+    else:
+        assert ivf_pq.extend(index, 2.0 * x[1000:]).row_norm == 0.0

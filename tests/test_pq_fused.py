@@ -328,7 +328,7 @@ def test_multi_hot_decode_every_width():
 def test_vmem_decode_cols_cap():
     """The VMEM model keeps the decode chunk under budget for any list
     length, in whole lane-aligned code groups, beside the one-list
-    multi-hot scratch and the banks of every sub-tile sharing it."""
+    multi-hot scratch and the pair tile's LUT rows and banks."""
     from raft_tpu.ops.pallas import vmem_model
     from raft_tpu.ops.pallas.pq_scan import vmem_decode_cols
 
@@ -338,9 +338,9 @@ def test_vmem_decode_cols_cap():
     assert dc % 256 == 0 and dc < 8192
     budget = vmem_model.pq_decode_chunk_budget(m=1152, code_mode="u8", ksub=256, bpr=32)
     assert 6 * 1152 * dc <= budget < 6 * 1152 * (dc + 256)
-    # more sub-tiles sharing one decode leave less room for the chunk
+    # a wider pair tile (more LUT rows and banks) leaves less room
     assert vmem_decode_cols(8192, m=1152, code_mode="u8", ksub=256, bpr=32,
-                            n_sub=4) < dc
+                            qt=256) < dc
     # short lists keep the request
     assert vmem_decode_cols(2048, m=256, code_mode="u8", ksub=256, bpr=32) == 2048
     # 0 = "single pass" still resolves to a bounded chunk
@@ -368,22 +368,21 @@ def test_vmem_decode_cols_cap():
 @pytest.mark.parametrize(
     "shape, measured_mib",
     [
-        # the 1M-row bench shape, one 128-query sub-tile: the 18 MiB
-        # multi-hot of 256-entry books dominates
-        (dict(m=1152, code_mode="u8", ksub=256, bpr=32, qt=128, k=10), 24.28),
-        # the glove200-pq cell: eight sub-tiles share each decoded list
-        (dict(m=1280, code_mode="nib8", ksub=16, bpr=64, qt=128, k=80,
-              rot_dim=256, n_sub=8), 23.44),
+        # the 1M-row bench shape: the 18 MiB multi-hot of 256-entry
+        # books dominates
+        (dict(m=1152, code_mode="u8", ksub=256, bpr=32, qt=128, k=10), 27.62),
+        # the glove200-pq cell: one 128-pair tile, k 80
+        (dict(m=1280, code_mode="nib8", ksub=16, bpr=64, qt=128, k=80), 14.90),
     ],
     ids=["bench_u8_256", "glove_nib8_8sub"],
 )
 def test_vmem_model_reproduces_measured_residency(shape, measured_mib):
     """The residency model must land within 5% of the scoped-VMEM
     allocation the TPU compiler reports for ``fused_pq_topk`` compiled
-    for a v5e at these shapes (the size in its refusal at any lower
-    limit; the compile passes at 25 and 24 MiB). The decode chunk's
-    temporaries do not enter that allocation (the same at 512 and 2048
-    columns); the model plans them on top, as a reserve."""
+    for a v5e at these shapes (the size in its refusal at a limit a few
+    MiB lower; the compile passes at 28 and 15 MiB). The decode chunk's
+    temporaries do not enter that allocation; the model plans them on
+    top, as a reserve."""
     from raft_tpu.ops.pallas import vmem_model
 
     res = vmem_model.pq_scan_residency(**shape)
@@ -393,9 +392,41 @@ def test_vmem_model_reproduces_measured_residency(shape, measured_mib):
         f"({err:.1%}):\n{res.table()}"
     planned = vmem_model.pq_scan_residency(decode_cols=2048, **shape)
     assert planned.total_bytes > measured
-    # the scratch that persists across the grid dominates
-    scratch = sum(r.nbytes for r in res.residents if r.kind == "scratch")
-    assert scratch > res.fixed_bytes / 2
+    # the one-list multi-hot scratch is the largest resident
+    assert max(res.residents, key=lambda r: r.nbytes).name == "multi_hot"
+
+
+@pytest.mark.parametrize(
+    "shape, measured_mib",
+    [
+        # the glove200-pq cell's bucket: 128 units, 30,720 pairs
+        (dict(m=1280, qt=128), 15.90),
+        (dict(m=1280, qt=64), 15.44),
+        # the deep10m-pq cell's bucket: 1,024 units, 65,536 pairs
+        (dict(m=1408, qt=128), 16.86),
+    ],
+    ids=["glove_qt128", "glove_qt64", "deep_qt128"],
+)
+def test_vmem_plan_covers_compiled_buckets(shape, measured_mib):
+    """At the PQ cells' own buckets the compiler's scoped-VMEM figure
+    for ``fused_pq_topk`` (compiled for a described v5e) is larger than
+    at the small grids above: it grows with the operands (units, pairs),
+    which a per-step model does not see, and it barely shrinks with the
+    tile. So the fixed residents read under it, by ~8% at 128-pair tiles
+    and ~28% at 64. What keeps the kernel inside its limit is the plan:
+    the fixed residents plus the capped decode chunk, which must stay
+    above the compiler's figure with the 25% headroom to spare."""
+    from raft_tpu.ops.pallas import vmem_model
+    from raft_tpu.ops.pallas.pq_scan import vmem_decode_cols
+
+    kw = dict(code_mode="nib8", ksub=16, bpr=64, k=80, g_lists=8, merge="bank8", **shape)
+    measured = measured_mib * 2**20
+    fixed = vmem_model.pq_scan_residency(**kw).fixed_bytes
+    assert fixed >= 0.7 * measured, (fixed, measured)
+    cols = vmem_decode_cols(2048, **kw)
+    planned = vmem_model.pq_scan_residency(decode_cols=cols, **kw).total_bytes
+    assert planned * vmem_model.VMEM_HEADROOM >= measured, (planned, measured)
+    assert planned <= vmem_model.VMEM_HEADROOM * vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES
 
 
 def test_vmem_model_matches_kernel_scratch_shapes():
@@ -407,27 +438,28 @@ def test_vmem_model_matches_kernel_scratch_shapes():
     from raft_tpu.ops.pallas.ivf_scan import _eff_banks
     from raft_tpu.ops.pallas.pq_scan import kernel_scratch_shapes
 
-    for m, merge, qt, k, n_sub, mode, ksub, bpr in [
-        (1152, "bank8", 128, 10, 1, "u8", 256, 32),
-        (256, "bank8", 128, 128, 4, "u8", 256, 32),
-        (1152, "bank4", 64, 10, 2, "nib8", 16, 64),
-        (100, "bank8", 128, 10, 8, "u8", 16, 20),  # K = 320 pads to 384
+    for m, merge, qt, k, mode, ksub, bpr in [
+        (1152, "bank8", 128, 10, "u8", 256, 32),
+        (256, "bank8", 128, 128, "u8", 256, 32),
+        (1152, "bank4", 64, 10, "nib8", 16, 64),
+        (100, "bank8", 128, 10, "u8", 16, 20),  # K = 320 pads to 384
     ]:
         banks = _eff_banks(merge, m, 0)
         assert vmem_model.merge_banks(merge, m) == banks, (merge, m)
         res = vmem_model.pq_scan_residency(
             m=m, code_mode=mode, ksub=ksub, bpr=bpr, qt=qt, k=k, merge=merge,
-            n_sub=n_sub,
         )
         model_scratch = [r for r in res.residents if r.kind == "scratch"]
         Kp = vmem_model.padded_cols(mode, ksub, bpr)
         assert Kp % 128 == 0 and Kp >= vmem_model.code_groups(mode, ksub, bpr)[0] * \
             vmem_model.code_groups(mode, ksub, bpr)[1]
-        decls = kernel_scratch_shapes(n_sub * qt, k, banks, m, Kp)
+        decls = kernel_scratch_shapes(qt, banks, m, Kp)
         assert len(model_scratch) == len(decls)
         for r, decl in zip(model_scratch, decls):
             assert tuple(decl.shape) == r.shape, r.name
             assert jnp.dtype(decl.dtype).itemsize == r.itemsize, r.name
+        # one pair tile's banks and the one-list multi-hot, no more
+        assert [r.name for r in model_scratch] == ["bank_vals", "bank_idx", "multi_hot"]
 
 
 def test_decode_budget_is_derived_not_hardcoded():
@@ -448,49 +480,109 @@ def test_decode_budget_is_derived_not_hardcoded():
     assert vmem_model.pq_decode_chunk_budget(
         limit=vmem_model.VMEM_LIMIT_BYTES, **shape) < 0
     # the budget shrinks as fixed residents grow: longer lists (bigger
-    # multi-hot, dot accumulator, code DMA buffers) and more sub-tiles
-    # sharing a decode (their LUT rows and banks)
+    # multi-hot, dot accumulator, code DMA buffers) and wider pair tiles
+    # (their LUT rows and banks)
     assert vmem_model.pq_decode_chunk_budget(**dict(shape, m=2304)) < budget
-    assert vmem_model.pq_decode_chunk_budget(n_sub=2, **shape) < budget
+    assert vmem_model.pq_decode_chunk_budget(**dict(shape, qt=256)) < budget
     # and the kernel-side wrapper agrees with the model
     assert pq_scan._decode_chunk_budget(**shape) == budget
 
 
-@pytest.mark.parametrize("nq, n_sub", [(1024, 8), (128, 1), (300, 3)])
-def test_glove_shape_shares_one_decode_per_bucket(nq, n_sub):
-    """At the glove200-pq cell's shape (m 1,280, nib8 with 64 bytes a
-    row, k 80, group 8) one decode serves the whole bucket under the
-    raised limit; a bucket of one sub-tile keeps the one-tile schedule."""
+@pytest.mark.parametrize("nq, n_tiles", [(1024, 240), (128, 30), (300, 71)])
+def test_glove_shape_shares_one_decode_per_bucket(nq, n_tiles):
+    """At the glove200-pq cell's shape (1,024 lists of 1,280 rows, nib8
+    with 64 bytes a row, k 80, 30 probes, group 8) a bucket's pairs cut
+    into ``n_tiles`` tiles of 128, and the walk decodes each list at most
+    once plus once a tile boundary: at most 1,024 + ``n_tiles`` MXU
+    passes, where a 128-query sub-tile union made 960 a sub-tile. The
+    whole 2,048-column multi-hot decodes in one chunk inside the limit."""
     from raft_tpu.ops.pallas import pq_scan, vmem_model
 
     shape = dict(m=1280, code_mode="nib8", ksub=16, bpr=64, qt=128, k=80,
-                 g_lists=8, rot_dim=256, merge="bank8")
-    assert pq_scan.fused_subtiles(nq, **shape) == n_sub
-    # ... with the whole 2,048-column multi-hot decoded in one chunk
-    assert pq_scan.vmem_decode_cols(2048, n_sub=n_sub, **shape) == 2048
-    res = vmem_model.pq_scan_residency(n_sub=n_sub, decode_cols=2048, **shape)
+                 g_lists=8, merge="bank8")
+    n_lists, n_probes, group = 1024, 30, 8
+    rng = np.random.default_rng(nq)
+    # each query probes 30 distinct lists around a random home list
+    home = rng.integers(0, n_lists, nq)
+    offs = np.stack([rng.permutation(96)[:n_probes] - 48 for _ in range(nq)])
+    probes = jnp.asarray((home[:, None] + offs) % n_lists, jnp.int32)
+    walk = pq_scan.pair_walk(probes, n_lists=n_lists, group=group, qt=128)
+    assert walk.tile_units.shape == (n_tiles,)
+    assert walk.step_tile.shape[0] <= n_tiles + n_lists // group
+    assert int(walk.list_passes) <= n_lists + n_tiles
+    assert pq_scan.vmem_decode_cols(2048, **shape) == 2048
+    res = vmem_model.pq_scan_residency(decode_cols=2048, **shape)
     assert res.total_bytes <= 0.75 * vmem_model.PQ_SCAN_VMEM_LIMIT_BYTES
 
 
-def _subtile_reference(idx, qs, k, *, n_probes, probe_factor, group, qt, keep=None):
-    """Exact top-k of each query over exactly the lists of its own
-    ``qt``-query sub-tile's probe table (``build_tile_probe_tables``),
-    scored by ADC from the materialized books, in float64. The LUT
-    entries are rounded to bfloat16 as the fused kernel's LUT is (for
-    nibble books, each half of the additive pair on its own), so ids can
-    be compared exactly. Returns ``(ids, dists, dists_f32_books, cand)``
-    with ``cand[q]`` the query's candidate row ids."""
-    import jax
-    from raft_tpu.neighbors.ivf_common import probe_selection
-    from raft_tpu.ops.pallas.ivf_scan import build_tile_probe_tables
+def _walk_plainly(probes, group, qt):
+    """The pair walk of ``probes`` built with Python loops: sorted pairs,
+    and the ``(tile, unit, held lists)`` steps in order."""
+    pairs = sorted((int(l), q) for q in range(probes.shape[0]) for l in probes[q])
+    steps = []
+    for t in range(-(-len(pairs) // qt)):
+        units = {}
+        for l, _ in pairs[t * qt:(t + 1) * qt]:
+            units.setdefault(l // group, set()).add(l % group)
+        steps += [(t, u, frozenset(g)) for u, g in sorted(units.items())]
+    return pairs, steps
+
+
+@pytest.mark.parametrize("nq", [1, 128, 300, 1024])
+def test_pair_walk_holds_every_pair_once(nq):
+    """Every (query, probe) pair lands in exactly one tile row, sorted by
+    list; ``inverse`` finds it again; the walk visits each tile's units
+    in ascending order, holds exactly the lists the tile's pairs probe,
+    flags each tile's first and last step, and is no longer than
+    ``n_tiles + n_units``."""
+    from raft_tpu.ops.pallas.pq_scan import pair_walk
+
+    n_lists, group, qt, n_probes = 96, 8, 128, 5
+    rng = np.random.default_rng(nq)
+    probes = np.stack([rng.permutation(n_lists)[:n_probes] for _ in range(nq)]).astype(np.int32)
+    walk = pair_walk(jnp.asarray(probes), n_lists=n_lists, group=group, qt=qt)
+    pq, pl_, inv, st, su, sf = (np.asarray(a) for a in walk[:6])
+    n_pairs = nq * n_probes
+    n_tiles, n_units = -(-n_pairs // qt), n_lists // group
+    pairs, steps = _walk_plainly(probes, group, qt)
+
+    assert pq.shape == pl_.shape == (n_tiles * qt,)
+    assert [(int(l), int(q)) for l, q in zip(pl_[:n_pairs], pq[:n_pairs])] == pairs
+    assert (pl_[n_pairs:] == n_lists).all()  # padding matches no list
+    q_of, j_of = np.divmod(np.arange(n_pairs), n_probes)
+    np.testing.assert_array_equal(pq[inv], q_of)
+    np.testing.assert_array_equal(pl_[inv], probes[q_of, j_of])
+
+    n_steps = st.shape[0]
+    assert n_steps <= n_tiles + n_units and n_steps <= n_pairs
+    held = [frozenset(g for g in range(group) if f >> g & 1) for f in sf]
+    got = [(int(t), int(u), h) for t, u, h in zip(st, su, held)]
+    assert got[: len(steps)] == steps
+    # padding steps hold nothing and re-address the last tile and unit
+    assert all(h == frozenset() for h in held[len(steps):])
+    assert (st[len(steps):] == n_tiles - 1).all() and (su[len(steps):] == su[len(steps) - 1]).all()
+    first, last = sf >> group & 1, sf >> (group + 1) & 1
+    np.testing.assert_array_equal(first, np.r_[1, st[1:] != st[:-1]])
+    np.testing.assert_array_equal(last, np.r_[st[1:] != st[:-1], 1])
+    assert int(walk.list_passes) == sum(len(h) for _, _, h in steps)
+    assert int(walk.list_passes) <= n_lists + n_tiles
+    np.testing.assert_array_equal(
+        np.asarray(walk.tile_units), np.bincount([t for t, _, _ in steps], minlength=n_tiles))
+
+
+def _own_probe_reference(idx, qs, k, *, n_probes, keep=None):
+    """Exact top-k of each query over exactly its own ``n_probes`` lists
+    (the candidate set of ``mode="probe"``), scored by ADC from the
+    materialized books, in float64. The LUT entries are rounded to
+    bfloat16 as the fused kernel's LUT is (for nibble books, each half of
+    the additive pair on its own), so ids can be compared exactly.
+    Returns ``(ids, dists, dists_f32_books, cand)`` with ``cand[q]`` the
+    query's candidate row ids."""
+    from raft_tpu.neighbors.ivf_common import coarse_scores, probe_lists
 
     nq = qs.shape[0]
     qf = jnp.asarray(qs, jnp.float32)
-    coarse, probed = probe_selection(idx.centers, qf, n_probes, idx.metric)
-    order_pad, tp, pv = build_tile_probe_tables(
-        coarse, probed, idx.center_rank, nq=nq, qt=qt, n_lists=idx.n_lists,
-        group=group, n_probes=n_probes, probe_factor=probe_factor)
-    order, tp, pv = np.asarray(order_pad)[:nq], np.asarray(tp), np.asarray(pv)
+    probes = np.asarray(probe_lists(coarse_scores(idx.centers, qf, idx.metric, idx.row_norm), n_probes))
 
     rot = np.asarray(idx.rotation, np.float64)
     q_rot = np.asarray(qs, np.float64) @ rot.T
@@ -514,11 +606,8 @@ def _subtile_reference(idx, qs, k, *, n_probes, probe_factor, group, qt, keep=No
     out_d = np.zeros((nq, k))
     out_f = np.zeros((nq, k))
     cand = []
-    row_of = np.empty(nq, np.int64)
-    row_of[order] = np.arange(nq)
     for q in range(nq):
-        t = row_of[q] // qt
-        lists = (tp[t][pv[t] > 0][:, None] * group + np.arange(group)).ravel()
+        lists = probes[q]
         li = ids[lists].ravel()
         ok = li >= 0
         if keep is not None:
@@ -545,7 +634,7 @@ def _subtile_reference(idx, qs, k, *, n_probes, probe_factor, group, qt, keep=No
 @pytest.fixture(scope="module")
 def subtile_indexes():
     """One index per (code layout, metric), built once for the parity
-    cases: 32 lists, so a sub-tile's 8-unit table leaves lists out."""
+    cases: 32 lists, so 4 probes a query leave most lists out."""
     ds, qs = _data(seed=31, n=3000, nq=300)
     out = {}
     for kind in ("nibble", "kmeans"):
@@ -561,14 +650,12 @@ def subtile_indexes():
                          ids=["l2", "ip"])
 @pytest.mark.parametrize("kind, code_mode", [("nibble", "nib8"), ("kmeans", "u8")])
 def test_fused_matches_subtile_reference(subtile_indexes, kind, code_mode, metric, filtered):
-    """300 queries span three 128-query sub-tiles (the last partial), all
-    sharing each decoded list. Every query must be scored against exactly
-    the lists of its own sub-tile's table: the fused ids equal the
-    per-sub-tile reference's, never a list of a neighbouring sub-tile's
-    table. ``extract_every=1`` with one-list units keeps every candidate
-    of a step in its own bank slot, so the kernel's top-k is exact; with
-    the default schedule (bank merges over the whole walk) the ids must
-    still come from the query's own candidate set."""
+    """300 queries, 4 probes each: 1,200 (query, probe) pairs in ten
+    128-pair tiles. Every query is scored against exactly its own lists:
+    the fused ids equal the own-probe reference's, never a list another
+    query probes. A pair row sees one list, whose rows all fit its banks
+    here, so the per-pair top-k and the per-query merge are exact, and
+    the DMA unit (one list or four) changes nothing."""
     from raft_tpu import obs
     from raft_tpu.core.bitset import Bitset
 
@@ -577,26 +664,26 @@ def test_fused_matches_subtile_reference(subtile_indexes, kind, code_mode, metri
     from raft_tpu.neighbors.ivf_pq import _fused_code_layout
 
     assert _fused_code_layout(idx)[0] == code_mode
-    k, n_probes, pf = 10, 4, 2
+    k, n_probes = 10, 4
     keep = prefilter = None
     if filtered:
         keep = np.ones(ds.shape[0], bool)
         keep[::3] = False
         prefilter = Bitset.from_unset_indices(ds.shape[0], jnp.asarray(np.flatnonzero(~keep), jnp.int32))
-    sp = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=128, fused_probe_factor=pf,
-                                  fused_group=1, fused_extract_every=1)
+    sp = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=128, fused_group=1)
     reg = obs.registry()
     reg.reset()
     obs.enable()
     try:
         d, i = ivf_pq.search(idx, qs, k, sp, prefilter=prefilter, mode="fused")
-        hist = obs.registry().as_dict()["histograms"]["ivf_pq.search.fused_subtiles"]
+        snap = obs.registry().as_dict()
     finally:
         obs.disable()
         reg.reset()
-    assert hist["count"] == 1 and hist["sum"] == 3.0  # 300 queries: 3 sub-tiles, one decode
-    ref_i, ref_d, ref_f, cand = _subtile_reference(
-        idx, qs, k, n_probes=n_probes, probe_factor=pf, group=1, qt=128, keep=keep)
+    passes = snap["counters"]["ivf_pq.search.list_passes"]
+    assert passes <= idx.n_lists + 10  # one pass a list, one more a tile boundary
+    assert snap["histograms"]["ivf_pq.search.tile_units"]["count"] == 10
+    ref_i, ref_d, ref_f, cand = _own_probe_reference(idx, qs, k, n_probes=n_probes, keep=keep)
     i, d = np.asarray(i), np.asarray(d)
     np.testing.assert_array_equal(i, ref_i)
     # a LUT entry on a bfloat16 rounding boundary may round the other way
@@ -607,63 +694,48 @@ def test_fused_matches_subtile_reference(subtile_indexes, kind, code_mode, metri
     np.testing.assert_allclose(d, ref_f, rtol=0.02, atol=0.02 * scale)
     if keep is not None:
         assert keep[i[i >= 0]].all()
-    # the default schedule: one bank walk over the whole union, 4-list
-    # units; still no query sees a list outside its own sub-tile's table
-    sp0 = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=128, fused_probe_factor=pf,
-                                   fused_group=4)
-    _, i0 = ivf_pq.search(idx, qs, k, sp0, prefilter=prefilter, mode="fused")
-    _, _, _, cand4 = _subtile_reference(
-        idx, qs, k, n_probes=n_probes, probe_factor=pf, group=4, qt=128, keep=keep)
-    for q, row in enumerate(np.asarray(i0)):
-        assert set(row[row >= 0].tolist()) <= cand4[q], q
+    # 4-list DMA units: the same walk over the same lists, the same answer
+    sp4 = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=128, fused_group=4)
+    _, i4 = ivf_pq.search(idx, qs, k, sp4, prefilter=prefilter, mode="fused")
+    np.testing.assert_array_equal(np.asarray(i4), ref_i)
+    for q, row in enumerate(i):
+        assert set(row[row >= 0].tolist()) <= cand[q], q
 
 
 @pytest.mark.parametrize("extract_every", [0, 2])
-def test_subtile_sharing_keeps_ids(subtile_indexes, extract_every, monkeypatch):
-    """How many sub-tiles share a decode changes the schedule, never the
-    result: one sub-tile a grid row (the one-tile schedule) and all three
-    of a 300-query call give identical ids and distances, banks and
-    their merge order included."""
-    from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
-    from raft_tpu.ops.pallas import pq_scan
-
+def test_subtile_sharing_keeps_ids(subtile_indexes, extract_every):
+    """The RaBitQ-only knobs (``fused_probe_factor``,
+    ``fused_extract_every``) and the DMA unit leave the PQ fused answer
+    alone: at any setting every query gets exactly the own-probe
+    reference's ids and distances."""
     ds, qs, indexes = subtile_indexes
     idx = indexes["nibble", DistanceType.L2Expanded]
-    code_mode, ksub = _fused_code_layout(idx)
-    args = (idx.centers, idx.centers_rot, idx.center_rank, idx.rotation,
-            nibble_books(idx.pq_centers), idx.codes, idx.list_indices,
-            idx.rot_sqnorms, jnp.asarray(qs), None)
-    kw = dict(k=10, n_probes=4, metric=idx.metric, qt=128, probe_factor=2, group=4,
-              code_mode=code_mode, ksub=ksub, extract_every=extract_every,
-              decode_cols=256, interpret=True)
-    out = {}
-    for n_sub in (1, 3):
-        monkeypatch.setattr(pq_scan, "fused_subtiles", lambda nq, n=n_sub, **_: n)
-        d, i = pq_scan.ivf_pq_fused_search.__wrapped__(*args, **kw)
-        out[n_sub] = np.asarray(d), np.asarray(i)
-    np.testing.assert_array_equal(out[1][1], out[3][1])
-    np.testing.assert_array_equal(out[1][0], out[3][0])
+    ref_i, ref_d, _, _ = _own_probe_reference(idx, qs, 10, n_probes=4)
+    for probe_factor, group in [(1, 1), (32, 8)]:
+        sp = ivf_pq.IvfPqSearchParams(
+            n_probes=4, fused_qt=128, fused_probe_factor=probe_factor, fused_group=group,
+            fused_extract_every=extract_every, fused_decode_cols=256)
+        d, i = ivf_pq.search(idx, qs, 10, sp, mode="fused")
+        np.testing.assert_array_equal(np.asarray(i), ref_i)
+        np.testing.assert_allclose(np.asarray(d), ref_d, rtol=1e-3, atol=1e-3)
 
 
-@pytest.mark.parametrize("probe_factor, p", [(2, 8), (8, 32)], ids=["p8_drops", "p32_holds"])
-def test_sparse_tables_count_dropped_probes(subtile_indexes, probe_factor, p):
-    """32 one-list units, 4 probes a query, three 128-query sub-tiles
-    spread over the whole index: a sub-tile's queries probe more units
-    than the 8 a table of ``probe_factor`` 2 keeps, so that sizing drops
-    probes, and ``ivf_pq.search.probes_dropped`` counts exactly the
-    (query, list) pairs that no table of their sub-tile holds. A table of
-    every unit (factor 8) drops none; each query is then scored against
-    all its own lists, and every id of the scan path (which scores each
-    query's own lists) is among its fused candidates."""
+@pytest.mark.parametrize("probe_factor", [2, 8], ids=["factor2", "factor8"])
+def test_sparse_tables_count_dropped_probes(subtile_indexes, probe_factor):
+    """32 one-list units, 4 probes a query, 300 queries spread over the
+    whole index. Whatever ``fused_probe_factor`` says (a factor-2 table
+    of 8 units once dropped probes here), no probe is dropped:
+    ``list_passes`` counts exactly the (tile, list) passes of the walk,
+    ``tile_units`` the units each of the ten pair tiles holds, and each
+    query is scored against all its own lists."""
     from raft_tpu import obs
-    from raft_tpu.neighbors.ivf_common import probe_selection
-    from raft_tpu.ops.pallas.ivf_scan import build_tile_probe_tables
+    from raft_tpu.neighbors.ivf_common import coarse_scores, probe_lists
 
     ds, qs, indexes = subtile_indexes
     idx = indexes["nibble", DistanceType.InnerProduct]
     k, n_probes, qt = 10, 4, 128
     sp = ivf_pq.IvfPqSearchParams(n_probes=n_probes, fused_qt=qt, fused_probe_factor=probe_factor,
-                                  fused_group=1, fused_extract_every=1)
+                                  fused_group=1)
     reg = obs.registry()
     reg.reset()
     obs.enable()
@@ -673,33 +745,64 @@ def test_sparse_tables_count_dropped_probes(subtile_indexes, probe_factor, p):
     finally:
         obs.disable()
         reg.reset()
-    counted = snap["counters"]["ivf_pq.search.probes_dropped"]
+    probes = np.asarray(probe_lists(coarse_scores(idx.centers, jnp.asarray(qs), idx.metric, idx.row_norm), n_probes))
+    _, steps = _walk_plainly(probes, 1, qt)
+    assert snap["counters"]["ivf_pq.search.list_passes"] == len(steps)
     units = snap["histograms"]["ivf_pq.search.tile_units"]
-
-    # the same count, plainly: each query's probed lists against its table
-    nq = qs.shape[0]
-    coarse, probed = probe_selection(idx.centers, jnp.asarray(qs), n_probes, idx.metric)
-    order, tp, pv = build_tile_probe_tables(
-        coarse, probed, idx.center_rank, nq=nq, qt=qt, n_lists=idx.n_lists, group=1, n_probes=n_probes, probe_factor=probe_factor)
-    assert tp.shape == (3, p)
-    order, tp, pv, probed = (np.asarray(a) for a in (order, tp, pv, probed))
-    missing = 0
-    for row, q in enumerate(order[:nq]):
-        table = set(tp[row // qt][pv[row // qt] > 0].tolist())
-        missing += len(set(np.flatnonzero(probed[q]).tolist()) - table)
-    assert counted == missing
-    assert units["count"] == 3 and units["sum"] == pv.sum()
-    if p < idx.n_lists:
-        assert missing > 0  # the old sizing loses lists its queries probe
-        return
-    assert missing == 0
-    ref_i, _, _, cand = _subtile_reference(
-        idx, qs, k, n_probes=n_probes, probe_factor=probe_factor, group=1, qt=qt)
+    assert units["count"] == 10 and units["sum"] == len(steps)
+    ref_i, _, _, cand = _own_probe_reference(idx, qs, k, n_probes=n_probes)
     np.testing.assert_array_equal(np.asarray(i), ref_i)
     _, i_scan = ivf_pq.search(idx, qs, k, ivf_pq.IvfPqSearchParams(n_probes=n_probes),
                               mode="scan")
     for q, row in enumerate(np.asarray(i_scan)):
         assert set(row[row >= 0].tolist()) <= cand[q], q
+
+
+def test_skewed_batch_spreads_one_list_over_tiles(subtile_indexes):
+    """All 300 queries probe the same two lists: 600 pairs of two lists
+    fill five tiles, so each list spans several consecutive tiles, and
+    every query still gets the exact top-k of its own two lists."""
+    from raft_tpu import obs
+    from raft_tpu.neighbors.ivf_common import coarse_scores, probe_lists
+
+    ds, _, indexes = subtile_indexes
+    idx = indexes["kmeans", DistanceType.L2Expanded]
+    rng = np.random.default_rng(5)
+    qs = np.asarray(idx.centers)[3] + 1e-3 * rng.standard_normal((300, ds.shape[1])).astype(np.float32)
+    probes = np.asarray(probe_lists(coarse_scores(idx.centers, jnp.asarray(qs), idx.metric, idx.row_norm), 2))
+    assert (np.sort(probes, axis=1) == np.sort(probes[0])).all()
+    sp = ivf_pq.IvfPqSearchParams(n_probes=2, fused_qt=128, fused_group=4)
+    reg = obs.registry()
+    reg.reset()
+    obs.enable()
+    try:
+        d, i = ivf_pq.search(idx, qs, 10, sp, mode="fused")
+        snap = obs.registry().as_dict()
+    finally:
+        obs.disable()
+        reg.reset()
+    # five tiles; the boundary between the lists falls inside the third
+    assert snap["counters"]["ivf_pq.search.list_passes"] == 6
+    assert snap["histograms"]["ivf_pq.search.tile_units"]["count"] == 5
+    ref_i, ref_d, _, _ = _own_probe_reference(idx, qs, 10, n_probes=2)
+    np.testing.assert_array_equal(np.asarray(i), ref_i)
+    # distances near 20 here: one LUT term that rounds to bfloat16 the
+    # other way from float64 moves one by ~0.03
+    np.testing.assert_allclose(np.asarray(d), ref_d, rtol=2e-3)
+
+
+@pytest.mark.parametrize("nq", [1, 128, 1024])
+def test_fused_batch_sizes_match_own_probes(subtile_indexes, nq):
+    """A batch of one query (one tile of a few pairs), one of 128 and one
+    of 1,024 (32 tiles) each return the own-probe reference's ids."""
+    ds, _, indexes = subtile_indexes
+    idx = indexes["nibble", DistanceType.InnerProduct]
+    rng = np.random.default_rng(nq)
+    qs = ds[rng.integers(0, ds.shape[0], nq)] + 0.5 * rng.standard_normal((nq, ds.shape[1]))
+    sp = ivf_pq.IvfPqSearchParams(n_probes=4, fused_qt=128, fused_group=8)
+    _, i = ivf_pq.search(idx, qs, 10, sp, mode="fused", query_batch=1024)
+    ref_i, _, _, _ = _own_probe_reference(idx, qs, 10, n_probes=4)
+    np.testing.assert_array_equal(np.asarray(i), ref_i)
 
 
 def test_deep_shape_fused_refine_is_exact():

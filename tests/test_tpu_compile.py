@@ -91,7 +91,7 @@ def test_ivf_scan_compiles(one_chip):
 
 def test_pq_scan_compiles(one_chip):
     from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
-    from raft_tpu.ops.pallas.pq_scan import ivf_pq_fused_search, vmem_decode_cols
+    from raft_tpu.ops.pallas.pq_scan import ivf_pq_fused_search
 
     idx = _tiny_pq("nibble")
     code_mode, ksub = _fused_code_layout(idx)
@@ -100,34 +100,22 @@ def test_pq_scan_compiles(one_chip):
     centers, codes, ids = _list_specs(one_chip, bpr=bpr, dtype=jnp.uint8)
     _assert_kernel(ivf_pq_fused_search.lower(
         centers, _spec((N_LISTS, rot), jnp.float32, one_chip),
-        _spec((N_LISTS,), jnp.int32, one_chip),
         _spec(idx.rotation.shape, jnp.float32, one_chip),
         _spec(books.shape, jnp.float32, one_chip), codes, ids,
         _spec((N_LISTS, MAX_LIST), jnp.float32, one_chip),
         _spec((NQ, DIM), jnp.float32, one_chip), None,
-        k=8 * K, n_probes=30, metric=L2, qt=128, probe_factor=32, group=8,
-        merge="bank8", code_mode=code_mode, ksub=ksub,
-        decode_cols=vmem_decode_cols(2048, m=MAX_LIST, code_mode=code_mode, ksub=ksub,
-                                     bpr=bpr, qt=128, k=8 * K, g_lists=8, rot_dim=rot,
-                                     merge="bank8"),
+        k=8 * K, n_probes=30, metric=L2, qt=128, group=8,
+        merge="bank8", code_mode=code_mode, ksub=ksub, decode_cols=2048,
         interpret=False))
 
 
-def test_pq_scan_compiles_glove_bucket(one_chip):
-    """The glove200-pq benchmark cell's shape: 1,183,514 x 200 by inner
-    product in 1,024 lists capped at 1,280 rows, nibble codes of 64 bytes
-    a row, k 80 (10 x refine 8), a 1,024-query bucket. Its eight 128-query
-    sub-tiles share each decoded list under the kernel's raised VMEM
-    limit, and the 2,048-column multi-hot decodes in one chunk."""
+def _pq_bucket(one_chip, *, d, m, nq, k, n_lists, n_probes):
+    """Lower the fused PQ search of a benchmark cell's bucket: inner
+    product, nibble codes of 64 bytes a row, groups of 8 lists."""
     from raft_tpu.neighbors import ivf_pq
     from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
-    from raft_tpu.ops.pallas.pq_scan import (
-        fused_subtiles,
-        ivf_pq_fused_search,
-        vmem_decode_cols,
-    )
+    from raft_tpu.ops.pallas.pq_scan import ivf_pq_fused_search
 
-    d, m, nq, k = 200, 1280, 1024, 80
     x = np.random.default_rng(0).standard_normal((2048, d)).astype(np.float32)
     idx = ivf_pq.build(jnp.asarray(x), ivf_pq.IvfPqIndexParams(
         n_lists=8, pq_dim=64, pq_bits=8, pq_kind="nibble", kmeans_n_iters=2,
@@ -135,65 +123,43 @@ def test_pq_scan_compiles_glove_bucket(one_chip):
     code_mode, ksub = _fused_code_layout(idx)
     books = nibble_books(idx.pq_centers)
     bpr, rot = idx.codes.shape[2], idx.rotation.shape[0]
-    model = dict(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=128, k=k,
-                 g_lists=8, rot_dim=rot, merge="bank8")
-    assert (code_mode, bpr) == ("nib8", 64) and fused_subtiles(nq, **model) == 8
-    _assert_kernel(ivf_pq_fused_search.lower(
-        _spec((N_LISTS, d), jnp.float32, one_chip),
-        _spec((N_LISTS, rot), jnp.float32, one_chip),
-        _spec((N_LISTS,), jnp.int32, one_chip),
-        _spec(idx.rotation.shape, jnp.float32, one_chip),
-        _spec(books.shape, jnp.float32, one_chip),
-        _spec((N_LISTS, m, bpr), jnp.uint8, one_chip),
-        _spec((N_LISTS, m), jnp.int32, one_chip),
-        _spec((N_LISTS, m), jnp.float32, one_chip),
-        _spec((nq, d), jnp.float32, one_chip), None,
-        k=k, n_probes=30, metric=DistanceType.InnerProduct, qt=128, probe_factor=32,
-        group=8, merge="bank8", code_mode=code_mode, ksub=ksub,
-        decode_cols=vmem_decode_cols(2048, **model), interpret=False))
-
-
-def test_pq_scan_compiles_deep_bucket(one_chip):
-    """The deep10m-pq benchmark cell's shape: 9,990,000 x 96 by inner
-    product in 8,192 lists capped at 1,408 rows, nibble codes of 64 bytes
-    a row (pq_len 2, rot_dim 128), k 80, a 1,024-query bucket. Probe
-    factor 128 gives each sub-tile a table of all 1,024 units, so the
-    kernel's walk and its scalar-prefetch tables are [1, 1,024]; eight
-    sub-tiles share each decoded list."""
-    from raft_tpu.neighbors import ivf_pq
-    from raft_tpu.neighbors.ivf_pq import _fused_code_layout, nibble_books
-    from raft_tpu.ops.pallas.pq_scan import (
-        fused_subtiles,
-        ivf_pq_fused_search,
-        vmem_decode_cols,
-    )
-
-    d, m, nq, k, n_lists = 96, 1408, 1024, 80, 8192
-    x = np.random.default_rng(0).standard_normal((2048, d)).astype(np.float32)
-    idx = ivf_pq.build(jnp.asarray(x), ivf_pq.IvfPqIndexParams(
-        n_lists=8, pq_dim=64, pq_bits=8, pq_kind="nibble", kmeans_n_iters=2,
-        metric=DistanceType.InnerProduct))
-    code_mode, ksub = _fused_code_layout(idx)
-    books = nibble_books(idx.pq_centers)
-    bpr, rot = idx.codes.shape[2], idx.rotation.shape[0]
-    model = dict(m=m, code_mode=code_mode, ksub=ksub, bpr=bpr, qt=128, k=k,
-                 g_lists=8, rot_dim=rot, merge="bank8")
-    assert (code_mode, bpr, rot, idx.pq_len) == ("nib8", 64, 128, 2)
-    assert fused_subtiles(nq, **model) == 8
-    lowered = ivf_pq_fused_search.lower(
+    assert (code_mode, bpr) == ("nib8", 64)
+    return idx, ivf_pq_fused_search.lower(
         _spec((n_lists, d), jnp.float32, one_chip),
         _spec((n_lists, rot), jnp.float32, one_chip),
-        _spec((n_lists,), jnp.int32, one_chip),
         _spec(idx.rotation.shape, jnp.float32, one_chip),
         _spec(books.shape, jnp.float32, one_chip),
         _spec((n_lists, m, bpr), jnp.uint8, one_chip),
         _spec((n_lists, m), jnp.int32, one_chip),
         _spec((n_lists, m), jnp.float32, one_chip),
         _spec((nq, d), jnp.float32, one_chip), None,
-        k=k, n_probes=64, metric=DistanceType.InnerProduct, qt=128, probe_factor=128,
+        k=k, n_probes=n_probes, metric=DistanceType.InnerProduct, qt=128,
         group=8, merge="bank8", code_mode=code_mode, ksub=ksub,
-        decode_cols=vmem_decode_cols(2048, **model), interpret=False)
-    assert "tensor<1x1024xi32>" in lowered.as_text()  # the unit walk's prefetch tables
+        decode_cols=2048, interpret=False)
+
+
+def test_pq_scan_compiles_glove_bucket(one_chip):
+    """The glove200-pq benchmark cell's shape: 1,183,514 x 200 by inner
+    product in 1,024 lists capped at 1,280 rows, nibble codes of 64 bytes
+    a row, k 80 (10 x refine 8), a 1,024-query bucket of 30 probes: its
+    30,720 (query, probe) pairs make 240 tiles of 128, and the walk's
+    scalar-prefetch tables have 240 + 128 - 1 steps."""
+    _, lowered = _pq_bucket(one_chip, d=200, m=1280, nq=1024, k=80, n_lists=1024,
+                            n_probes=30)
+    assert "tensor<367xi32>" in lowered.as_text()  # the pair walk's prefetch tables
+    _assert_kernel(lowered)
+
+
+def test_pq_scan_compiles_deep_bucket(one_chip):
+    """The deep10m-pq benchmark cell's shape: 9,990,000 x 96 by inner
+    product in 8,192 lists capped at 1,408 rows, nibble codes of 64 bytes
+    a row (pq_len 2, rot_dim 128), k 80, a 1,024-query bucket of 64
+    probes: 65,536 pairs in 512 tiles, a walk of at most 512 + 1,024 - 1
+    (tile, unit) steps."""
+    idx, lowered = _pq_bucket(one_chip, d=96, m=1408, nq=1024, k=80, n_lists=8192,
+                              n_probes=64)
+    assert (idx.rotation.shape[0], idx.pq_len) == (128, 2)
+    assert "tensor<1535xi32>" in lowered.as_text()  # the pair walk's prefetch tables
     _assert_kernel(lowered)
 
 

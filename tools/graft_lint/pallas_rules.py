@@ -56,11 +56,11 @@ from tools.graft_lint.core import Checker, LintModule, Violation
 #: fires.
 KERNEL_SHAPE_BINDINGS: Dict[str, Dict[str, object]] = {
     # the glove200-pq benchmark cell: nibble codes (bpr 64, Kp 2048),
-    # lists capped at 1,280 rows, k x refine 80, and the eight 128-query
-    # sub-tiles of a 1,024-query bucket sharing each decoded list
+    # lists capped at 1,280 rows, k x refine 80, tiles of 128 (query,
+    # probe) pairs
     "pq_scan": dict(
-        qt=128, rows=1024, k=80, Kp=2048, rot_dim=256, g_lists=8, m=1280,
-        gm=10240, bpr=64, banks=8,
+        qt=128, k=80, Kp=2048, g_lists=8, m=1280, gm=10240, bpr=64,
+        banks=8,
     ),
     # the fused RaBitQ sign-bit kernel at the same 1M-row bench shape
     # (bpr = rot_dim/8 = 16 B/row of packed sign codes)
