@@ -253,8 +253,7 @@ def _load_data():
 #: serving, churn rows carry their own metrics; tiered_sharded rows are
 #: multi-device tier comparisons, not single-device Pareto points;
 #: dist_build rows compare build-time comm schedules, not search configs)
-_NON_PARETO = ("cagra_latency", "mutable_churn", "tiered_sharded", "dist_build",
-               "planner")
+_NON_PARETO = ("cagra_latency", "mutable_churn", "tiered_sharded", "dist_build")
 
 
 def _is_pareto_algo(algo):
@@ -2026,78 +2025,6 @@ def _bench_main():
             print(f"# tiered_sharded failed: {phase_errors['tiered_sharded']}",
                   flush=True)
 
-    # ---- planner: costed auto-dispatch vs the hand-tuned frontier --------
-    # At >=3 operating points (batch sizes spanning the probe/scan/fused
-    # crossovers) the SAME index runs once with mode="auto" — the
-    # raft_tpu.plan cost models decide — and once per explicit hand-tuned
-    # mode. planner_regret = planner QPS / best hand-tuned QPS at the
-    # same recall floor (1.0 = the planner found the frontier); it rides
-    # in each planner row so tools/bench_regress.py gates it across
-    # rounds like any other row metric.
-    planner_summary = {}
-    plan_explain_text = ""
-    if not over_budget(0.97):
-        try:
-            from raft_tpu import plan as planlib
-
-            psp = ivf_flat.IvfFlatSearchParams(n_probes=30, fused_group=8,
-                                               **flat_kw)
-            on_tpu = "cpu" not in device0.lower()
-            hand_modes = ("probe", "scan", "fused") if on_tpu else ("probe", "scan")
-            for m in sorted({8, 128, nq}):  # latency / crossover / throughput
-                qs = queries[:m]
-                gt_m = gt[:m]
-
-                def _planner_point(mode, m=m, qs=qs, gt_m=gt_m):
-                    dt, (_v, i) = _timed(
-                        lambda: ivf_flat.search(fidx, qs, K, psp, mode=mode),
-                        nrep=2, label=f"planner_nq{m}_{mode}")
-                    rec = float(neighborhood_recall(np.asarray(i)[:, :K], gt_m))
-                    return {"qps": round(m / dt, 1), "recall": round(rec, 4),
-                            "mode": mode, **_pctl_cols(dt)}
-
-                hand = {}
-                for hand_mode in hand_modes:
-                    try:
-                        hand[hand_mode] = _planner_point(hand_mode)
-                    except Exception as e:  # noqa: BLE001 — an infeasible explicit mode is a skipped column, not a phase failure
-                        print(f"# planner nq={m} mode={hand_mode} skipped: "
-                              f"{type(e).__name__}: {e}"[:160], flush=True)
-                auto = _planner_point("auto")
-                chosen = planlib.plan_search_mode(
-                    "ivf_flat", m, on_tpu=on_tpu, fused_ok=on_tpu).choice
-                floor = auto["recall"] - 0.01
-                ok_rows = [r for r in hand.values() if r["recall"] >= floor]
-                best = max(ok_rows, key=lambda r: r["qps"]) if ok_rows else None
-                regret = round(auto["qps"] / best["qps"], 4) if best else 1.0
-                row = {"config": f"auto nq={m} chose={chosen}",
-                       "qps": auto["qps"], "recall": auto["recall"],
-                       "planner_regret": regret,
-                       "hand_best": (f"{best['mode']} {best['qps']}"
-                                     if best else "none")}
-                results.setdefault("planner", []).append(row)
-                _rec_add({"algo": "planner", **row})
-                print(f"# {'planner':16s} nq={m:<6d} auto->{chosen:<6s} "
-                      f"{auto['qps']:>12,.1f} qps  regret={regret:.3f} "
-                      f"(best hand: {row['hand_best']})", flush=True)
-                planner_summary[f"nq={m}"] = {
-                    "choice": chosen, "planner_qps": auto["qps"],
-                    "planner_recall": auto["recall"], "regret": regret,
-                    "hand": {hm: {c: r[c] for c in ("qps", "recall")}
-                             for hm, r in hand.items()},
-                }
-            # the active plan's full cost breakdown, captured for the
-            # obs report's plan-explain section below
-            from raft_tpu.serve.engine import ServingEngine as _PlanEngine
-
-            _peng = _PlanEngine(max_batch=128, max_wait_ms=0.0)
-            _peng.register("bench_ivf_flat", "ivf_flat", fidx, params=psp)
-            plan_explain_text = _peng.plan_explain("bench_ivf_flat") or ""
-            del _peng
-        except Exception as e:  # noqa: BLE001
-            phase_errors["planner"] = f"{type(e).__name__}: {e}"[:200]
-            print(f"# planner failed: {phase_errors['planner']}", flush=True)
-
     # operating points: best QPS at recall >= MIN_RECALL per algorithm
     # (latency/serving/churn rows carry their own metrics, not Pareto rows)
     ops = {}
@@ -2132,8 +2059,7 @@ def _bench_main():
                              ring_speedup=ring_speedup,
                              tiered=tiered_summary,
                              tiered_sharded=tiered_sharded_summary,
-                             dist_build=dist_build_summary,
-                             planner=planner_summary)
+                             dist_build=dist_build_summary)
         except Exception as e:  # noqa: BLE001
             print(f"# artifact context dropped: {e}", flush=True)
 
@@ -2188,13 +2114,7 @@ def _bench_main():
             sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "tools"))
             from obs_report import render_report
 
-            if plan_explain_text:
-                with open("bench_artifacts/plan_explain.txt", "w") as f:
-                    f.write(plan_explain_text)
-                artifacts["plan_explain"] = "bench_artifacts/plan_explain.txt"
-            print(render_report(artifacts["metrics"], artifacts["trace"],
-                                plan_explains=[plan_explain_text]
-                                if plan_explain_text else None), flush=True)
+            print(render_report(artifacts["metrics"], artifacts["trace"]), flush=True)
         except Exception as e:  # noqa: BLE001
             artifacts["obs_error"] = f"{type(e).__name__}: {e}"[:200]
 
@@ -2218,7 +2138,6 @@ def _bench_main():
                     "tiered": tiered_summary,
                     "tiered_sharded": tiered_sharded_summary,
                     "dist_build": dist_build_summary,
-                    "planner": planner_summary,
                     "all_results": results,
                     "build_seconds": build_times,
                     "cagra_error": cagra_err,

@@ -164,10 +164,7 @@ def _delta_route(mode: str, metric, cap: int, k: int) -> str:
 
     from raft_tpu import plan as _plan
 
-    on_tpu = jax.default_backend() == "tpu"
-    if _plan.is_enabled():
-        return _plan.plan_delta_mode(eligible=eligible, on_tpu=on_tpu).choice
-    return "fused" if eligible and on_tpu else "exact"
+    return _plan.plan_delta_mode(eligible=eligible, on_tpu=jax.default_backend() == "tpu")
 
 
 def _delta_fused_search(metric, delta_bf, delta_live, queries, k: int):

@@ -933,14 +933,9 @@ def _search_dispatch(
     if mode == "auto":
         from raft_tpu import plan as _plan
 
-        on_tpu = jax.default_backend() == "tpu"
-        if _plan.is_enabled():
-            mode = _plan.plan_cagra_mode(
-                queries.shape[0], on_tpu=on_tpu,
-                fused_ok=not why_not_fused, why_not_fused=why_not_fused,
-            ).choice
-        else:
-            mode = "fused" if on_tpu and not why_not_fused else "xla"
+        mode = _plan.plan_cagra_mode(
+            on_tpu=jax.default_backend() == "tpu", fused_ok=not why_not_fused
+        )
     expects(mode in ("xla", "fused"), "mode must be auto|xla|fused, got %r", mode)
     if mode == "fused":
         expects(not why_not_fused, "fused mode %s (use mode='xla')", why_not_fused)

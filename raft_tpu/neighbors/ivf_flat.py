@@ -709,16 +709,10 @@ def _search_dispatch(
         from raft_tpu import plan as _plan
         from raft_tpu.ops.pallas.ivf_scan import supported_metric
 
-        on_tpu = jax.default_backend() == "tpu"
-        if _plan.is_enabled():
-            mode = _plan.plan_search_mode(
-                "ivf_flat", nq, on_tpu=on_tpu,
-                fused_ok=supported_metric(index.metric),
-            ).choice
-        elif nq >= 128 and on_tpu and supported_metric(index.metric):
-            mode = "fused"
-        else:
-            mode = "scan" if nq >= 128 else "probe"
+        mode = _plan.plan_search_mode(
+            nq, on_tpu=jax.default_backend() == "tpu",
+            fused_ok=supported_metric(index.metric),
+        )
     expects(
         mode in ("scan", "probe", "fused"), "mode must be auto|scan|probe|fused, got %r", mode
     )

@@ -741,20 +741,7 @@ def build(
     if pq_kind == "auto":  # default: nibble whenever representable
         from raft_tpu import plan as _plan
 
-        if _plan.is_enabled():
-            pq_kind = _plan.plan_pq_kind(
-                params.pq_bits,
-                params.codebook_kind == PER_SUBSPACE,
-                pq_dim=int(getattr(params, "pq_dim", 0) or 16),
-            ).choice
-        elif params.pq_bits == 1:
-            pq_kind = "rabitq"
-        else:
-            pq_kind = (
-                "nibble"
-                if params.pq_bits == 8 and params.codebook_kind == PER_SUBSPACE
-                else "kmeans"
-            )
+        pq_kind = _plan.plan_pq_kind(params.pq_bits, params.codebook_kind == PER_SUBSPACE)
     nibble = pq_kind == "nibble"
     rabitq = pq_kind == "rabitq"
     if rabitq:
@@ -1735,16 +1722,10 @@ def _search_dispatch(
     if mode == "auto":
         from raft_tpu import plan as _plan
 
-        on_tpu = jax.default_backend() == "tpu"
-        if _plan.is_enabled():
-            mode = _plan.plan_search_mode(
-                "ivf_pq", nq, on_tpu=on_tpu, fused_ok=fused_ok,
-                wants_f32_lut=wants_f32_lut,
-            ).choice
-        elif nq >= 128 and on_tpu and fused_ok and not wants_f32_lut:
-            mode = "fused"
-        else:
-            mode = "scan" if nq >= 128 else "probe"
+        mode = _plan.plan_search_mode(
+            nq, on_tpu=jax.default_backend() == "tpu", fused_ok=fused_ok,
+            wants_f32_lut=wants_f32_lut,
+        )
     expects(
         mode in ("scan", "probe", "fused"), "mode must be auto|scan|probe|fused, got %r", mode
     )
@@ -1954,15 +1935,9 @@ def _rabitq_modes(
     if mode == "auto":
         from raft_tpu import plan as _plan
 
-        on_tpu = jax.default_backend() == "tpu"
-        if _plan.is_enabled():
-            mode = _plan.plan_search_mode(
-                "ivf_pq", nq, on_tpu=on_tpu, fused_ok=fused_ok,
-            ).choice
-        elif nq >= 128 and on_tpu and fused_ok:
-            mode = "fused"
-        else:
-            mode = "scan" if nq >= 128 else "probe"
+        mode = _plan.plan_search_mode(
+            nq, on_tpu=jax.default_backend() == "tpu", fused_ok=fused_ok
+        )
     expects(
         mode in ("scan", "probe", "fused"), "mode must be auto|scan|probe|fused, got %r", mode
     )

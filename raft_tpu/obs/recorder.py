@@ -5,8 +5,8 @@ evidence in bounded memory — metric time series (a
 :class:`~raft_tpu.obs.timeseries.SeriesBank` sampled on the maintenance
 tick, rate-limited to ``sample_interval_s``, plus one final at-trigger
 sample in every dump), and the incident event stream (anomalies, fault-seam
-firings, SLO alert transitions, breaker trips, plan flips, compactor
-worker deaths). On a **trigger** it writes one atomic, CRC-framed
+firings, SLO alert transitions, breaker trips, compactor worker
+deaths). On a **trigger** it writes one atomic, CRC-framed
 diagnostic bundle capturing everything a post-mortem needs:
 
 * the trigger cause and context, and the retained event stream;
@@ -33,7 +33,7 @@ subsystems' critical sections (e.g. ``wal.append`` under the writer
 lock) and the recorder must never insert itself into their ordering.
 For the same reason a fault trigger only *latches* a pending dump
 (single-slot, last-wins) that the next :meth:`FlightRecorder.tick`
-drains; SLO/breaker/plan-flip/worker-death triggers dump inline — their
+drains; SLO/breaker/worker-death triggers dump inline — their
 hook sites sit exactly where registry emission already happens, i.e.
 contractually outside every tracked lock.
 
@@ -65,7 +65,7 @@ BUNDLE_SUFFIX = ".raftbundle"
 #: trigger causes an auto-dumping recorder reacts to (``manual`` — an
 #: explicit :func:`dump` call — is always allowed)
 DEFAULT_TRIGGERS = frozenset(
-    {"slo", "fault", "breaker", "plan_flip", "worker", "election", "fenced"}
+    {"slo", "fault", "breaker", "worker", "election", "fenced"}
 )
 
 
@@ -200,11 +200,6 @@ class FlightRecorder:
         if to == "open":
             return self._trigger("breaker", {"target": target})
         return None
-
-    def note_plan_flip(self, index_id: str, epoch: int) -> Optional[str]:
-        """The planner swapped an index's plan."""
-        self._record("plan_flip", index_id=index_id, epoch=epoch)
-        return self._trigger("plan_flip", {"index_id": index_id, "epoch": epoch})
 
     def note_worker_death(self, index: str) -> Optional[str]:
         """A compactor worker died and was restarted by the watchdog."""
@@ -577,12 +572,6 @@ def note_breaker(target: str, to: str) -> None:
     r = _active
     if r is not None and metrics.is_enabled():
         r.note_breaker(target, to)
-
-
-def note_plan_flip(index_id: str, epoch: int) -> None:
-    r = _active
-    if r is not None and metrics.is_enabled():
-        r.note_plan_flip(index_id, epoch)
 
 
 def note_worker_death(index: str) -> None:

@@ -122,9 +122,8 @@ def lanes(w: int) -> int:
     return -(-w // _LANES) * _LANES
 
 # The per-query merge wire model moved to the consolidated
-# raft_tpu.parallel.wire_model (the planner prices ring-vs-gather from
-# it); re-exported at this original home, where the engines' byte
-# counters and every pre-planner consumer import it from.
+# raft_tpu.parallel.wire_model; re-exported at this original home, where
+# the engines' byte counters and older consumers import it from.
 from raft_tpu.parallel.wire_model import (  # noqa: F401  (re-export)
     AG_ENTRY_BYTES,
     RS_ENTRY_BYTES,

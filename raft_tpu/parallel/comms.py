@@ -60,10 +60,10 @@ def _payload_bytes(x) -> float:
 
 
 # The per-verb wire model now lives in raft_tpu.parallel.wire_model so
-# the planner, the build byte accounting, and these obs counters all
-# price collectives from one table; re-exported here because this module
-# is where the ``comms.{verb}.bytes`` counters apply it and where every
-# pre-planner consumer imported it from.
+# the comm-mode rule, the build byte accounting, and these obs counters
+# all price collectives from one table; re-exported here because this
+# module is where the ``comms.{verb}.bytes`` counters apply it and where
+# older consumers imported it from.
 from raft_tpu.parallel.wire_model import (  # noqa: F401  (re-export)
     WIRE_FACTORS as _WIRE_FACTORS,
     wire_bytes,

@@ -61,25 +61,23 @@ def _health_array(health, n_shards) -> jnp.ndarray:
 _MERGE_MODES = ("auto", "ring", "fused_ring", "gather")
 
 
-def _resolve_merge_mode(merge_mode: str, n_shards: int, k=None) -> str:
+def _resolve_merge_mode(merge_mode: str, n_shards: int) -> str:
     """``auto`` prefers the ring exchange whenever there is more than one
     shard (parity with gather is exact, wire bytes are ~0.4n× lower); a
     single shard has nothing to exchange and keeps the trivial path.
     ``fused_ring`` keeps the same wire schedule but folds the scan's
-    candidate tile to the merge width inside the ring engine.
+    candidate tile to the merge width inside the ring engine; only a
+    caller that pins it gets it.
 
     This is the single merge-engine chokepoint: every sharded search
     (and the serving engine's sharded registrations, transitively)
-    resolves ``auto`` here, through the planner's wire-model costing
-    when enabled."""
+    resolves ``auto`` here, by :func:`raft_tpu.plan.plan_merge_mode`."""
     expects(merge_mode in _MERGE_MODES, "merge_mode %r (want one of %s)",
             merge_mode, _MERGE_MODES)
     if merge_mode == "auto":
         from raft_tpu import plan as _plan
 
-        if _plan.is_enabled():
-            return _plan.plan_merge_mode(n_shards, k).choice
-        return "ring" if n_shards > 1 else "gather"
+        return _plan.plan_merge_mode(n_shards)
     if merge_mode == "fused_ring" and n_shards == 1:
         return "gather"
     return merge_mode
@@ -212,7 +210,7 @@ def sharded_ivf_flat_search(
     g = ivf_flat_mod.scan_chunk_lists(l_local, index.max_list)
 
     masked = health is not None
-    mode = _resolve_merge_mode(merge_mode, n_shards, k)
+    mode = _resolve_merge_mode(merge_mode, n_shards)
     ln = index.list_norms
     if ln is None:
         ln = jnp.zeros(index.list_indices.shape, jnp.float32)
@@ -397,7 +395,7 @@ def sharded_ivf_pq_lists_search(
     bf16 = ivf_pq_mod.scan_bf16(params.lut_dtype)
 
     masked = health is not None
-    mode = _resolve_merge_mode(merge_mode, n_shards, k)
+    mode = _resolve_merge_mode(merge_mode, n_shards)
     put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
     args = [
         put(index.centers, P()),
@@ -427,20 +425,16 @@ def _resolve_comm_mode(comm_mode: str, n_shards: int, n_rows=None,
     """``auto`` prefers the communication-avoiding exchange whenever
     there is more than one shard (wire bytes per iteration drop to the
     changed-row fraction); a single shard pays no wire bytes either way
-    and keeps the reference ``full`` exchange.
-
-    With the planner enabled and the accumulator shape known
-    (``n_rows``/``d``), ``auto`` is costed from the consolidated wire
-    model instead — which also keeps ``full`` for degenerate shapes
-    where the CA row cap cannot undercut the full exchange."""
+    and keeps the reference ``full`` exchange. With the accumulator
+    shape known (``n_rows``/``d``), :func:`raft_tpu.plan.plan_comm_mode`
+    compares the wire model's bytes, which also keeps ``full`` where the
+    CA row cap cannot undercut the full exchange."""
     expects(comm_mode in _COMM_MODES, "comm_mode %r (want one of %s)",
             comm_mode, _COMM_MODES)
     if comm_mode == "auto":
         from raft_tpu import plan as _plan
 
-        if _plan.is_enabled() and n_rows is not None and d is not None:
-            return _plan.plan_comm_mode(n_rows, d, n_shards, ca_cap=ca_cap).choice
-        return "ca" if n_shards > 1 else "full"
+        return _plan.plan_comm_mode(n_shards, n_rows=n_rows, d=d, ca_cap=ca_cap)
     return comm_mode
 
 
@@ -648,8 +642,8 @@ def dist_codebook_step(books, resid, ksub, axis, fuse_comms=True,
 
 
 # The per-iteration build byte models moved to the consolidated
-# raft_tpu.parallel.wire_model (the planner's comm terms price builds
-# from the same table); re-exported at this original home, where the
+# raft_tpu.parallel.wire_model (the comm-mode rule in raft_tpu.plan
+# reads the same table); re-exported at this original home, where the
 # bench dist_build phase and tests import them from.
 from raft_tpu.parallel.wire_model import (  # noqa: E402,F401  (re-export)
     codebook_wire_bytes_per_iter,

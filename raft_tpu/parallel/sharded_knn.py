@@ -110,7 +110,7 @@ def sharded_knn(
     per = n // n_shards
     expects(k <= per, "k=%d larger than per-shard rows %d", k, per)
     select_min = is_min_close(metric)
-    mode = _resolve_merge_mode(merge_mode, n_shards, k)
+    mode = _resolve_merge_mode(merge_mode, n_shards)
 
     ds_sharded = jax.device_put(dataset, NamedSharding(mesh, P(axis, None)))
     q_repl = jax.device_put(queries, NamedSharding(mesh, P(None, None)))

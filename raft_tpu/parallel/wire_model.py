@@ -5,9 +5,8 @@ per-verb collective factors lived in :mod:`raft_tpu.parallel.comms`
 (where the obs byte counters apply them), the distributed-build
 per-iteration models in :mod:`raft_tpu.parallel.sharded_ann`, and the
 search-merge per-query model in :mod:`raft_tpu.ops.pallas.ring_topk`.
-The cost-model planner (:mod:`raft_tpu.plan`) prices candidate plans
-against all three at once, so they now live here — one module, no jax
-dependency, import-cheap — and the original homes re-export them
+They now live here — one module, no jax dependency, import-cheap, read
+by :func:`raft_tpu.plan.plan_comm_mode` — and the original homes re-export them
 unchanged (every byte value below is pinned by the pre-existing tests
 at those import paths: ``tests/test_sharded_ann.py``,
 ``tests/test_ring_topk.py``, ``tests/test_scan_ring_topk.py``).
@@ -46,8 +45,9 @@ def wire_bytes(verb: str, payload_bytes: float, n: int) -> float:
     on an ``n``-rank axis. This is the same model ``comms.{verb}.bytes``
     counters apply, exposed so byte budgets elsewhere (the
     communication-avoiding build accounting in
-    :mod:`raft_tpu.parallel.sharded_ann`, the planner's comm terms,
-    bench columns, docs tables) stay pinned to one source of truth."""
+    :mod:`raft_tpu.parallel.sharded_ann`, the comm-mode rule in
+    :mod:`raft_tpu.plan`, bench columns, docs tables) stay pinned to one
+    source of truth."""
     if n <= 1:
         return 0.0
     return float(WIRE_FACTORS.get(verb, lambda p, _: p)(float(payload_bytes), int(n)))
@@ -83,7 +83,6 @@ def wire_bytes_per_query(n_shards: int, k: int, mode: str = "ring") -> float:
     """Estimated per-rank ICI payload bytes received per query for one
     merge: the ``k`` candidate entries that must cross, not the padded
     slots the fused kernel moves (:func:`ring_wire_bytes` counts those).
-    The planner prices merges from this payload model.
 
     ``mode="gather"``: each rank receives ``n-1`` foreign ``[k]`` blocks
     of (f32, i32). ``mode="ring"``: ``n-1`` reduce-scatter hops of one

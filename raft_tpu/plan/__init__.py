@@ -1,76 +1,95 @@
-"""Cost-model query planner: one dispatcher over the vmem / HBM /
-wire / traffic models.
+"""Dispatch rules: every ``"auto"`` engine choice in raft_tpu resolves here.
 
-Every ``"auto"`` dispatch decision in raft_tpu — IVF search engine,
-CAGRA beam engine, cross-shard merge engine, distributed-build comm
-mode, mutable delta engine, PQ code family, sparse pairwise engine,
-and the serving engine's per-registration plan — resolves here instead
-of through scattered local heuristics. Each resolver enumerates the
-eligible candidates, prices them from the repo's existing cost models
-(:mod:`raft_tpu.plan.cost`), and returns a typed, explainable
-:class:`Plan`.
-
-Gate: set ``RAFT_TPU_PLAN=0`` (or ``false``/``off``) to disable the
-planner — every call site then runs its original inline heuristic,
-bit-identically. With the gate on, the calibrated cost constants make
-the planner reproduce the legacy choices across the legacy decision
-envelope (pinned by ``tests/test_plan.py``), so results stay
-bit-identical there too.
+Each ``plan_*`` function is one plain rule over inputs its call site
+already holds (batch size, backend, the kernel-eligibility verdict the
+call site computed) and returns the chosen engine's name. A call site
+calls its rule once and dispatches on the string. ``docs/planner.md``
+lists each rule, what it reads and where it is called.
 """
 from __future__ import annotations
 
-import os
+from typing import Optional
 
-from raft_tpu.plan.cost import CostTerm
-from raft_tpu.plan.planner import (
-    Candidate,
-    Plan,
-    plan_cagra_mode,
-    plan_comm_mode,
-    plan_delta_mode,
-    plan_merge_mode,
-    plan_pq_kind,
-    plan_search_mode,
-    plan_sparse_mode,
-)
-from raft_tpu.plan.registration import (
-    GROWTH_REPLAN_FACTOR,
-    TRAFFIC_MIN_SAMPLES,
-    WARM_BUCKETS,
-    RegistrationPlan,
-    TrafficSnapshot,
-    needs_replan,
-    plan_registration,
-    traffic_from_counts,
-)
+from raft_tpu.parallel.wire_model import lloyd_wire_bytes_per_iter
 
-_OFF = ("0", "false", "off", "no")
+#: batch size from which one dense scan launch (or the fused probed-list
+#: kernel) beats per-query probe gathers
+SCAN_MIN_BATCH = 128
+#: feature width above which sparse pairwise distances stop densifying
+#: rows (the native sort-merge path needs no [block, n_cols] tiles)
+DENSIFY_MAX_COLS = 1 << 18
+#: bytes charged to the CA exchange's changed-row select: CA must
+#: undercut the full exchange by more than one 8-byte entry per
+#: iteration, so equal byte counts (one shard) keep ``full``
+CA_SELECT_BYTES = 8.0
 
 
-def is_enabled() -> bool:
-    """Planner gate: on by default; ``RAFT_TPU_PLAN=0`` restores every
-    call site's original inline heuristic."""
-    return os.environ.get("RAFT_TPU_PLAN", "1").strip().lower() not in _OFF
+def plan_search_mode(nq: int, *, on_tpu: bool, fused_ok: bool,
+                     wants_f32_lut: bool = False) -> str:
+    """IVF-Flat / IVF-PQ / RaBitQ engine for a batch of ``nq`` queries:
+    ``probe`` below :data:`SCAN_MIN_BATCH`, else ``fused`` on a TPU when
+    the kernel is eligible and no f32 LUT is asked for, else ``scan``."""
+    if nq < SCAN_MIN_BATCH:
+        return "probe"
+    if on_tpu and fused_ok and not wants_f32_lut:
+        return "fused"
+    return "scan"
+
+
+def plan_cagra_mode(*, on_tpu: bool, fused_ok: bool) -> str:
+    """CAGRA beam engine: ``fused`` on a TPU when eligible, else ``xla``."""
+    return "fused" if on_tpu and fused_ok else "xla"
+
+
+def plan_merge_mode(n_shards: int) -> str:
+    """Cross-shard merge engine: ``ring`` across shards, ``gather`` for
+    one. ``fused_ring`` is never chosen here: a caller pins it."""
+    return "ring" if n_shards > 1 else "gather"
+
+
+def plan_comm_mode(n_shards: int, n_rows: Optional[int] = None,
+                   d: Optional[int] = None, ca_cap=None) -> str:
+    """Distributed-build accumulator exchange over ``[n_rows, d+1]`` f32
+    rows: ``ca`` when its wire bytes plus :data:`CA_SELECT_BYTES` are
+    below the full exchange's. Without the shape, ``ca`` across shards."""
+    if n_rows is None or d is None:
+        return "ca" if n_shards > 1 else "full"
+    ca = lloyd_wire_bytes_per_iter(n_rows, d, n_shards, comm_mode="ca",
+                                   ca_cap=ca_cap)
+    full = lloyd_wire_bytes_per_iter(n_rows, d, n_shards, comm_mode="full")
+    return "ca" if ca + CA_SELECT_BYTES < full else "full"
+
+
+def plan_delta_mode(*, eligible: bool, on_tpu: bool) -> str:
+    """Mutable delta scan: ``fused`` on a TPU inside the banked kernel's
+    lossless window (``eligible``), else ``exact``."""
+    return "fused" if eligible and on_tpu else "exact"
+
+
+def plan_pq_kind(pq_bits: int, per_subspace: bool) -> str:
+    """PQ code family at build: ``rabitq`` at 1 bit, ``nibble`` at 8 bits
+    with per-subspace codebooks, else ``kmeans`` (whose build validates
+    ``pq_bits``)."""
+    if pq_bits == 1:
+        return "rabitq"
+    return "nibble" if pq_bits == 8 and per_subspace else "kmeans"
+
+
+def plan_sparse_mode(n_cols: int, *, native_ok: bool) -> str:
+    """Sparse pairwise engine: ``native`` above :data:`DENSIFY_MAX_COLS`
+    columns when the metric has a native path, else ``densify``."""
+    return "native" if n_cols > DENSIFY_MAX_COLS and native_ok else "densify"
 
 
 __all__ = [
-    "Candidate",
-    "CostTerm",
-    "GROWTH_REPLAN_FACTOR",
-    "Plan",
-    "RegistrationPlan",
-    "TRAFFIC_MIN_SAMPLES",
-    "TrafficSnapshot",
-    "WARM_BUCKETS",
-    "is_enabled",
-    "needs_replan",
+    "CA_SELECT_BYTES",
+    "DENSIFY_MAX_COLS",
+    "SCAN_MIN_BATCH",
     "plan_cagra_mode",
     "plan_comm_mode",
     "plan_delta_mode",
     "plan_merge_mode",
     "plan_pq_kind",
-    "plan_registration",
     "plan_search_mode",
     "plan_sparse_mode",
-    "traffic_from_counts",
 ]

@@ -137,16 +137,6 @@ class _Registration:
     #: generation of the last dispatched batch (-1 before the first) —
     #: crossing a flip bumps the ``serve.generation_flips`` counter
     last_generation: int = -1
-    #: active :class:`raft_tpu.plan.RegistrationPlan` (None with the
-    #: planner gate off); swapped atomically by the re-plan tick
-    plan: object = None
-    #: live batch-size histogram since the last plan (bucket -> batches)
-    bucket_counts: Dict[int, int] = dataclasses.field(default_factory=dict)
-    #: dispatched-rows/s EWMA — the traffic model's arrival-rate input
-    ewma_rows_per_s: float = 0.0
-    last_dispatch_t: float = -1.0
-    #: k of the most recent dispatch — what a plan flip precompiles for
-    last_k: int = 10
 
 
 class ServingEngine:
@@ -279,7 +269,6 @@ class ServingEngine:
             merge_mode=merge_mode,
             search_kwargs=dict(search_kwargs),
         )
-        reg.plan = self._plan_registration(reg)
         self._indexes[index_id] = reg
 
     def _plan_tier(self, index_id: str, algo: str, index, dataset):
@@ -438,9 +427,6 @@ class ServingEngine:
             search_kwargs=dict(search_kwargs),
             compactor=compactor,
         )
-        # no engine pick for snapshot dispatch, but the plan still
-        # carries the corpus/traffic anchors the re-plan tick tracks
-        reg.plan = self._plan_registration(reg)
         self._indexes[index_id] = reg
 
     def registered(self) -> List[str]:
@@ -664,16 +650,15 @@ class ServingEngine:
 
     def maintenance_tick(self) -> None:
         """One watchdog + auto-compaction pass over every registration
-        that carries a :class:`~raft_tpu.mutable.Compactor`, followed by
-        the planner's drift check (:meth:`_replan_tick`). Driven
-        from :meth:`step` (rate-limited by ``maintenance_interval_ms``)
-        so serving loops get background maintenance for free; callable
-        directly by deployments with their own schedulers."""
+        that carries a :class:`~raft_tpu.mutable.Compactor`, then the
+        flight recorder's tick. Driven from :meth:`step` (rate-limited
+        by ``maintenance_interval_ms``) so serving loops get background
+        maintenance for free; callable directly by deployments with
+        their own schedulers."""
         with obs.span("serve.maintenance"):
             for reg in list(self._indexes.values()):
                 if reg.compactor is not None:
                     reg.compactor.tick()
-            self._replan_tick()
             # flight-recorder sampler tick: retains the serving time series
             # and drains any fault-latched dump; no-op unless a recorder is
             # installed AND obs is enabled
@@ -696,8 +681,7 @@ class ServingEngine:
         snap = reg.index.snapshot() if reg.algo == "mutable" else None
         generation = snap.generation if snap is not None else 0
         keys = [
-            ProgramKey(index_id, reg.algo, b, int(k),
-                       self._program_params(reg, b), generation)
+            ProgramKey(index_id, reg.algo, b, int(k), params_key(reg.params), generation)
             for b in bucket_sizes(self.max_batch)
         ]
         built = self.cache.warmup(
@@ -751,177 +735,43 @@ class ServingEngine:
             health.append(ok)
         return tuple(health)
 
-    # -- query planning ----------------------------------------------------
-
-    def _tier_label(self, reg: _Registration) -> str:
-        """Placement verdict recorded on the plan ("" = unplanned)."""
-        if reg.algo in ("tiered", "tiered_sharded"):
-            return reg.algo
-        if reg.dataset is not None:
-            from raft_tpu.neighbors.refine import is_host_dataset
-
-            if is_host_dataset(reg.dataset):
-                return "tiered"
-        if reg.index_id in self._residencies or reg.index_id in self.sharded_placements:
-            return "resident"
-        return ""
-
-    @staticmethod
-    def _corpus_rows(reg: _Registration) -> int:
-        try:
-            return int(getattr(reg.index, "size", 0) or 0)
-        except (TypeError, ValueError):
-            return 0
-
-    def _plan_registration(self, reg: _Registration, k: Optional[int] = None,
-                           traffic=None, epoch: int = 0):
-        """Cost this registration's full decision set (None = gate off).
-
-        ``fused_ok`` is passed optimistically: a planned ``fused``
-        dispatches as ``"auto"`` (see :meth:`_planned_mode`), so the
-        search's own kernel-feasibility check remains authoritative and
-        the fused→scan/xla degrade contract is preserved."""
-        from raft_tpu import plan as _plan
-
-        if not _plan.is_enabled():
-            return None
-        import jax
-
-        n_shards = reg.mesh.shape[reg.axis] if reg.mesh is not None else 0
-        with obs.span("plan.build", index_id=reg.index_id, algo=reg.algo,
-                      epoch=epoch):
-            return _plan.plan_registration(
-                reg.index_id,
-                reg.algo,
-                buckets=bucket_sizes(self.max_batch),
-                corpus_rows=self._corpus_rows(reg),
-                on_tpu=jax.default_backend() == "tpu",
-                fused_ok=True,
-                n_shards=n_shards,
-                k=int(k if k is not None else reg.last_k),
-                tier=self._tier_label(reg),
-                mode_pinned=reg.mode != "auto",
-                merge_pinned=reg.merge_mode != "auto",
-                traffic=traffic,
-                epoch=epoch,
-            )
-
-    def _planned_mode(self, reg: _Registration, bucket: int,
-                      plan=None) -> Optional[str]:
-        """The plan's resolved engine for this bucket (None = dispatch
-        on ``reg.mode`` unchanged). A planned ``fused`` is dispatched as
-        ``"auto"``: the search re-resolves to fused by the same
-        calibration when the kernel is actually feasible, and keeps the
-        documented auto-degrade path when it is not."""
-        plan = plan if plan is not None else reg.plan
-        if plan is None or reg.mode != "auto":
-            return None
-        m = plan.mode_for(bucket, "")
-        if not m:
-            return None
-        return "auto" if m == "fused" else m
-
-    def _program_params(self, reg: _Registration, bucket: int,
-                        plan=None) -> Tuple:
-        """Params tuple for the ProgramKey: the registration params plus
-        the planned engine when one applies, so a plan flip that changes
-        a bucket's engine compiles a distinct program (bounded by
-        engines × buckets) and one that does not reuses the cache."""
-        pk = params_key(reg.params)
-        m = self._planned_mode(reg, bucket, plan=plan)
-        if m is not None:
-            pk = pk + (("planned_mode", m),)
-        return pk
+    # -- engine choice -----------------------------------------------------
 
     def plan_explain(self, index_id: str) -> Optional[str]:
-        """The active plan's full cost breakdown (None = planner off)."""
-        reg = self._reg(index_id)
-        return reg.plan.explain() if reg.plan is not None else None
-
-    def _warm_plan(self, reg: _Registration, new_plan) -> List[ProgramKey]:
-        """Precompile the new plan's warm buckets BEFORE the swap, so a
-        flip never pays an XLA compile on the serving path."""
-        if reg.mode != "auto" or not new_plan.bucket_modes:
-            return []
-        keys = [
-            ProgramKey(reg.index_id, reg.algo, b, int(reg.last_k),
-                       self._program_params(reg, b, plan=new_plan), 0)
-            for b in new_plan.warm_buckets
-            if new_plan.mode_for(b, "")
-        ]
-        if not keys:
-            return []
-        dim = self._index_dim(reg)
-        for key in keys:
-            prog = self.cache.get(
-                key, lambda: self._build_program(reg, key.bucket, key.k,
-                                                 plan=new_plan)
-            )
-            zeros = np.zeros((key.bucket, dim), np.float32)
-            out = tuple(prog(zeros))
-            np.asarray(out[0])  # block until the compile+run completes  # graft-lint: ignore[sync-transfer-in-loop] — flip warmup exists to block on each compile
-        return keys
-
-    def _replan_tick(self) -> None:
-        """Re-cost every planned registration whose corpus/traffic has
-        drifted past the hysteresis thresholds; swap the plan atomically
-        when a decision changed (``serve.plan_flips``), refresh the
-        anchors when not (``serve.plan.recosts``)."""
+        """The engine ``mode="auto"`` resolves at each of the engine's
+        shape buckets, as ``bucket modes: 1→probe … 1024→fused`` (None
+        when the algo has no engine pick or its mode is pinned). The
+        fused kernel is assumed eligible here; the search's own check
+        decides at dispatch."""
         from raft_tpu import plan as _plan
 
-        if not _plan.is_enabled():
-            return
-        with obs.span("plan.replan"):
-            for reg in list(self._indexes.values()):
-                rp = reg.plan
-                if rp is None:
-                    continue
-                traffic = _plan.traffic_from_counts(
-                    reg.bucket_counts, reg.ewma_rows_per_s)
-                rows = self._corpus_rows(reg)
-                if not _plan.needs_replan(rp, rows, traffic):
-                    continue
-                new = self._plan_registration(
-                    reg, k=reg.last_k, traffic=traffic, epoch=rp.epoch + 1)
-                if new is None:
-                    continue
-                if rp.same_decisions(new):
-                    # drift acknowledged, decisions unchanged: re-anchor
-                    # without burning an epoch (or a compile)
-                    reg.plan = dataclasses.replace(new, epoch=rp.epoch)
-                    obs.inc("serve.plan.recosts", index_id=reg.index_id)
-                    continue
-                with obs.span("plan.flip", index_id=reg.index_id,
-                              epoch=new.epoch, algo=reg.algo):
-                    self._warm_plan(reg, new)
-                    # one assignment: a concurrent dispatch reads the old
-                    # plan or the new one, never a mix
-                    reg.plan = new
-                reg.bucket_counts = {}
-                obs.inc("serve.plan_flips", index_id=reg.index_id)
-                obs.set_gauge("serve.plan.epoch", float(new.epoch),
-                              index_id=reg.index_id)
-                # flight-recorder trigger: the swap is complete and no
-                # engine lock is held here
-                obs.recorder.note_plan_flip(reg.index_id, int(new.epoch))
+        reg = self._reg(index_id)
+        if reg.algo not in ("ivf_flat", "ivf_pq", "cagra") or reg.mode != "auto":
+            return None
+        on_tpu = jax.default_backend() == "tpu"
+        modes = (
+            (b, _plan.plan_cagra_mode(on_tpu=on_tpu, fused_ok=True) if reg.algo == "cagra"
+             else _plan.plan_search_mode(b, on_tpu=on_tpu, fused_ok=True))
+            for b in bucket_sizes(self.max_batch)
+        )
+        return (f"plan[{index_id}] algo={reg.algo}\n  bucket modes: "
+                + " ".join(f"{b}→{m}" for b, m in modes))
 
     def _build_program(self, reg: _Registration, bucket: int, k: int,
                        plan=None) -> Callable:
         """One dispatchable closure for ``(reg, bucket, k)``; its jitted
-        inner search is XLA-cached by the bucket's fixed shape."""
+        inner search is XLA-cached by the bucket's fixed shape. ``plan``
+        is accepted for callers that still pass one and has no effect."""
         from raft_tpu.neighbors import brute_force, cagra, ivf_flat, ivf_pq
 
         kw = reg.search_kwargs
-        # the planner's resolved engine for this bucket ("auto" for a
-        # planned fused — the search's own feasibility check decides)
-        mode = self._planned_mode(reg, bucket, plan=plan) or reg.mode
         if reg.algo == "mutable":
             # the snapshot is NOT baked into the closure — it arrives per
             # dispatch, so a cached program can never serve a stale view
             return lambda q, snap: snap.search(q, k, params=reg.params, **kw)
         if reg.algo == "tiered":
             # "auto" defers to the TieredIndex's own per-family default
-            t_mode = None if reg.mode == "auto" else reg.mode
+            t_mode = None if reg.mode == "auto" else reg.mode  # graft-lint: ignore[scattered-auto] — a pass-through: the index resolves its own default
             return lambda q: reg.index.search(q, k, mode=t_mode, **kw)
         if reg.algo == "brute_force":
             return lambda q: brute_force.search(
@@ -930,17 +780,17 @@ class ServingEngine:
             )
         if reg.algo == "ivf_flat":
             return lambda q: ivf_flat.search(
-                reg.index, q, k, reg.params, query_batch=bucket, mode=mode,
+                reg.index, q, k, reg.params, query_batch=bucket, mode=reg.mode,
                 dataset=reg.dataset, **kw
             )
         if reg.algo == "ivf_pq":
             return lambda q: ivf_pq.search(
-                reg.index, q, k, reg.params, query_batch=bucket, mode=mode,
+                reg.index, q, k, reg.params, query_batch=bucket, mode=reg.mode,
                 dataset=reg.dataset, **kw
             )
         if reg.algo == "cagra":
             return lambda q: cagra.search(
-                reg.index, q, k, reg.params, query_batch=bucket, mode=mode, **kw
+                reg.index, q, k, reg.params, query_batch=bucket, mode=reg.mode, **kw
             )
         if reg.algo == "tiered_sharded":
             # the composition path: timed health probe feeds the scan-side
@@ -950,7 +800,7 @@ class ServingEngine:
                 health = self._probe_health_timed(reg)
                 return reg.index.search(
                     q, k, health=health, min_coverage=reg.min_coverage,
-                    merge_mode=None if reg.merge_mode == "auto" else reg.merge_mode,
+                    merge_mode=None if reg.merge_mode == "auto" else reg.merge_mode,  # graft-lint: ignore[scattered-auto] — a pass-through: the index resolves its own default
                     **kw,
                 )
 
@@ -999,18 +849,7 @@ class ServingEngine:
             if reg.last_generation >= 0 and generation != reg.last_generation:
                 obs.inc("serve.generation_flips", index_id=reg.index_id)
             reg.last_generation = generation
-        # traffic model inputs: the batch-size histogram and arrival-rate
-        # EWMA the re-plan tick measures drift against
-        reg.bucket_counts[bucket] = reg.bucket_counts.get(bucket, 0) + 1
-        reg.last_k = k
-        if reg.last_dispatch_t >= 0.0:
-            rate = n / max(now - reg.last_dispatch_t, 1e-6)
-            reg.ewma_rows_per_s = 0.25 * rate + 0.75 * reg.ewma_rows_per_s
-        reg.last_dispatch_t = now
-        key = ProgramKey(
-            reg.index_id, reg.algo, bucket, k,
-            self._program_params(reg, bucket), generation
-        )
+        key = ProgramKey(reg.index_id, reg.algo, bucket, k, params_key(reg.params), generation)
         tracker = self._slos.get(reg.index_id)
         # the batch's trace identities ride the dispatch thread: every
         # span recorded below (dispatch, degrade, tiered fetch/refine)

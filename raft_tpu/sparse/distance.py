@@ -74,14 +74,11 @@ _NATIVE = _NATIVE_GRAM | _NATIVE_UNION
 
 
 def _plan_sparse(n_cols: int, metric) -> str:
-    """Resolve ``mode="auto"``: densify vs native-CSR, costed by the
-    planner (gate off restores the legacy width threshold)."""
+    """Resolve ``mode="auto"``: densify vs native-CSR by
+    :func:`raft_tpu.plan.plan_sparse_mode`."""
     from raft_tpu import plan as _plan
 
-    native_ok = metric in _NATIVE
-    if _plan.is_enabled():
-        return _plan.plan_sparse_mode(n_cols, native_ok=native_ok).choice
-    return "native" if n_cols > (1 << 18) and native_ok else "densify"
+    return _plan.plan_sparse_mode(n_cols, native_ok=metric in _NATIVE)
 
 
 def _densify_rows(a: CSR, start: int, count: int, rows=None) -> jax.Array:

@@ -1,5 +1,5 @@
 """Fixture: a string-literal "auto" dispatch branch resolved by a local
-heuristic, with no route through the raft_tpu.plan planner."""
+heuristic, with no route through a raft_tpu.plan rule."""
 
 
 def search(index, queries, k, mode="auto"):

@@ -315,19 +315,12 @@ class CleanWorker:
 
 
 def plan_routed_search(index, queries, k, mode="auto"):
-    # scattered-auto negative: the "auto" branch routes through the
-    # planner; the gate-off legacy heuristic in the same function is
-    # the sanctioned pattern
+    # scattered-auto negative: the "auto" branch calls its one rule in
+    # raft_tpu.plan — the sanctioned pattern
     from raft_tpu import plan as _plan
 
-    nq = queries.shape[0]
     if mode == "auto":
-        if _plan.is_enabled():
-            mode = _plan.plan_search_mode(
-                "ivf_flat", nq, on_tpu=False, fused_ok=False
-            ).choice
-        else:
-            mode = "scan" if nq >= 128 else "probe"
+        mode = _plan.plan_search_mode(queries.shape[0], on_tpu=False, fused_ok=False)
     return index.run(queries, k, mode)
 
 

@@ -390,8 +390,8 @@ class TestFusedSearch:
 
     def test_table_that_cannot_fit_plans_xla(self, rng, nn_index, monkeypatch):
         """An index whose packed table exceeds free HBM is not fused-
-        eligible: auto plans XLA with the reason in its plan, and an
-        explicit fused request fails up front — no allocation attempted."""
+        eligible: auto's rule picks XLA, the reason names the table, and
+        an explicit fused request fails up front — no allocation attempted."""
         from raft_tpu import plan
         from raft_tpu.ops.pallas import hbm_model
 
@@ -401,8 +401,7 @@ class TestFusedSearch:
         monkeypatch.setattr(hbm_model, "device_free_bytes", lambda device=None: 1024)
         why = cagra.fused_ineligible_reason(fresh, sp)
         assert "packed neighbor table" in why
-        p = plan.plan_cagra_mode(64, on_tpu=True, fused_ok=False, why_not_fused=why)
-        assert p.choice == "xla" and "packed neighbor table" in p.explain()
+        assert plan.plan_cagra_mode(on_tpu=True, fused_ok=not why) == "xla"
         with pytest.raises(Exception, match="fused mode needs"):
             cagra.search(fresh, _data(rng, 4, 16), 5, sp, mode="fused")
         assert getattr(fresh, "_fused_table_cache", None) is None

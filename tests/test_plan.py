@@ -1,24 +1,18 @@
-"""Cost-model query planner: legacy parity, explainability, and live
-re-planning.
+"""Dispatch rules (``raft_tpu.plan``) and what the serving engine makes
+of them.
 
-The planner's contract (docs/planner.md) has three legs, each pinned
-here:
+Every ``"auto"`` engine choice is one plain rule returning the engine's
+name (docs/planner.md). Pinned here:
 
-* **parity** — with the gate on, every ``plan_*`` resolver reproduces
-  the legacy inline heuristic it replaced across that heuristic's whole
-  decision envelope, and gates-off results are bit-identical to the
-  planned ones (the planner resolves to the configs the heuristics
-  chose on these shapes);
-* **explainability** — every decision is a typed :class:`Plan` whose
-  explain() carries a per-term cost breakdown for every candidate,
-  including losers and ineligibles;
-* **re-planning** — the serving engine re-costs a drifting
-  registration from its maintenance tick, swaps the plan atomically
-  (``serve.plan_flips``), keeps recompiles bounded by engines ×
-  buckets, and never surfaces an error to a caller in flight.
+* **truth tables** — each rule against its written-out condition over
+  the inputs its call sites pass;
+* **auto equals the rule's engine** — a search with ``mode="auto"`` is
+  bit-identical to the same search with the rule's engine pinned, and
+  the serving engine dispatches, per shape bucket, exactly the engine
+  the rule picks for that bucket;
+* **the engine does not plan** — a pinned mode dispatches the pinned
+  engine, and the maintenance tick compiles nothing.
 """
-import dataclasses
-
 import numpy as np
 import pytest
 
@@ -45,331 +39,220 @@ def _counter(registry, name, **labels):
     snap = registry.as_dict()["counters"]
     total = 0.0
     for key, value in snap.items():
-        if not key.startswith(name):
+        if not key.startswith(name + "{") and key != name:
             continue
         if all(f'{k}="{v}"' in key for k, v in labels.items()):
             total += value
     return total
 
 
-# -- gate --------------------------------------------------------------------
-
-
-def test_gate_default_on_and_env_off(monkeypatch):
-    monkeypatch.delenv("RAFT_TPU_PLAN", raising=False)
-    assert planlib.is_enabled()
-    for off in ("0", "false", "OFF", " no "):
-        monkeypatch.setenv("RAFT_TPU_PLAN", off)
-        assert not planlib.is_enabled()
-    monkeypatch.setenv("RAFT_TPU_PLAN", "1")
-    assert planlib.is_enabled()
-
-
-# -- per-decision legacy parity ----------------------------------------------
+# -- rule truth tables -------------------------------------------------------
 
 
 NQ_SWEEP = list(range(1, 16)) + [63, 64, 126, 127, 128, 129, 192, 256, 1024]
 
 
-class TestLegacyParity:
+class TestRules:
     @pytest.mark.parametrize("on_tpu", [False, True])
     @pytest.mark.parametrize("fused_ok", [False, True])
     @pytest.mark.parametrize("wants_f32_lut", [False, True])
     def test_ivf_search_mode(self, on_tpu, fused_ok, wants_f32_lut):
-        """ivf_pq/ivf_flat mode="auto": the probe/scan/fused three-way."""
+        """ivf_pq/ivf_flat/rabitq mode="auto": the probe/scan/fused three-way."""
         for nq in NQ_SWEEP:
-            if nq >= 128 and on_tpu and fused_ok and not wants_f32_lut:
-                legacy = "fused"
+            if nq < 128:
+                want = "probe"
+            elif on_tpu and fused_ok and not wants_f32_lut:
+                want = "fused"
             else:
-                legacy = "scan" if nq >= 128 else "probe"
-            for algo in ("ivf_pq", "ivf_flat"):
-                p = planlib.plan_search_mode(
-                    algo, nq, on_tpu=on_tpu, fused_ok=fused_ok,
-                    wants_f32_lut=wants_f32_lut)
-                assert p.choice == legacy, (algo, nq, on_tpu, fused_ok,
-                                            wants_f32_lut, p.explain())
+                want = "scan"
+            got = planlib.plan_search_mode(nq, on_tpu=on_tpu, fused_ok=fused_ok,
+                                           wants_f32_lut=wants_f32_lut)
+            assert isinstance(got, str)
+            assert got == want, (nq, on_tpu, fused_ok, wants_f32_lut)
 
     @pytest.mark.parametrize("on_tpu", [False, True])
     @pytest.mark.parametrize("fused_ok", [False, True])
     def test_cagra_mode(self, on_tpu, fused_ok):
-        for nq in NQ_SWEEP:
-            legacy = "fused" if on_tpu and fused_ok else "xla"
-            p = planlib.plan_cagra_mode(nq, on_tpu=on_tpu, fused_ok=fused_ok)
-            assert p.choice == legacy, (nq, on_tpu, fused_ok, p.explain())
+        want = "fused" if on_tpu and fused_ok else "xla"
+        assert planlib.plan_cagra_mode(on_tpu=on_tpu, fused_ok=fused_ok) == want
 
     def test_merge_mode(self):
         for n_shards in (1, 2, 3, 4, 8, 16):
-            for k in (1, 5, 10, 64, 128):
-                legacy = "ring" if n_shards > 1 else "gather"
-                p = planlib.plan_merge_mode(n_shards, k)
-                assert p.choice == legacy, (n_shards, k, p.explain())
-
-    def test_merge_mode_fused_ring_wins_with_wide_tile(self):
-        """The model sees what the legacy auto could not: with the
-        scan's candidate tile wider than k, folding inside the ring
-        engine skips the HBM round-trip — fused_ring wins."""
-        p = planlib.plan_merge_mode(4, 10, tile_width=64)
-        assert p.choice == "fused_ring", p.explain()
-        assert p.candidate("ring").cost > p.cost
+            want = "ring" if n_shards > 1 else "gather"
+            assert planlib.plan_merge_mode(n_shards) == want, n_shards
 
     def test_comm_mode(self):
-        # legacy: ca whenever n_shards > 1 — the planner agrees on
-        # every real accumulator shape (row cap < full rows)
+        # ca on every real accumulator shape across shards (row cap below
+        # the full rows), full on one shard; without the shape, the
+        # shard count alone decides
         for n_shards in (2, 4, 8):
             for n_rows in (32, 256, 4096):
                 for d in (8, 64, 768):
-                    p = planlib.plan_comm_mode(n_rows, d, n_shards)
-                    assert p.choice == "ca", (n_rows, d, n_shards, p.explain())
-        assert planlib.plan_comm_mode(4096, 64, 1).choice == "full"
+                    got = planlib.plan_comm_mode(n_shards, n_rows=n_rows, d=d)
+                    assert got == "ca", (n_rows, d, n_shards)
+            assert planlib.plan_comm_mode(n_shards) == "ca"
+        assert planlib.plan_comm_mode(1, n_rows=4096, d=64) == "full"
+        assert planlib.plan_comm_mode(1) == "full"
 
     def test_comm_mode_degenerate_cap_keeps_full(self):
-        """Documented deviation (docs/planner.md): when the CA row cap
-        cannot undercut the full exchange, the wire model keeps full —
-        and the model's own byte terms justify it."""
-        p = planlib.plan_comm_mode(4, 8, 2, ca_cap=4)  # cap == rows
-        assert p.choice == "full", p.explain()
-        wire = {c.name: sum(t.value for t in c.terms if t.name == "wire")
-                for c in p.candidates}
-        assert wire["ca"] >= wire["full"]
+        """When the CA row cap cannot undercut the full exchange, the
+        byte comparison keeps full — and the wire model's bytes say so."""
+        from raft_tpu.parallel.wire_model import lloyd_wire_bytes_per_iter
+
+        assert planlib.plan_comm_mode(2, n_rows=4, d=8, ca_cap=4) == "full"  # cap == rows
+        ca = lloyd_wire_bytes_per_iter(4, 8, 2, comm_mode="ca", ca_cap=4)
+        full = lloyd_wire_bytes_per_iter(4, 8, 2, comm_mode="full")
+        assert ca + planlib.CA_SELECT_BYTES >= full
 
     @pytest.mark.parametrize("eligible", [False, True])
     @pytest.mark.parametrize("on_tpu", [False, True])
     def test_delta_mode(self, eligible, on_tpu):
-        legacy = "fused" if eligible and on_tpu else "exact"
-        p = planlib.plan_delta_mode(eligible=eligible, on_tpu=on_tpu)
-        assert p.choice == legacy, p.explain()
+        want = "fused" if eligible and on_tpu else "exact"
+        assert planlib.plan_delta_mode(eligible=eligible, on_tpu=on_tpu) == want
 
     @pytest.mark.parametrize("per_subspace", [False, True])
     def test_pq_kind(self, per_subspace):
         for pq_bits in range(1, 9):
             if pq_bits == 1:
-                legacy = "rabitq"
+                want = "rabitq"
             else:
-                legacy = "nibble" if pq_bits == 8 and per_subspace else "kmeans"
-            p = planlib.plan_pq_kind(pq_bits, per_subspace)
-            assert p.choice == legacy, (pq_bits, per_subspace, p.explain())
+                want = "nibble" if pq_bits == 8 and per_subspace else "kmeans"
+            assert planlib.plan_pq_kind(pq_bits, per_subspace) == want, pq_bits
 
     def test_sparse_mode(self):
         B = 1 << 18
         for n_cols in (16, B - 1, B, B + 1, B * 4):
             for native_ok in (False, True):
-                legacy = "native" if n_cols > B and native_ok else "densify"
-                p = planlib.plan_sparse_mode(n_cols, native_ok=native_ok)
-                assert p.choice == legacy, (n_cols, native_ok, p.explain())
+                want = "native" if n_cols > B and native_ok else "densify"
+                got = planlib.plan_sparse_mode(n_cols, native_ok=native_ok)
+                assert got == want, (n_cols, native_ok)
 
 
-# -- gates-off bit-identical parity ------------------------------------------
+# -- auto equals the rule's explicit engine, bit for bit ---------------------
 
 
 @pytest.fixture(scope="module")
 def small_corpus():
     rng = np.random.default_rng(11)
     X = rng.standard_normal((512, 16)).astype(np.float32)
-    Q = rng.standard_normal((130, 16)).astype(np.float32)
+    Q = rng.standard_normal((256, 16)).astype(np.float32)
     return X, Q
 
 
-class TestBitParity:
-    """The same search, planner on vs. gate off, must produce the same
-    bits — the planner resolves to the configs the heuristics chose."""
-
-    def _run(self, fn, enabled, monkeypatch):
-        monkeypatch.setenv("RAFT_TPU_PLAN", "1" if enabled else "0")
-        return fn()
-
-    def test_ivf_pq_auto_search(self, small_corpus, monkeypatch):
-        X, Q = small_corpus
-        idx = ivf_pq.build(X, ivf_pq.IvfPqIndexParams(
-            n_lists=8, pq_dim=8, seed=3))
-
-        def run():
-            d, i = ivf_pq.search(idx, Q, 10, ivf_pq.IvfPqSearchParams(
-                n_probes=4), mode="auto")
-            return np.asarray(d), np.asarray(i)
-
-        d_on, i_on = self._run(run, True, monkeypatch)
-        d_off, i_off = self._run(run, False, monkeypatch)
-        np.testing.assert_array_equal(i_on, i_off)
-        np.testing.assert_array_equal(d_on, d_off)
-
-    def test_ivf_flat_auto_search_both_sides_of_128(self, small_corpus,
-                                                    monkeypatch):
-        X, Q = small_corpus
-        idx = ivf_flat.build(X, ivf_flat.IvfFlatIndexParams(n_lists=8, seed=3))
-        for nq in (4, 130):  # probe side and scan side of the crossover
-            def run():
-                d, i = ivf_flat.search(idx, Q[:nq], 10,
-                                       ivf_flat.IvfFlatSearchParams(n_probes=4),
-                                       mode="auto")
-                return np.asarray(d), np.asarray(i)
-
-            d_on, i_on = self._run(run, True, monkeypatch)
-            d_off, i_off = self._run(run, False, monkeypatch)
-            np.testing.assert_array_equal(i_on, i_off)
-            np.testing.assert_array_equal(d_on, d_off)
-
-    def test_engine_serving_bit_identical(self, small_corpus, monkeypatch):
-        X, Q = small_corpus
-        idx = ivf_flat.build(X, ivf_flat.IvfFlatIndexParams(n_lists=8, seed=3))
-
-        def serve():
-            eng = ServingEngine(max_batch=16, max_wait_ms=0.0)
-            eng.register("t", "ivf_flat", idx,
-                         params=ivf_flat.IvfFlatSearchParams(n_probes=4))
-            fut = eng.submit("t", Q[:6], k=5)
-            eng.run_until_idle()
-            r = fut.result()
-            return np.asarray(r.distances), np.asarray(r.indices)
-
-        d_on, i_on = self._run(serve, True, monkeypatch)
-        d_off, i_off = self._run(serve, False, monkeypatch)
-        np.testing.assert_array_equal(i_on, i_off)
-        np.testing.assert_array_equal(d_on, d_off)
-
-
-# -- explain format ----------------------------------------------------------
-
-
-class TestExplain:
-    def test_plan_explain_carries_every_candidate(self):
-        p = planlib.plan_search_mode("ivf_pq", 8, on_tpu=False, fused_ok=False)
-        text = p.explain()
-        assert "ivf_pq.search_mode" in text and "probe" in text
-        assert "scan" in text and "fused" in text
-        assert "ineligible" in text          # losers explain why
-        assert "cu" in text                  # per-term cost units
-        assert "nq=8" in text                # inputs recorded
-
-    def test_registration_plan_explain(self, small_corpus):
-        X, _ = small_corpus
-        idx = ivf_flat.build(X, ivf_flat.IvfFlatIndexParams(n_lists=8, seed=3))
-        eng = ServingEngine(max_batch=16, max_wait_ms=0.0)
-        eng.register("exp", "ivf_flat", idx,
-                     params=ivf_flat.IvfFlatSearchParams(n_probes=4))
-        text = eng.plan_explain("exp")
-        assert text is not None
-        assert "plan[exp]" in text and "epoch=0" in text
-        assert "bucket modes:" in text
-        for b in bucket_sizes(16):  # one costed engine per bucket
-            assert f" {b}→" in text
-
-    def test_decisions_metric_emitted(self, serve_obs):
-        planlib.plan_merge_mode(4, 10)
-        snap = serve_obs.as_dict()["counters"]
-        assert any(k.startswith("plan.decisions") for k in snap), snap
-
-
-# -- live re-planning under drift --------------------------------------------
-
-
-class TestReplanning:
-    def _engine(self, X, max_batch=16):
-        idx = ivf_flat.build(X, ivf_flat.IvfFlatIndexParams(n_lists=8, seed=3))
-        eng = ServingEngine(max_batch=max_batch, max_wait_ms=0.0)
-        eng.register("drift", "ivf_flat", idx,
-                     params=ivf_flat.IvfFlatSearchParams(n_probes=4))
-        return eng
-
-    def _pump(self, eng, Q, nq, batches, k=5):
-        outs = []
-        for _ in range(batches):
-            fut = eng.submit("drift", Q[:nq], k=k)
-            eng.run_until_idle()
-            outs.append(fut.result())  # raises if dispatch errored
-        return outs
-
-    def test_traffic_shift_flips_plan_without_caller_error(
-            self, small_corpus, serve_obs):
-        X, Q = small_corpus
-        eng = self._engine(X)
-        plan0 = eng._indexes["drift"].plan
-        assert plan0 is not None and plan0.epoch == 0
-        # traffic arrives concentrated on one bucket; past
-        # TRAFFIC_MIN_SAMPLES the dominant bucket diverges from the
-        # plan's cold anchor and the tick must re-plan
-        self._pump(eng, Q, nq=7, batches=planlib.TRAFFIC_MIN_SAMPLES + 2)
-        eng.maintenance_tick()
-        plan1 = eng._indexes["drift"].plan
-        assert plan1.epoch == plan0.epoch + 1
-        assert plan1.dominant_bucket == 8
-        assert 8 in plan1.warm_buckets
-        assert _counter(serve_obs, "serve.plan_flips", index_id="drift") == 1
-        # serving continues on the new plan, no caller-visible error
-        res = self._pump(eng, Q, nq=7, batches=2)[-1]
-        assert np.asarray(res.indices).shape == (7, 5)
-
-    def test_recost_without_decision_change_keeps_epoch(
-            self, small_corpus, serve_obs):
-        X, Q = small_corpus
-        eng = self._engine(X)
-        reg = eng._indexes["drift"]
-        self._pump(eng, Q, nq=7, batches=planlib.TRAFFIC_MIN_SAMPLES + 2)
-        eng.maintenance_tick()  # flip 1: cold anchors -> live traffic
-        epoch = reg.plan.epoch
-        # corpus growth past the hysteresis factor with unchanged
-        # traffic: decisions cannot change (bucket engines are a pure
-        # function of bucket size on CPU) -> re-cost, not flip
-        self._pump(eng, Q, nq=7, batches=planlib.TRAFFIC_MIN_SAMPLES + 2)
-        anchor = int(reg.plan.corpus_rows // (planlib.GROWTH_REPLAN_FACTOR * 2))
-        reg.plan = dataclasses.replace(reg.plan, corpus_rows=anchor)
-        eng.maintenance_tick()
-        assert _counter(serve_obs, "serve.plan.recosts", index_id="drift") == 1
-        assert reg.plan.epoch == epoch          # no epoch burn
-        assert reg.plan.corpus_rows == 512      # anchors refreshed
-        assert _counter(serve_obs, "serve.plan_flips", index_id="drift") == 1
-
-    def test_hysteresis_holds_plan_inside_thresholds(self, small_corpus):
-        X, Q = small_corpus
-        eng = self._engine(X)
-        reg = eng._indexes["drift"]
-        plan0 = reg.plan
-        # a handful of batches: below TRAFFIC_MIN_SAMPLES, no growth
-        self._pump(eng, Q, nq=7, batches=3)
-        eng.maintenance_tick()
-        assert reg.plan is plan0  # untouched — not even a re-cost
-
-    def test_recompiles_bounded_by_engines_times_buckets(self, small_corpus):
-        """A flip whose bucket engines did not change must reuse every
-        cached program: total misses stay <= one per (bucket, engine)
-        pair ever dispatched or warmed."""
-        X, Q = small_corpus
-        eng = self._engine(X)
-        self._pump(eng, Q, nq=7, batches=planlib.TRAFFIC_MIN_SAMPLES + 2)
-        eng.maintenance_tick()   # flip (warm set changed)
-        self._pump(eng, Q, nq=7, batches=4)
-        st = eng.cache.stats()
-        # bucket 8 dispatched (1 miss) + warm-bucket precompiles at the
-        # flip (<= WARM_BUCKETS; the engine for bucket 8 did not change,
-        # so its warmed key re-uses the dispatched program); everything
-        # after the flip must hit
-        assert st.misses <= 1 + planlib.WARM_BUCKETS, st
-        assert st.hits >= planlib.TRAFFIC_MIN_SAMPLES, st
-
-    def test_mutable_growth_recosts_from_tick(self, serve_obs):
-        """A mutable registration's plan carries corpus anchors; real
-        insert-driven growth past GROWTH_REPLAN_FACTOR re-costs it."""
-        rng = np.random.default_rng(5)
-        mi = MutableIndex("brute_force", 8)
-        mi.insert(rng.standard_normal((64, 8)).astype(np.float32))
-        eng = ServingEngine(max_batch=8, max_wait_ms=0.0)
-        eng.register_mutable("grow", mi)
-        reg = eng._indexes["grow"]
-        assert reg.plan is not None and reg.plan.corpus_rows == 64
-        mi.insert(rng.standard_normal((64, 8)).astype(np.float32))
-        eng.maintenance_tick()
-        assert _counter(serve_obs, "serve.plan.recosts", index_id="grow") == 1
-        assert reg.plan.corpus_rows == 128
-
-
-# -- planner stays out of the way when pinned --------------------------------
-
-
-def test_pinned_mode_never_planned(small_corpus):
+@pytest.fixture(scope="module")
+def flat_index(small_corpus):
     X, _ = small_corpus
-    idx = ivf_flat.build(X, ivf_flat.IvfFlatIndexParams(n_lists=8, seed=3))
+    return ivf_flat.build(X, ivf_flat.IvfFlatIndexParams(n_lists=8, seed=3))
+
+
+FLAT_PARAMS = ivf_flat.IvfFlatSearchParams(n_probes=4)
+
+
+def _rule_mode(nq):
+    return planlib.plan_search_mode(nq, on_tpu=False, fused_ok=True)
+
+
+def _assert_same(a, b):
+    np.testing.assert_array_equal(np.asarray(a[1]), np.asarray(b[1]))
+    np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(b[0]))
+
+
+class TestBitParity:
+    def test_ivf_pq_auto_search(self, small_corpus):
+        X, Q = small_corpus
+        idx = ivf_pq.build(X, ivf_pq.IvfPqIndexParams(n_lists=8, pq_dim=8, seed=3))
+        sp = ivf_pq.IvfPqSearchParams(n_probes=4)
+        nq = 130
+        _assert_same(ivf_pq.search(idx, Q[:nq], 10, sp, mode="auto"),
+                     ivf_pq.search(idx, Q[:nq], 10, sp, mode=_rule_mode(nq)))
+
+    def test_ivf_flat_auto_search_both_sides_of_128(self, small_corpus, flat_index):
+        _, Q = small_corpus
+        for nq in (4, 130):  # probe side and scan side of the rule
+            _assert_same(
+                ivf_flat.search(flat_index, Q[:nq], 10, FLAT_PARAMS, mode="auto"),
+                ivf_flat.search(flat_index, Q[:nq], 10, FLAT_PARAMS, mode=_rule_mode(nq)))
+
+    def test_engine_serving_bit_identical(self, small_corpus, flat_index):
+        _, Q = small_corpus
+        eng = ServingEngine(max_batch=16, max_wait_ms=0.0)
+        eng.register("t", "ivf_flat", flat_index, params=FLAT_PARAMS)
+        fut = eng.submit("t", Q[:8], k=5)
+        eng.run_until_idle()
+        r = fut.result()
+        _assert_same((r.distances, r.indices),
+                     ivf_flat.search(flat_index, Q[:8], 5, FLAT_PARAMS, query_batch=8))
+
+
+@pytest.mark.parametrize("bucket", bucket_sizes(256))
+def test_engine_bucket_dispatches_rule_engine(small_corpus, flat_index, serve_obs, bucket):
+    """A bucket's engine is what the search's auto resolves there: the
+    dispatch counter shows the rule's engine, and the served ids equal a
+    direct search with that engine pinned."""
+    _, Q = small_corpus
+    rule = planlib.plan_search_mode(bucket, on_tpu=False, fused_ok=True)
+    eng = ServingEngine(max_batch=256, max_wait_ms=0.0)
+    eng.register("b", "ivf_flat", flat_index, params=FLAT_PARAMS)
+    fut = eng.submit("b", Q[:bucket], k=5)
+    eng.run_until_idle()
+    r = fut.result()
+    assert r.bucket == bucket
+    assert _counter(serve_obs, "ivf_flat.search.calls", mode=rule) == 1
+    assert _counter(serve_obs, "ivf_flat.search.calls") == 1
+    d, i = ivf_flat.search(flat_index, Q[:bucket], 5, FLAT_PARAMS, mode=rule,
+                           query_batch=bucket)
+    np.testing.assert_array_equal(np.asarray(r.indices), np.asarray(i))
+    np.testing.assert_array_equal(np.asarray(r.distances), np.asarray(d))
+
+
+# -- explain and the engine's hands-off dispatch -----------------------------
+
+
+def test_registration_plan_explain(flat_index):
     eng = ServingEngine(max_batch=16, max_wait_ms=0.0)
-    eng.register("pinned", "ivf_flat", idx, mode="scan",
-                 params=ivf_flat.IvfFlatSearchParams(n_probes=4))
-    plan = eng._indexes["pinned"].plan
-    assert plan is not None
-    assert plan.bucket_modes == ()  # an explicit pin is never second-guessed
+    eng.register("exp", "ivf_flat", flat_index, params=FLAT_PARAMS)
+    text = eng.plan_explain("exp")
+    assert text is not None and "plan[exp]" in text
+    lines = [ln.strip() for ln in text.splitlines() if "bucket modes:" in ln]
+    assert len(lines) == 1
+    entries = lines[0].split("bucket modes:")[1].split()
+    assert entries == [f"{b}→{_rule_mode(b)}" for b in bucket_sizes(16)]
+
+
+def test_pinned_mode_never_planned(small_corpus, flat_index, serve_obs):
+    """A pinned mode dispatches the pinned engine (scan at a bucket whose
+    rule says probe), and there is no bucket plan to explain."""
+    _, Q = small_corpus
+    eng = ServingEngine(max_batch=16, max_wait_ms=0.0)
+    eng.register("pinned", "ivf_flat", flat_index, mode="scan", params=FLAT_PARAMS)
+    assert eng.plan_explain("pinned") is None
+    fut = eng.submit("pinned", Q[:6], k=5)
+    eng.run_until_idle()
+    fut.result()
+    assert _rule_mode(8) == "probe"
+    assert _counter(serve_obs, "ivf_flat.search.calls", mode="scan") == 1
+    assert _counter(serve_obs, "ivf_flat.search.calls") == 1
+
+
+def test_maintenance_tick_compiles_nothing(small_corpus, flat_index):
+    """The tick runs compactors and the recorder only: with a mutable
+    registration that grew 2x and an auto-mode registration that served
+    traffic, it builds no program."""
+    _, Q = small_corpus
+    rng = np.random.default_rng(5)
+    mi = MutableIndex("brute_force", 16)
+    mi.insert(rng.standard_normal((64, 16)).astype(np.float32))
+    eng = ServingEngine(max_batch=16, max_wait_ms=0.0)
+    eng.register_mutable("grow", mi)
+    eng.register("flat", "ivf_flat", flat_index, params=FLAT_PARAMS)
+    for index_id in ("grow", "flat"):
+        for _ in range(3):
+            fut = eng.submit(index_id, Q[:7], k=5)
+            eng.run_until_idle()
+            fut.result()
+    mi.insert(rng.standard_normal((64, 16)).astype(np.float32))
+    assert mi.size == 128
+    before = eng.cache.stats()
+    eng.maintenance_tick()
+    after = eng.cache.stats()
+    assert (after.misses, after.size) == (before.misses, before.size)

@@ -366,7 +366,6 @@ class TestRecorderEvents:
             str(tmp_path), triggers=("slo",), clock=VClock()
         )
         assert r.note_breaker("a", "open") is None
-        assert r.note_plan_flip("i", 3) is None
         assert recorder.list_bundles(str(tmp_path)) == []
 
     def test_bundle_body_shape(self, obs_on, tmp_path):

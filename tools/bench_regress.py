@@ -51,10 +51,6 @@ _METRICS = {
     # traffic regresses by growing, the full:ca reduction by shrinking
     "wire_bytes_per_iter": (-1, "ratio", "bytes_rise"),
     "build_bytes_ratio": (+1, "ratio", "bytes_rise"),
-    # planner phase column (bench.py): planner QPS / best hand-tuned QPS
-    # at the same recall floor — 1.0 means the cost models found the
-    # measured frontier; regresses by dropping
-    "planner_regret": (+1, "absolute", "regret_drop"),
     # obs_overhead phase column (bench.py): fractional QPS cost of the
     # always-on recorder + time-series pipeline on the serve row —
     # regresses by growing (absolute: the fraction itself is the delta)
@@ -245,9 +241,6 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("--overlap-drop", type=float, default=0.25,
                     help="flag absolute overlap_efficiency drops beyond this "
                          "(default 0.25)")
-    ap.add_argument("--regret-drop", type=float, default=0.05,
-                    help="flag absolute planner_regret drops beyond this "
-                         "(default 0.05)")
     ap.add_argument("--overhead-rise", type=float, default=0.02,
                     help="flag absolute recorder_overhead_frac rises beyond "
                          "this (default 0.02 — the <2%% overhead contract)")

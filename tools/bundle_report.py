@@ -9,8 +9,8 @@ Usage::
     python tools/bundle_report.py bundle.raftbundle --json
 
 The bundle is the black box :class:`raft_tpu.obs.recorder.FlightRecorder`
-writes on a trigger (SLO alert, fault seam, breaker trip, plan flip,
-compactor worker death, or an explicit ``dump()``). This tool answers
+writes on a trigger (SLO alert, fault seam, breaker trip, compactor
+worker death, or an explicit ``dump()``). This tool answers
 the first three incident questions in order: *what tripped* (the
 trigger section), *what was the cluster doing* (health + event
 timeline), and *where did the slow requests spend their time* (the

@@ -1,16 +1,15 @@
-"""Dispatch-policy checkers: "auto" resolution must route through the
-planner.
+"""Dispatch-policy checkers: "auto" resolution must route through
+``raft_tpu.plan``.
 
 The repo's dispatch decisions ("auto" search/merge/comm/delta modes)
-resolve through one costed chokepoint — ``raft_tpu.plan`` — so every
-policy is explainable from a single cost table instead of re-derived by
-scattered one-liner heuristics that drift apart:
+each resolve by one rule in ``raft_tpu.plan``, so every policy is read
+in one place instead of re-derived by scattered heuristics that drift
+apart:
 
 * ``scattered-auto`` — an ``== "auto"`` / ``!= "auto"`` string-literal
-  comparison inside a function with no reference to the planner is a
-  local dispatch heuristic growing outside the chokepoint. Route the
-  branch through a ``raft_tpu.plan`` resolver (gate-off legacy branches
-  in the same function are fine — the planner reference marks the
+  comparison inside a function with no reference to ``raft_tpu.plan``
+  is a local dispatch heuristic growing outside it. Resolve the branch
+  by one rule in ``raft_tpu.plan`` (calling a ``plan_*`` rule marks the
   function as routed), or carry a rationale'd inline suppression.
 
 Membership validations (``mode in ("auto", ...)``) are not flagged —
@@ -24,7 +23,7 @@ from typing import Iterator, List
 
 from tools.graft_lint.core import Checker, LintModule, Violation
 
-#: attribute/name spellings that mark a function as planner-routed
+#: attribute/name spellings that mark a function as routed through raft_tpu.plan
 _PLAN_PREFIXES = ("plan_", "_plan", "planned_")
 
 
@@ -43,7 +42,7 @@ def _is_plan_ref(node: ast.AST) -> bool:
     return False
 
 
-def _routes_through_planner(fn: ast.AST) -> bool:
+def _routes_through_plan(fn: ast.AST) -> bool:
     return any(_is_plan_ref(n) for n in ast.walk(fn))
 
 
@@ -70,9 +69,9 @@ class ScatteredAutoChecker(Checker):
     rule = "scattered-auto"
     doc = (
         'string-literal "auto" dispatch branch in a function that never '
-        "references the planner — resolve the decision through a "
-        "raft_tpu.plan resolver so every policy prices from one cost "
-        "table instead of a drifting local heuristic"
+        "references raft_tpu.plan — resolve the decision by one rule in "
+        "raft_tpu.plan so every policy is read in one place instead of "
+        "a drifting local heuristic"
     )
 
     def check(self, module: LintModule) -> Iterator[Violation]:
@@ -90,10 +89,10 @@ class ScatteredAutoChecker(Checker):
             if not cmps:
                 continue
             # the function (or any function nesting it) routing through
-            # the planner clears its whole subtree: a gate-off legacy
-            # branch next to the planner call is the sanctioned pattern
+            # raft_tpu.plan clears its whole subtree: an "auto" branch
+            # that calls its rule is the sanctioned pattern
             if any(
-                _routes_through_planner(f)
+                _routes_through_plan(f)
                 for f in funcs
                 if f is fn or any(n is fn for n in ast.walk(f))
             ):
@@ -102,10 +101,9 @@ class ScatteredAutoChecker(Checker):
                 yield self.violation(
                     module, cmp_node,
                     '"auto" resolved by a local heuristic in '
-                    f"{fn.name}() — route the decision through a "
-                    "raft_tpu.plan resolver (plan_search_mode, "
-                    "plan_merge_mode, ...) so the choice is costed and "
-                    "explainable, or suppress with a rationale",
+                    f"{fn.name}() — resolve the decision by one rule in "
+                    "raft_tpu.plan (plan_search_mode, plan_merge_mode, "
+                    "...), or suppress with a rationale",
                 )
 
 

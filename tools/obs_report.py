@@ -110,12 +110,6 @@ _BUILD_COMMS_PREFIX = "comms.build."
 #: operator correlates with compactions, not with QPS)
 _HEALTH_EXTRAS = ("serve.generation_flips",)
 
-#: query-planner metrics (docs/planner.md): per-decision resolutions
-#: plus the serving engine's re-plan activity — their own table so a
-#: surprising dispatch choice or a flip storm is visible at a glance
-_PLANNER_PREFIXES = ("plan.decisions", "serve.plan_flips",
-                     "serve.plan.recosts", "serve.plan.epoch")
-
 
 def _key(rec: Dict[str, Any]) -> str:
     labels = rec.get("labels") or {}
@@ -258,17 +252,12 @@ def _table(rows: List[List[str]], header: List[str]) -> str:
 
 
 def render_report(*paths: str, top: int = 10,
-                  plan_explains: Optional[List[str]] = None,
                   health: Optional[Dict[str, Any]] = None) -> str:
     """Build the text report over one or more obs artifact files.
 
-    ``plan_explains`` appends the active query plans' full cost
-    breakdowns (``ServingEngine.plan_explain`` /
-    ``RegistrationPlan.explain``, see docs/planner.md) as their own
-    section, so the report pairs *what dispatched* (the planner metric
-    tables) with *why* (the per-candidate cost terms). ``health`` is a
-    ``ReplicaGroup.health()`` dump (``--health file.json``) rendered as
-    the cluster-health section alongside the breaker-state gauges."""
+    ``health`` is a ``ReplicaGroup.health()`` dump (``--health
+    file.json``) rendered as the cluster-health section alongside the
+    breaker-state gauges."""
     spans: List[Dict[str, Any]] = []
     counters: Dict[str, float] = {}
     gauges: Dict[str, float] = {}
@@ -369,31 +358,14 @@ def render_report(*paths: str, top: int = 10,
     if build_rows:
         sections.append("## build comms\n"
                         + _table(build_rows, ["counter", "value"]))
-    # query planner: decision resolutions (which engine each "auto"
-    # costed out to) and the serving engine's re-plan activity — flips,
-    # anchor-refresh recosts, active epochs (docs/planner.md)
-    planner_rows = [
-        [k, kind, f"{v:g}"]
-        for kind, table in (("counter", counters), ("gauge", gauges))
-        for k, v in sorted(table.items())
-        if k.startswith(_PLANNER_PREFIXES)
-    ]
-    if planner_rows:
-        sections.append("## query planner\n"
-                        + _table(planner_rows, ["metric", "kind", "value"]))
-    if plan_explains:
-        sections.append("## plan explain\n"
-                        + "\n\n".join(t.rstrip() for t in plan_explains if t))
     plain = {k: v for k, v in counters.items()
              if not k.startswith(_HEALTH_PREFIXES + _HEALTH_EXTRAS
-                                 + _DISPATCH_PREFIXES + _PLANNER_PREFIXES
-                                 + (_BUILD_COMMS_PREFIX,))}
+                                 + _DISPATCH_PREFIXES + (_BUILD_COMMS_PREFIX,))}
     if plain:
         rows = [[k, f"{v:g}"] for k, v in sorted(plain.items())]
         sections.append("## counters\n" + _table(rows, ["counter", "value"]))
     plain_g = {k: v for k, v in gauges.items()
-               if not k.startswith(_HEALTH_PREFIXES + _HEALTH_EXTRAS
-                                   + _PLANNER_PREFIXES)}
+               if not k.startswith(_HEALTH_PREFIXES + _HEALTH_EXTRAS)}
     if plain_g:
         rows = [[k, f"{v:g}"] for k, v in sorted(plain_g.items())]
         sections.append("## gauges\n" + _table(rows, ["gauge", "value"]))
@@ -413,25 +385,16 @@ def main(argv: Optional[List[str]] = None) -> int:
     ap.add_argument("paths", nargs="+",
                     help="metrics .jsonl and/or Chrome-trace .json files")
     ap.add_argument("--top", type=int, default=10, help="span rows to show")
-    ap.add_argument("--plan-explain", metavar="FILE", default=None,
-                    help="text file of RegistrationPlan.explain dumps "
-                         "(e.g. bench_artifacts/plan_explain.txt) appended "
-                         "as the report's plan-explain section")
     ap.add_argument("--health", metavar="FILE", default=None,
                     help="JSON dump of ReplicaGroup.health() rendered as "
                          "the cluster-health section")
     ns = ap.parse_args(argv)
-    explains = None
-    if ns.plan_explain:
-        with open(ns.plan_explain, "r", encoding="utf-8") as f:
-            explains = [f.read()]
     health = None
     if ns.health:
         with open(ns.health, "r", encoding="utf-8") as f:
             health = json.load(f)
     try:
-        print(render_report(*ns.paths, top=ns.top, plan_explains=explains,
-                            health=health))
+        print(render_report(*ns.paths, top=ns.top, health=health))
     except (OSError, json.JSONDecodeError, KeyError, ValueError) as e:
         print(f"obs_report: {type(e).__name__}: {e}", file=sys.stderr)
         return 1
